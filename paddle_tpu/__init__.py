@@ -10,14 +10,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# version-drift shims FIRST: library modules and user code reference
-# `jax.shard_map` / `jax.lax.axis_size`, which older JAX installs only
-# ship under other spellings — importing paddle_tpu makes the
-# environment whole
-from paddle_tpu.core import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from paddle_tpu.core.tensor import Parameter, Tensor  # noqa: F401
 from paddle_tpu.core import dtype as _dtype_mod
 from paddle_tpu.core.dtype import (  # noqa: F401

@@ -173,13 +173,10 @@ def _kl102(call, config, where):
         return []
     budget_mb, chip = config.vmem_budget_mb, ""
     if budget_mb is None:
-        try:
-            from paddle_tpu.observability import profile
-            spec = profile.default_chip()
-            budget_mb = float(getattr(spec, "vmem_mb", 16.0))
-            chip = f" ({spec.name})"
-        except Exception:
-            budget_mb = 16.0
+        # the static audit prices kernels for the v5e, by name
+        from paddle_tpu.observability import profile
+        budget_mb = profile.V5E.vmem_mb
+        chip = f" ({profile.V5E.name})"
     limit = float(budget_mb) * float(config.vmem_fill_limit) * _MIB
     if est.total_bytes <= limit:
         return []

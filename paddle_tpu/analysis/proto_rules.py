@@ -157,7 +157,7 @@ def _check_unbounded_get(pm):
             what = op.pattern if not op.opaque else f.qualname
             out.append(_finding(
                 op, "PL103",
-                f"'{what}' (no deadline: a dead peer wedges this "
+                f"'{what}' (no deadline: a dead peer hangs this "
                 f"process forever)"))
     return out
 

@@ -482,7 +482,7 @@ RULES = {r.code: r for r in [
        "non-root prefix reap that runs before the seq can recycle"),
     _R("PL103", "unbounded-kv-wait",
        "unbounded blocking KV get: {detail}",
-       "a blocking_key_value_get with no finite deadline wedges the "
+       "a blocking_key_value_get with no finite deadline hangs the "
        "process forever when the peer died before setting the key — "
        "the exact failure the fleet watchdog exists to convert into a "
        "typed CollectiveTimeout with a DEAD verdict",

@@ -67,11 +67,8 @@ def synchronize(device=None):
     """Block until all queued device work completes (the reference's
     cuda.synchronize; XLA's dispatch is async the same way)."""
     import jax
-    try:
-        jax.block_until_ready(
-            jax.device_put(0, jax.devices()[0] if device is None else device))
-    except Exception:
-        pass
+    jax.block_until_ready(
+        jax.device_put(0, jax.devices()[0] if device is None else device))
 
 
 def get_all_device_type():

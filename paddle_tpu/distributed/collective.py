@@ -455,8 +455,7 @@ def barrier(group=None):
 
 
 def wait(tensor, group=None, use_calc_stream=True):
-    from paddle_tpu.core.tensor import sync_array
-    sync_array(tensor._value)
+    tensor._value.block_until_ready()
     return tensor
 
 

@@ -4,7 +4,7 @@ Reference parity: python/paddle/distributed/elastic (+ fleet elastic
 manager): etcd-backed node watchdogs that detect dead trainers and
 trigger job restart. TPU-native design: JAX is single-controller per host,
 so in-process failure detection is (a) a step-progress watchdog (training
-stall = hung collective / wedged device — the moral equivalent of a NCCL
+stall = hung collective / hung device — the moral equivalent of a NCCL
 timeout) and (b) multi-host liveness via the jax.distributed coordination
 service, which already evicts dead hosts at barrier timeout. The watchdog
 runs as a daemon thread; on stall it snapshots live stacks (for the bug
@@ -61,7 +61,7 @@ class Watchdog:
     def _fire(self, idle):
         msg = (f"[paddle_tpu.elastic] WATCHDOG: no training progress for "
                f"{idle:.0f}s (last step {self._last_step}); likely a hung "
-               f"collective or wedged device")
+               f"collective or hung device")
         print(msg, file=sys.stderr, flush=True)
         try:
             faulthandler.dump_traceback(file=sys.stderr)  # live stacks

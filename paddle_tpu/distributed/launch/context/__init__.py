@@ -8,6 +8,7 @@ inventory; controllers consume it to build the pod.
 from __future__ import annotations
 
 import argparse
+import glob
 import logging
 import os
 import socket
@@ -25,10 +26,19 @@ class DeviceType:
     TPU = "tpu"
 
 
+# where the TPU driver exposes one node per chip (accel: v2-v4;
+# vfio: v5e and later)
+_TPU_DEVICE_NODES = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+
+
 class Device:
     """Node-local accelerator inventory (reference context/device.py).
-    Detection prefers TPU_VISIBLE_CHIPS, then live jax devices, then
-    cpu."""
+
+    Counted WITHOUT initialising a JAX backend: the launcher is the
+    parent of the workers that need the chips, and a chip belongs to one
+    process at a time — a parent that had asked jax would hold them.
+    Detection prefers TPU_VISIBLE_CHIPS, then the chips' device nodes; a
+    host with neither is a CPU host.  ``--devices`` selects among them."""
 
     def __init__(self, dtype=None, count=1, memory="", labels=None):
         self.dtype = dtype
@@ -42,15 +52,12 @@ class Device:
         if visible is not None:
             labels = [x for x in visible.split(",") if x.strip() != ""]
             return cls(DeviceType.TPU, len(labels), labels=labels)
-        try:
-            import jax
-            devs = jax.local_devices()
-            dtype = (DeviceType.TPU if devs and devs[0].platform == "tpu"
-                     else DeviceType.CPU)
-            return cls(dtype, len(devs),
-                       labels=[str(d.id) for d in devs])
-        except Exception:
-            return cls(DeviceType.CPU, 1, labels=["0"])
+        for pattern in _TPU_DEVICE_NODES:
+            n = len(glob.glob(pattern))
+            if n:
+                return cls(DeviceType.TPU, n,
+                           labels=[str(i) for i in range(n)])
+        return cls(DeviceType.CPU, 1, labels=["0"])
 
     def get_selected_device_key(self):
         return {DeviceType.TPU: "TPU_VISIBLE_CHIPS",

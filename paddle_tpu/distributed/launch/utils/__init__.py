@@ -175,8 +175,8 @@ def get_gpu_info(query=None):
 
 
 def get_gpu_process(query=None):
-    """Processes bound to local accelerators: the TPU claim is
-    single-process, so at most this process."""
+    """Processes bound to local accelerators: a chip belongs to one
+    process at a time, so at most this process."""
     from paddle_tpu.distributed.launch.context import Device
     dev = Device.detect_device()
     if dev.dtype == "tpu":

@@ -261,7 +261,8 @@ def get_logger(log_level=None, name="FLEET"):
 def get_gpus(selected_gpus):
     """Reference launch_utils.py:66 parses selected_gpus against
     CUDA_VISIBLE_DEVICES; the TPU analogue resolves device indices
-    against TPU_VISIBLE_CHIPS (or the full local device list)."""
+    against TPU_VISIBLE_CHIPS (or the node's device inventory — counted
+    without initialising a JAX backend in this launcher process)."""
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible is None:
         visible = os.environ.get("CUDA_VISIBLE_DEVICES")
@@ -274,8 +275,8 @@ def get_gpus(selected_gpus):
         # range(device_count) here)
         if vis is not None:
             return list(range(len(vis)))
-        import jax
-        return list(range(jax.local_device_count()))
+        from paddle_tpu.distributed.launch.context import Device
+        return list(range(Device.detect_device().count))
     want = [int(x) for x in str(selected_gpus).split(",")]
     if vis is None:
         return want

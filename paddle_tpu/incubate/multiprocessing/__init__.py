@@ -3,7 +3,7 @@ incubate/multiprocessing/reductions.py): make Tensors picklable across
 process boundaries for DataLoader workers.
 
 The reference registers CUDA-IPC reductions; device memory here is not
-process-shareable (the TPU claim is exclusive), so tensors reduce
+process-shareable (a chip belongs to one process), so tensors reduce
 through host numpy buffers — correct everywhere, zero-copy nowhere.
 """
 from __future__ import annotations

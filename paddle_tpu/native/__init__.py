@@ -7,48 +7,81 @@ epoch pipeline — shuffle, shard slicing, multithreaded row gather, prefetch
 ring — for datasets backed by contiguous host arrays; Python only wraps the
 popped buffers as Tensors.
 
-The library compiles on first use (g++, ~1s) and is cached next to the
-source; everything degrades gracefully to the pure-Python path when a
-toolchain isn't available (`available()` -> False).
+The library compiles on first use (g++, ~1s) into the checkout's cache
+root, under a name that carries a hash of its source — only a library
+built from the committed source is ever loaded.  A host without a C++
+toolchain runs the pure-Python path (`available()` -> False, with a
+warning); a compile or load that fails raises.
 """
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libptdata.so")
 _lib = None
 _lock = threading.Lock()
 _build_err = None
 
 
+@functools.lru_cache(maxsize=None)
+def _so_path(src_name):
+    """``<cache root>/native/lib<stem>-<source sha>.so`` — where the build
+    of exactly this source lives.  Read once per process, outside the
+    build lock."""
+    from paddle_tpu.utils.compile_cache import cache_root
+    with open(os.path.join(_DIR, src_name), "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(
+        cache_root(), "native",
+        f"lib{os.path.splitext(src_name)[0]}-{digest}.so")
+
+
 def _build_and_load(src_name, so_path):
-    """Shared build-or-load: (re)compile when the .so is missing/stale,
-    then dlopen. Raises on toolchain/load failure (callers decide the
-    fallback policy)."""
-    src = os.path.join(_DIR, src_name)
-    if not os.path.exists(so_path) or (
-            os.path.getmtime(so_path) < os.path.getmtime(src)):
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-shared",
-             "-o", so_path, src],
-            check=True, capture_output=True)
+    """Shared build-or-load: compile `so_path` unless that exact build
+    exists, then dlopen. Raises FileNotFoundError without a toolchain,
+    RuntimeError (with the compiler's stderr) when the source does not
+    compile."""
+    if not os.path.exists(so_path):
+        if shutil.which("g++") is None:
+            raise FileNotFoundError("g++")
+        os.makedirs(os.path.dirname(so_path), exist_ok=True)
+        tmp = f"{so_path}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread",
+                 "-shared", "-o", tmp, os.path.join(_DIR, src_name)],
+                check=True, capture_output=True, text=True)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"{src_name} failed to compile:\n{e.stderr}") from e
+        os.replace(tmp, so_path)     # concurrent builders: last one wins
     return ctypes.CDLL(so_path)
+
+
+def _warn_no_toolchain(what):
+    warnings.warn(f"no C++ toolchain (g++): {what} runs the pure-Python "
+                  f"path", RuntimeWarning, stacklevel=3)
 
 
 def _load():
     global _lib, _build_err
+    so_path = _so_path("ptdata.cc")
     with _lock:
         if _lib is not None or _build_err is not None:
             return _lib
         try:
-            lib = _build_and_load("ptdata.cc", _SO)
-        except Exception as e:  # no toolchain / load failure -> Python path
+            lib = _build_and_load("ptdata.cc", so_path)
+        except FileNotFoundError as e:      # no toolchain -> Python path
+            _warn_no_toolchain("the data pipeline")
             _build_err = e
             return None
         lib.ptdata_shuffle.argtypes = [
@@ -250,7 +283,6 @@ class NativeLoader:
 
 
 # ------------------------------------------------------- PS sparse table
-_PSTABLE_SO = os.path.join(_DIR, "libpstable.so")
 _pstable_lib = None
 _pstable_err = None
 
@@ -259,24 +291,26 @@ def _pstable():
     """Load (building on first use) the native PS table kernels; None
     when no toolchain is available (callers fall back to numpy)."""
     global _pstable_lib, _pstable_err
+    so_path = _so_path("pstable.cc")
     with _lock:
         if _pstable_lib is not None or _pstable_err is not None:
             return _pstable_lib
         try:
-            lib = _build_and_load("pstable.cc", _PSTABLE_SO)
-            lib.pstable_pull.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_int]
-            lib.pstable_push.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_void_p, ctypes.c_float,
-                ctypes.c_float, ctypes.c_int]
-            _pstable_lib = lib
-        except Exception as e:
+            lib = _build_and_load("pstable.cc", so_path)
+        except FileNotFoundError as e:
+            _warn_no_toolchain("the PS sparse table")
             _pstable_err = e
             return None
+        lib.pstable_pull.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int]
+        lib.pstable_push.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_float,
+            ctypes.c_float, ctypes.c_int]
+        _pstable_lib = lib
         return _pstable_lib
 
 

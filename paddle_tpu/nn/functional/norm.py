@@ -110,11 +110,8 @@ def _use_fused(fused):
         return bool(fused)
     if _FUSED_NORM[0]:
         return True
-    try:
-        from paddle_tpu.ops.pallas import on_tpu
-        return on_tpu()
-    except Exception:
-        return False
+    from paddle_tpu.ops.pallas import kernel_default
+    return kernel_default()
 
 
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
@@ -126,12 +123,9 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     if nd == 1 and _use_fused(fused):
         # last-axis layernorm: fused Pallas kernel (custom VJP whose
         # backward recomputes the stats; interpret mode off-TPU)
-        try:
-            from paddle_tpu.ops.pallas.norm import fused_layer_norm
-            return apply(lambda v, w, b: fused_layer_norm(
-                v, w, b, epsilon), x, weight, bias)
-        except Exception:
-            pass
+        from paddle_tpu.ops.pallas.norm import fused_layer_norm
+        return apply(lambda v, w, b: fused_layer_norm(
+            v, w, b, epsilon), x, weight, bias)
 
     def fn(v, w, b):
         from paddle_tpu.amp.auto_cast import downcast_inputs
@@ -163,16 +157,13 @@ def fused_ln_residual(x, residual, weight=None, bias=None, epsilon=1e-5,
     normalized input.  On the fused path (Pallas kernel, interpret mode
     off-TPU) the custom VJP recomputes the normalized intermediate in
     backward instead of materializing it; the pure-JAX composition is
-    the fallback (weight-free norms always use it).  ``act`` is None or
-    ``"gelu"`` (tanh approximation)."""
+    the unfused path (weight-free norms always use it).  ``act`` is None
+    or ``"gelu"`` (tanh approximation)."""
     if _use_fused(fused) and weight is not None:
-        try:
-            from paddle_tpu.ops.pallas.norm import (
-                fused_ln_residual as _pallas_ln_res)
-            return apply(lambda a, r, w, b: _pallas_ln_res(
-                a, r, w, b, epsilon, act), x, residual, weight, bias)
-        except Exception:
-            pass
+        from paddle_tpu.ops.pallas.norm import (
+            fused_ln_residual as _pallas_ln_res)
+        return apply(lambda a, r, w, b: _pallas_ln_res(
+            a, r, w, b, epsilon, act), x, residual, weight, bias)
 
     def fn(a, r, w, b):
         h = a + r
@@ -256,12 +247,9 @@ def local_response_norm(x, size, alpha=1e-4, beta=0.75, k=1.0,
 def rms_norm(x, weight=None, epsilon=1e-6, name=None, fused=None):
     """RMSNorm (TPU-friendly LLM building block; also via pallas kernel)."""
     if _use_fused(fused):
-        try:
-            from paddle_tpu.ops.pallas.norm import fused_rms_norm
-            return apply(lambda v, w: fused_rms_norm(v, w, epsilon),
-                         x, weight)
-        except Exception:
-            pass
+        from paddle_tpu.ops.pallas.norm import fused_rms_norm
+        return apply(lambda v, w: fused_rms_norm(v, w, epsilon),
+                     x, weight)
 
     def fn(v, w):
         ms = jnp.mean(jnp.square(v.astype(jnp.float32)), axis=-1, keepdims=True)

@@ -1,9 +1,11 @@
 """Attention functionals.
 
 Reference: python/paddle/nn/functional/ (scaled_dot_product_attention appears
-in later paddle; incubate flash_attention). TPU-first: the hot path calls the
-Pallas flash-attention kernel (paddle_tpu/ops/pallas/flash_attention.py) when
-shapes allow; otherwise an XLA einsum softmax fallback (still MXU-bound).
+in later paddle; incubate flash_attention). TPU-first: where the Pallas
+kernels are the default (``ops.pallas.kernel_default``) unmasked, dropout-free
+attention calls the flash-attention kernel
+(paddle_tpu/ops/pallas/flash_attention.py); everything else is the XLA einsum
+softmax composition (still MXU-bound).
 """
 from __future__ import annotations
 
@@ -49,16 +51,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
                                  scale=None, name=None):
     """query/key/value: [batch, seq, num_heads, head_dim] (paddle convention)."""
+    from paddle_tpu.ops.pallas import kernel_default
     apply_dropout = dropout_p > 0.0 and training
-    use_flash = attn_mask is None and not apply_dropout
-    if use_flash:
-        try:
-            from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
-            return apply(lambda q, k, v: flash_attention_bshd(q, k, v, causal=is_causal,
-                                                              scale=scale),
-                         query, key, value)
-        except Exception:
-            pass
+    if attn_mask is None and not apply_dropout and kernel_default():
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+        return apply(lambda q, k, v: flash_attention_bshd(
+            q, k, v, causal=is_causal, scale=scale), query, key, value)
+
     def fn(q, k, v, m):
         key_ = next_key() if apply_dropout else None
         return _sdpa_ref(q, k, v, m, dropout_p if apply_dropout else 0.0,

@@ -43,7 +43,8 @@ import jax
 
 __all__ = [
     "ChipSpec", "CHIP_SPECS", "LayerCost", "RooflineReport",
-    "backward_scope", "current_scope", "default_chip", "eqn_cost",
+    "V5E", "attached_chip", "backward_scope", "chip_spec",
+    "current_scope", "eqn_cost",
     "kernel_interiors", "layer_scope", "normalize_scope",
     "profile_engine", "profile_static_function", "profile_traced",
     "reconcile", "scope", "scope_tagging", "set_scope_tagging",
@@ -205,27 +206,43 @@ class ChipSpec:
                 "vmem_mb": self.vmem_mb}
 
 
+# The chip the deterministic cost model prices programs for.  It is a
+# property of the model, not of the host that traces it: a CPU host
+# profiles *for* the v5e, and perfgate's baselines would move if this
+# followed the attached device.
+V5E = ChipSpec("TPU v5e", 197.0, 819.0)
+_V6E = ChipSpec("TPU v6e", 918.0, 1640.0)
+
+# Published per-chip peaks (Google Cloud TPU documentation, one page per
+# generation), keyed by the ``device_kind`` jax reports — a v5e says
+# "TPU v5 lite", a v6e "TPU v6 lite".
 CHIP_SPECS = {
-    "v4": ChipSpec("TPU v4", 275.0, 1228.0),
-    "v5e": ChipSpec("TPU v5e", 197.0, 819.0),
-    "v5p": ChipSpec("TPU v5p", 459.0, 2765.0),
-    "v6e": ChipSpec("TPU v6e", 918.0, 1640.0),
+    "TPU v4": ChipSpec("TPU v4", 275.0, 1228.0),
+    "TPU v5 lite": V5E,
+    "TPU v5e": V5E,
+    "TPU v5p": ChipSpec("TPU v5p", 459.0, 2765.0),
+    "TPU v6 lite": _V6E,
+    "TPU v6e": _V6E,
 }
 
 
-def default_chip():
-    """The chip the roofline classifies against: the attached device
-    kind when it names a known TPU, else v5e (the target platform) —
-    a CPU host profiles *for* the TPU, never against its own specs."""
+def chip_spec(device_kind):
+    """Peaks of the chip a measurement ran on.  A device that is not in
+    the table is an error, never a default."""
     try:
-        kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    except Exception:  # noqa: BLE001 — backend init must not kill a profile
-        kind = ""
-    kind = kind.lower().replace(" ", "").replace("lite", "e")
-    for key, spec in CHIP_SPECS.items():
-        if key in kind:
-            return spec
-    return CHIP_SPECS["v5e"]
+        return CHIP_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(CHIP_SPECS)}") from None
+
+
+def attached_chip():
+    """(device, ChipSpec) of the first attached device — what a device
+    measurement divides by.  Raises on a CPU host like on any device
+    whose kind has no published peaks."""
+    dev = jax.devices()[0]
+    return dev, chip_spec(dev.device_kind)
 
 
 # ----------------------------------------------------- per-eqn cost model
@@ -403,7 +420,7 @@ def _iter_eqns_rec(jaxpr):
             yield from _iter_eqns_rec(sub)
 
 
-def kernel_interiors(closed_jaxpr, chip=None):
+def kernel_interiors(closed_jaxpr, chip=V5E):
     """Opt-in per-kernel INTERIOR roofline rows — the dual of the
     call-boundary cost ``_walk`` books for ``pallas_call``.
 
@@ -415,7 +432,6 @@ def kernel_interiors(closed_jaxpr, chip=None):
     the kernel re-touches each HBM byte inside VMEM, i.e. exactly the
     reuse that justifies fusing (a factor near 1.0 means the kernel
     gains nothing over the unfused composition)."""
-    chip = chip or default_chip()
     from paddle_tpu.analysis.vmem_model import estimate_vmem
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     rows = []
@@ -605,14 +621,13 @@ class RooflineReport:
 
 
 # ---------------------------------------------------------- entry points
-def profile_traced(closed_jaxpr, where="<traced program>", chip=None,
+def profile_traced(closed_jaxpr, where="<traced program>", chip=V5E,
                    include_xla=False, include_interiors=False):
     """Roofline-profile one traced program: per-eqn cost model,
     attributed to the normalized ``jax.named_scope`` paths the layer
     tree threaded through tracing.  ``include_interiors=True`` adds the
     per-kernel INTERIOR rows (:func:`kernel_interiors`) next to the
     call-boundary attribution."""
-    chip = chip or default_chip()
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     sink = {}
     _walk(jaxpr, "", 1, sink)
@@ -632,7 +647,7 @@ def profile_traced(closed_jaxpr, where="<traced program>", chip=None,
     return rep
 
 
-def profile_static_function(fn, *args, where=None, chip=None,
+def profile_static_function(fn, *args, where=None, chip=V5E,
                             include_xla=False, **kwargs):
     """Profile one ``@to_static`` function's signature: traces (never
     compiles or runs) via :meth:`StaticFunction.traced_program` and
@@ -643,7 +658,7 @@ def profile_static_function(fn, *args, where=None, chip=None,
         chip=chip, include_xla=include_xla)
 
 
-def profile_engine(engine, chip=None, include_xla=False):
+def profile_engine(engine, chip=V5E, include_xla=False):
     """{program_name: RooflineReport} over every program the serving
     engine will ever compile (``LLMEngine.audit_programs()``)."""
     return {

@@ -46,14 +46,7 @@ def _vmem_spec(*args, **kwargs):
 
 
 def _compiler_params(dims):
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dims)
-            except Exception:  # pragma: no cover - API drift
-                continue
-    return None  # pragma: no cover
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def _round_up(n, m):
@@ -239,10 +232,9 @@ def _pick_blocks(sq, sk, block_q, block_k):
     return (min(block_q, _round_up(sq, 16)), min(block_k, _round_up(sk, 16)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_bhsd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return o
+    return _flash_block(q, k, v, causal, scale, block_q, block_k,
+                        interpret)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -303,15 +295,32 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return o, lse
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return o, (q, k, v, o, lse)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_block(q, k, v, causal, scale, block_q, block_k, interpret):
+    """Flash attention returning (o, lse); differentiable in both (ring
+    attention merges blocks by their lse)."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_block_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+    # through _flash_block, not the raw _flash_fwd: under nested
+    # differentiation (recompute's backward takes a vjp of a region whose
+    # ops each took their own) the outer trace then meets a custom_vjp
+    # call it can linearize, never a raw pallas_call it would have to JVP
+    o, lse = _flash_block(q, k, v, causal, scale, block_q, block_k,
+                          interpret)
+    return (o, lse), (q, k, v, o, lse)
+
+
+def _flash_block_bwd(causal, scale, block_q, block_k, interpret, res, cts):
     q, k, v, o, lse = res
+    do, dlse = cts
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                                    # [b,h,sq]
+    # rows that never saw a key (lse == _NEG_INF sentinel, which is a
+    # finite -1e30) have p == 0 everywhere — drop their lse cotangent
+    delta = delta - jnp.where(lse > _NEG_INF / 2,
+                              dlse.astype(jnp.float32), 0.0)
     return _flash_bwd_impl(q, k, v, do, lse, delta, causal, scale,
                            block_q, block_k, interpret)
 
@@ -415,7 +424,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
     return dq, dk, dv
 
 
-_flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_block.defvjp(_flash_block_fwd, _flash_block_bwd)
 
 
 def _on_tpu():
@@ -427,7 +436,7 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, block_q=None,
                          block_k=None, interpret=None):
     """Flash attention on [batch, seq, heads, head_dim] inputs (paddle
     layout). Differentiable (custom VJP). Raises on CPU unless
-    `interpret=True` — callers fall back to the XLA sdpa path."""
+    `interpret=True` — callers pick the XLA sdpa path there."""
     if interpret is None:
         interpret = False
         if not _on_tpu():

@@ -4,7 +4,8 @@ Reference parity: paddle/phi/kernels/gpu/layer_norm_kernel.cu (fused CUDA
 layernorm). TPU-native: one VMEM pass per row block — mean/var/normalize/
 affine fused in a single kernel (XLA already fuses these well; the kernel
 removes the leftover HBM round-trips between the reduction and the scale).
-Backward is the analytic formula in jnp (custom VJP) — fully fusible by XLA.
+The affine LayerNorm backward is a Pallas kernel too (custom VJP); RMSNorm
+and the weight-free LayerNorm use the analytic formula in jnp.
 """
 from __future__ import annotations
 
@@ -13,20 +14,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_ROWS = 128
+# per-block partial sums leave the kernel as one full f32 sublane tile
+# (the row sum replicated down 8 sublanes): Mosaic rejects a (1, hidden)
+# block of a (grid, hidden) array, and (8, hidden) is the smallest legal
+_PARTIAL_ROWS = 8
 
 
 def _vmem_spec(*args, **kwargs):
-    if _VMEM is not None:
-        kwargs["memory_space"] = _VMEM
+    kwargs["memory_space"] = pltpu.VMEM
     return pl.BlockSpec(*args, **kwargs)
 
 
@@ -218,8 +216,10 @@ def _ln_bwd_core(h, w, b, gy, gh, dx_ref, dwp_ref, dbp_ref, *, eps, act):
     if gh is not None:
         dx = dx + gh
     dx_ref[:] = dx.astype(dx_ref.dtype)
-    dwp_ref[:] = jnp.sum(gy * xhat, axis=0, keepdims=True)
-    dbp_ref[:] = jnp.sum(gy, axis=0, keepdims=True)
+    dwp_ref[:] = jnp.broadcast_to(
+        jnp.sum(gy * xhat, axis=0, keepdims=True), dwp_ref.shape)
+    dbp_ref[:] = jnp.broadcast_to(
+        jnp.sum(gy, axis=0, keepdims=True), dbp_ref.shape)
 
 
 def _ln_bwd_kernel_plain(h_ref, gy_ref, w_ref, b_ref, dx_ref, dwp_ref,
@@ -241,8 +241,8 @@ def _ln_bwd_kernel_res(h_ref, gy_ref, gh_ref, w_ref, b_ref, dx_ref,
 def _run_ln_multi(kernel, rows_in, vecs, rows_out_dtypes, n_partials,
                   block_rows, interpret):
     """Row-block kernel with several [rows, hidden] inputs/outputs plus
-    per-block (grid, hidden) f32 partial-sum outputs (summed by the
-    caller — the cross-block reduction is one tiny eqn)."""
+    per-block f32 partial-sum outputs, returned as (grid, hidden) and
+    summed by the caller — the cross-block reduction is one tiny eqn."""
     rows, hidden = rows_in[0].shape
     xp = [_pad_rows(a, block_rows) for a in rows_in]
     prows = xp[0].shape[0]
@@ -252,11 +252,12 @@ def _run_ln_multi(kernel, rows_in, vecs, rows_out_dtypes, n_partials,
     in_specs += [_vmem_spec((1, hidden), lambda i: (0, 0)) for _ in vecs]
     out_specs = [_vmem_spec((block_rows, hidden), lambda i: (i, 0))
                  for _ in rows_out_dtypes]
-    out_specs += [_vmem_spec((1, hidden), lambda i: (i, 0))
+    out_specs += [_vmem_spec((_PARTIAL_ROWS, hidden), lambda i: (i, 0))
                   for _ in range(n_partials)]
     out_shape = [jax.ShapeDtypeStruct((prows, hidden), dt)
                  for dt in rows_out_dtypes]
-    out_shape += [jax.ShapeDtypeStruct((grid[0], hidden), jnp.float32)
+    out_shape += [jax.ShapeDtypeStruct((grid[0] * _PARTIAL_ROWS, hidden),
+                                       jnp.float32)
                   for _ in range(n_partials)]
     outs = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
@@ -264,7 +265,7 @@ def _run_ln_multi(kernel, rows_in, vecs, rows_out_dtypes, n_partials,
     )(*xp, *[v[None, :] for v in vecs])
     n_rows_out = len(rows_out_dtypes)
     return ([o[:rows] for o in outs[:n_rows_out]]
-            + list(outs[n_rows_out:]))
+            + [o[::_PARTIAL_ROWS] for o in outs[n_rows_out:]])
 
 
 def _ln_bwd_pallas(h, weight, bias, gy, gh, eps, act, block_rows,
@@ -325,8 +326,11 @@ def _ln_res_fwd_impl(x, residual, weight, bias, eps, act, block_rows,
 
 def _ln_res_fwd_rule(x, residual, weight, bias, eps, act, block_rows,
                      interpret):
-    h, y = _ln_res_fwd_impl(x, residual, weight, bias, eps, act,
-                            block_rows, interpret)
+    # through the custom_vjp function, not the raw kernel: a nested
+    # differentiation (recompute's backward) must meet a call it can
+    # linearize, never a raw pallas_call it would have to JVP
+    h, y = fused_ln_residual(x, residual, weight, bias, eps, act,
+                             block_rows, interpret)
     # scalar zero sentinels carry the primal dtypes into the bwd rule
     # (residual pytree leaves must be jax values, not dtype objects)
     return (h, y), (h, weight, bias, jnp.zeros((), x.dtype),
@@ -364,8 +368,8 @@ def fused_add_layer_norm(x, residual, weight, bias, eps=1e-5, act=None,
 
 def _add_ln_fwd_rule(x, residual, weight, bias, eps, act, block_rows,
                      interpret):
-    h, y = _ln_res_fwd_impl(x, residual, weight, bias, eps, act,
-                            block_rows, interpret)
+    h, y = fused_ln_residual(x, residual, weight, bias, eps, act,
+                             block_rows, interpret)
     return y, (h, weight, bias, jnp.zeros((), x.dtype),
                jnp.zeros((), residual.dtype))
 

@@ -32,9 +32,11 @@ from paddle_tpu.ops.pallas.norm import _vmem_spec
 
 __all__ = ["fused_adam_update", "supports_fused"]
 
-# per-operand block-bytes ceiling: 7 live refs per grid step must fit
-# VMEM (~16 MB/core) with room for double buffering
-_BLOCK_BYTES = 1 << 21
+# per-operand block-bytes ceiling: a grid step holds 7 row blocks
+# (p, g, m, v in; p', m', v' out), each double-buffered by the pipeline,
+# inside Mosaic's 16 MiB scoped-VMEM default — 14 x 512 KiB = 7 MiB
+# leaves the rest for the f32 update temporaries
+_BLOCK_BYTES = 1 << 19
 
 
 def supports_fused(shape):
@@ -124,9 +126,9 @@ def fused_adam_update(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
     (f32, reduced in-kernel — the sentinel probe's zero-extra-read
     path) and each block's commit is gated on its own finiteness (the
     zero-update skip; docs/resilience.md "Numerics sentinel" has the
-    region-granularity contract).  The partials rows are 128 lanes
-    wide (the block scalar broadcast) to stay a legal TPU tile; the
-    caller reads column 0.
+    region-granularity contract).  The kernel writes each block scalar
+    as one full (8, 128) f32 tile — the smallest block Mosaic accepts —
+    of which one row per block is returned; the caller reads column 0.
     """
     if interpret is None:
         from paddle_tpu.ops.pallas import on_tpu
@@ -152,10 +154,10 @@ def fused_adam_update(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     ]
     if guard:
-        out_specs.append(_vmem_spec((1, 128), blk))
+        out_specs.append(_vmem_spec((8, 128), blk))
         out_shape.append(
-            jax.ShapeDtypeStruct((grid[0], 128), jnp.float32))
-    return pl.pallas_call(
+            jax.ShapeDtypeStruct((grid[0] * 8, 128), jnp.float32))
+    outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[_vmem_spec((1, 4), lambda i: (0, 0))]
@@ -167,3 +169,6 @@ def fused_adam_update(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
     )(sc, p, g, m, v)
+    if guard:
+        return (*outs[:3], outs[3][::8])
+    return outs

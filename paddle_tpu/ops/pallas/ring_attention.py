@@ -27,38 +27,10 @@ from jax import lax
 from paddle_tpu.ops.pallas.flash_attention import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
-    _flash_bwd_impl,
-    _flash_fwd,
+    _flash_block,
     _NEG_INF,
 )
 from paddle_tpu.distributed import mesh as mesh_mod
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_block(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Flash attention block returning (o, lse); differentiable in both."""
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-
-
-def _flash_block_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return (o, lse), (q, k, v, o, lse)
-
-
-def _flash_block_bwd(causal, scale, block_q, block_k, interpret, res, cts):
-    q, k, v, o, lse = res
-    do, dlse = cts
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    if dlse is not None and getattr(dlse, "dtype", None) != jax.dtypes.float0:
-        # rows that never saw a key (lse == _NEG_INF sentinel, which is a
-        # finite -1e30) have p == 0 everywhere — drop their lse cotangent
-        delta = delta - jnp.where(lse > _NEG_INF / 2,
-                                  dlse.astype(jnp.float32), 0.0)
-    return _flash_bwd_impl(q, k, v, do, lse, delta, causal, scale,
-                           block_q, block_k, interpret)
-
-
-_flash_block.defvjp(_flash_block_fwd, _flash_block_bwd)
 
 
 def _merge(o1, lse1, o2, lse2):

@@ -56,7 +56,7 @@ __all__ = ["collective_wire_bytes", "quantized_all_reduce",
 
 
 def _axis_size(axis_name):
-    """Static extent of a named mesh axis (jax_compat shims older jax)."""
+    """Static extent of a named mesh axis."""
     return int(lax.axis_size(axis_name))
 
 

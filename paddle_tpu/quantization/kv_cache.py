@@ -93,31 +93,23 @@ class KVQuantSpec:
         return jnp.dtype(self.code_dtype).itemsize
 
 
-def _fp8_qmax(dtype_name):
-    try:
-        return float(jnp.finfo(getattr(jnp, dtype_name)).max)
-    except (AttributeError, TypeError):  # dtype absent on this jax
-        return 0.0
-
-
-# int8 is always available; the fp8 entries exist only where this jax
-# exposes the dtype (resolve_kv_cache_dtype gives the actionable error)
 KV_CACHE_DTYPES = {
     "int8": KVQuantSpec("int8", "int8", 127.0, True),
+    "fp8_e4m3": KVQuantSpec(
+        "fp8_e4m3", "float8_e4m3fn",
+        float(jnp.finfo(jnp.float8_e4m3fn).max), False),
+    "fp8_e5m2": KVQuantSpec(
+        "fp8_e5m2", "float8_e5m2",
+        float(jnp.finfo(jnp.float8_e5m2).max), False),
 }
-for _name, _attr in (("fp8_e4m3", "float8_e4m3fn"),
-                     ("fp8_e5m2", "float8_e5m2")):
-    if hasattr(jnp, _attr):
-        KV_CACHE_DTYPES[_name] = KVQuantSpec(
-            _name, _attr, _fp8_qmax(_attr), False)
 
 
 def resolve_kv_cache_dtype(name):
     """Config string -> :class:`KVQuantSpec` (None passes through).
 
     Accepts ``None`` (un-quantized pools at ``EngineConfig.dtype``) or
-    one of :data:`KV_CACHE_DTYPES`.  Unknown names — including fp8 on a
-    jax without the dtype — raise with the supported set spelled out.
+    one of :data:`KV_CACHE_DTYPES`.  Unknown names raise with the
+    supported set spelled out.
     """
     if name is None or isinstance(name, KVQuantSpec):
         return name
@@ -125,8 +117,7 @@ def resolve_kv_cache_dtype(name):
     if spec is None:
         raise ValueError(
             f"kv_cache_dtype {name!r} is not supported here; choose "
-            f"None or one of {sorted(KV_CACHE_DTYPES)} (fp8 entries "
-            f"exist only when this jax exposes the dtype)")
+            f"None or one of {sorted(KV_CACHE_DTYPES)}")
     return spec
 
 

@@ -13,16 +13,21 @@ few files":
 - **Fingerprint.**  :func:`engine_fingerprint` hashes everything a
   compiled program's correctness depends on — model config, engine
   geometry (slots/pages/buckets/dtype), parameter tree (names, shapes,
-  dtypes — never values), mesh spec, jax/jaxlib versions, backend
-  platform, device kind and count.  Any component changing produces a
+  dtypes — never values), mesh spec, jax/jaxlib versions, and the
+  platform and kind of each device the programs run on
+  (:func:`program_devices`).  Any component changing produces a
   DIFFERENT fingerprint directory, so invalidation is structural: stale
   entries are never loaded, only orphaned (and reapable via
-  :meth:`AOTProgramCache.evict_stale`).
+  :meth:`AOTProgramCache.evict_stale`).  Device *ids* are not part of
+  it: replicas of one engine on different chips of one kind share a
+  family, and :meth:`AOTProgramCache.load` places the executable on the
+  loading engine's devices.
 - **Entries.**  One file per program
   (``<cache_dir>/<fingerprint>/<program>.jaxprog``), written atomically
   (tmp + rename, the resilience checkpoint discipline) and containing a
   versioned pickle of ``jax.experimental.serialize_executable``'s
-  ``(payload, in_tree, out_tree)`` triple.
+  ``(payload, in_tree, out_tree)`` triple plus the ids of the devices
+  it was compiled on.
 - **Degradation.**  A backend whose executables refuse serialization, a
   torn/corrupt entry, or a deserialize failure all degrade to a normal
   compile (recorded as a ``serving.aot_cache_miss`` span) — the cache
@@ -37,19 +42,21 @@ asserts both directions.
 from __future__ import annotations
 
 import hashlib
+import io
 import os
 import pickle
 import tempfile
 
 import jax
+from jax.experimental import serialize_executable as se
 
 from paddle_tpu.observability import span
 
-__all__ = ["AOTProgramCache", "engine_fingerprint"]
+__all__ = ["AOTProgramCache", "engine_fingerprint", "program_devices"]
 
 # bump when the on-disk entry layout changes; folded into every
 # fingerprint so old trees are orphaned wholesale, never half-read
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 
 
 def _mesh_desc(mesh):
@@ -58,16 +65,25 @@ def _mesh_desc(mesh):
     return tuple((str(a), int(s)) for a, s in mesh.shape.items())
 
 
+def program_devices(params, mesh=None):
+    """The devices an engine's programs are compiled for and run on, in
+    a stable order: its mesh's devices, else the one device its
+    parameters live on."""
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    return sorted(next(iter(params.values())).devices(),
+                  key=lambda d: d.id)
+
+
 def engine_fingerprint(model_config, engine_config, params, mesh=None):
     """Hex digest naming the compiled-program family of one engine.
 
-    `params` contributes structure only (sorted name/shape/dtype) —
-    weights can be hot-swapped under a fingerprint because XLA compiled
-    against their avals, not their values.
+    `params` contributes structure (sorted name/shape/dtype) and
+    placement, never values — weights can be hot-swapped under a
+    fingerprint because XLA compiled against their avals.
     """
     import jaxlib
 
-    devices = jax.devices()
     ec = engine_config
     material = {
         "format": FORMAT_VERSION,
@@ -88,12 +104,60 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None):
                    bool(getattr(ec, "guard", False))),
         "mesh": _mesh_desc(mesh),
         "jax": jax.__version__,
-        "jaxlib": getattr(jaxlib, "__version__", "?"),
-        "backend": jax.default_backend(),
-        "device_kind": getattr(devices[0], "device_kind", ""),
-        "n_devices": len(devices),
+        "jaxlib": jaxlib.__version__,
+        "devices": [(d.platform, d.device_kind)
+                    for d in program_devices(params, mesh)],
     }
     return hashlib.sha256(repr(material).encode()).hexdigest()[:24]
+
+
+class _Unpickler(se._JaxPjrtUnpickler):
+    """jax's executable unpickler, loading onto the devices it is given.
+
+    jax's own resolves the device references pickled inside the payload
+    (shardings) by id and loads the executable with the device
+    assignment it was compiled with.  A replica on another chip than
+    the one that compiled the entry needs both rebound: `stored_ids`
+    maps position for position onto the execution devices, and (`moved`)
+    the executable is loaded under compile options that carry the new
+    assignment (the override jax's compilation cache also loads with).
+    """
+
+    def __init__(self, file, devices, stored_ids, moved):
+        super().__init__(file, devices[0].client, devices)
+        self.devices_by_id = dict(zip(stored_ids, devices))
+        self.compile_options = None
+        if moved:
+            from jax._src import compiler
+            self.compile_options = compiler.get_compile_options(
+                num_replicas=1, num_partitions=1,
+                device_assignment=[[devices[0].id]], backend=self.backend)
+
+    def persistent_load(self, pid):
+        if pid[0] == "exec":
+            return self.backend.deserialize_executable(
+                pid[1], executable_devices=self.execution_devices,
+                compile_options=self.compile_options)
+        return super().persistent_load(pid)
+
+
+def _load_executable(payload, in_tree, out_tree, stored_ids, devices):
+    """``serialize_executable.deserialize_and_load`` onto `devices`
+    (jax's entry point loads for every device of the backend unless
+    told otherwise, so a one-device program reloaded on an N-device
+    host expects N shards).  Only a one-device program may move to a
+    device other than the one it was compiled on."""
+    ids = [d.id for d in devices]
+    moved = ids != list(stored_ids)
+    if len(ids) != len(stored_ids) or (moved and len(ids) > 1):
+        raise ValueError(
+            f"entry compiled for devices {list(stored_ids)}, engine "
+            f"runs on {ids}")
+    unloaded, args_info_flat, no_kwargs = _Unpickler(
+        io.BytesIO(payload), devices, stored_ids, moved).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], in_tree.unflatten(args_info_flat), out_tree,
+        no_kwargs=no_kwargs)
 
 
 class AOTProgramCache:
@@ -136,19 +200,21 @@ class AOTProgramCache:
             return []
 
     # ------------------------------------------------------------- load
-    def load(self, fingerprint, program):
-        """Deserialize one program; returns a callable
+    def load(self, fingerprint, program, devices):
+        """Deserialize one program onto `devices` (the loading engine's
+        :func:`program_devices`); returns a callable
         ``jax.stages.Compiled`` or None (miss / corrupt / unsupported).
         A corrupt entry is unlinked so the follow-up compile's store
         replaces it."""
         path = self._entry_path(fingerprint, program)
         try:
             with open(path, "rb") as fh:
-                version, payload, in_tree, out_tree = pickle.load(fh)
+                version, payload, in_tree, out_tree, stored_ids = \
+                    pickle.load(fh)
             if version != FORMAT_VERSION:
                 raise ValueError(f"format {version} != {FORMAT_VERSION}")
-            from jax.experimental import serialize_executable as se
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            compiled = _load_executable(payload, in_tree, out_tree,
+                                        stored_ids, devices)
         except FileNotFoundError:
             self.miss_count += 1
             return None
@@ -166,14 +232,14 @@ class AOTProgramCache:
         return compiled
 
     # ------------------------------------------------------------ store
-    def store(self, fingerprint, program, compiled):
-        """Serialize `compiled` under (fingerprint, program); returns
-        True on success.  Never raises — an unserializable backend or a
-        full disk degrades to "no cache", not a serving failure."""
+    def store(self, fingerprint, program, compiled, devices):
+        """Serialize `compiled` (built for `devices`) under
+        (fingerprint, program); returns True on success.  Never raises —
+        an unserializable backend or a full disk degrades to "no
+        cache", not a serving failure."""
         if not self._serialize_supported:
             return False
         try:
-            from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
         except Exception as e:
             # ValueError("Compilation does not support serialization")
@@ -191,7 +257,8 @@ class AOTProgramCache:
             try:
                 with os.fdopen(fd, "wb") as fh:
                     pickle.dump(
-                        (FORMAT_VERSION, payload, in_tree, out_tree), fh)
+                        (FORMAT_VERSION, payload, in_tree, out_tree,
+                         [d.id for d in devices]), fh)
                 os.replace(tmp, entry)
             except BaseException:
                 try:

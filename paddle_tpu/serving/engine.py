@@ -34,6 +34,7 @@ unchanged on TPU.
 """
 from __future__ import annotations
 
+import functools
 import time
 import weakref
 from collections import OrderedDict
@@ -94,8 +95,8 @@ class EngineConfig:
       every program lowers as one SPMD computation over the mesh.
       `num_heads` must divide by the tp extent.
     - `kv_cache_dtype`: None (pools stored at `dtype`) or a
-      quantization code dtype ("int8", and "fp8_e4m3"/"fp8_e5m2" where
-      this jax has the dtype) — the per-layer pools become
+      quantization code dtype ("int8", "fp8_e4m3", "fp8_e5m2") — the
+      per-layer pools become
       per-page-scaled ``(codes, scales)`` pairs
       (paddle_tpu/quantization/kv_cache.py; docs/quantization.md has
       the storage format and the tolerance contract).  Activations and
@@ -298,6 +299,9 @@ class LLMEngine:
             self._params = {k: jax.device_put(
                 v, self._param_sharding(v))
                 for k, v in self._params.items()}
+        elif self._device is not None:
+            self._params = {k: jax.device_put(v, self._device)
+                            for k, v in self._params.items()}
 
         B, P = cfg.max_num_seqs, cfg.max_pages_per_seq
         pool_shape = (cfg.num_pages, self._num_heads, cfg.page_size,
@@ -306,15 +310,17 @@ class LLMEngine:
         # are (codes, scales) pairs with one f32 scale per (page, head)
         self._kv_quant = resolve_kv_cache_dtype(cfg.kv_cache_dtype)
 
+        # allocated where they live (device= takes a sharding too): a
+        # pinned replica must not stage its pools through device 0
+        pool_dev = self._pool_sharding or self._device
+
         def _pool():
             if self._kv_quant is None:
-                return self._place(jnp.zeros(pool_shape, cfg.dtype),
-                                   self._pool_sharding)
-            return (self._place(jnp.zeros(pool_shape,
-                                          self._kv_quant.code_dtype),
-                                self._pool_sharding),
-                    self._place(jnp.zeros(pool_shape[:2], jnp.float32),
-                                self._pool_sharding))
+                return jnp.zeros(pool_shape, cfg.dtype, device=pool_dev)
+            return (jnp.zeros(pool_shape, self._kv_quant.code_dtype,
+                              device=pool_dev),
+                    jnp.zeros(pool_shape[:2], jnp.float32,
+                              device=pool_dev))
 
         self._k_pools = [_pool() for _ in range(self._num_layers)]
         self._v_pools = [_pool() for _ in range(self._num_layers)]
@@ -408,12 +414,12 @@ class LLMEngine:
         all programs then lower as SPMD computations over the mesh.
         A ``{"tp": n}`` dict builds a mesh over the first n devices
         (virtual CPU devices in tests, real chips on TPU).
+
+        A mesh of ONE device is not an SPMD program: it resolves to the
+        off-mesh engine pinned to that device (``self._device``) — how
+        the router puts replica i on chip i.  ``mesh=None`` leaves
+        placement to JAX's default device.
         """
-        if mesh is None:
-            self._mesh = None
-            self._repl_sharding = None
-            self._pool_sharding = None
-            return
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
         if isinstance(mesh, dict):
             axes = tuple(mesh.keys())
@@ -427,6 +433,15 @@ class LLMEngine:
                     f"mesh {dict(mesh)} needs {n} devices but only "
                     f"{len(devices)} are visible")
             mesh = Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+        self._device = None
+        if mesh is not None and mesh.devices.size == 1:
+            self._device = mesh.devices.flat[0]
+            mesh = None
+        if mesh is None:
+            self._mesh = None
+            self._repl_sharding = None
+            self._pool_sharding = None
+            return
         tp = int(mesh.shape.get("tp", 1))
         if tp > 1 and self._num_heads % tp:
             raise ValueError(
@@ -461,15 +476,16 @@ class LLMEngine:
         return self._repl_sharding
 
     def _place(self, value, sharding=None):
-        """Device placement for program operands: plain ``asarray``
-        off-mesh; an explicit mesh placement (replicated by default) on
-        the mesh, so every input of an SPMD program lives on the same
-        device set."""
+        """Device placement for program operands: this engine's device
+        off-mesh (JAX's default when it was given none); an explicit
+        mesh placement (replicated by default) on the mesh, so every
+        input of an SPMD program lives on the same device set."""
+        if not isinstance(value, jax.Array):
+            value = np.asarray(value)
         if self._mesh is None:
-            return jnp.asarray(value)
-        return jax.device_put(np.asarray(value) if not isinstance(
-            value, jax.Array) else value,
-            sharding if sharding is not None else self._repl_sharding)
+            return jax.device_put(value, self._device)
+        return jax.device_put(
+            value, sharding if sharding is not None else self._repl_sharding)
 
     @property
     def program_fingerprint(self):
@@ -1168,7 +1184,7 @@ class LLMEngine:
         recovery is token-identical for every surviving request.
 
         A full batch of consecutive faults (streak > max_num_seqs)
-        means the fault is NOT request-local (wedged device, poisoned
+        means the fault is NOT request-local (hung device, poisoned
         weights) — rethrow rather than spin forever."""
         live = [r for r in self._slots if r is not None]
         self._decode_fault_streak += 1
@@ -1577,8 +1593,10 @@ class LLMEngine:
         and persists the result for the next replica."""
         prog_name = "/".join(str(p) for p in key)
         if self._program_cache is not None:
+            from paddle_tpu.serving.aot_cache import program_devices
+            devices = program_devices(self._params, self._mesh)
             compiled = self._program_cache.load(self._program_fp,
-                                               prog_name)
+                                               prog_name, devices)
             if compiled is not None:
                 with span("serving.aot_load", program=str(key),
                           fingerprint=self._program_fp):
@@ -1587,9 +1605,13 @@ class LLMEngine:
                 self._compiled[key] = compiled
                 return compiled
 
+        pinned = (None if self._device is None
+                  else jax.sharding.SingleDeviceSharding(self._device))
+
         def _struct(a):
             if self._mesh is None:
-                return jax.ShapeDtypeStruct(a.shape, a.dtype)
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=pinned)
             sh = getattr(a, "sharding", None)
             if not isinstance(sh, jax.sharding.NamedSharding):
                 sh = self._repl_sharding    # host-built example operand
@@ -1601,6 +1623,13 @@ class LLMEngine:
         jit_kw = {"donate_argnums": donate}
         if out_shardings is not None:
             jit_kw["out_shardings"] = out_shardings
+        if self._program_cache is not None:
+            # the store below needs an executable that has never run
+            # (XLA:CPU refuses to serialize one whose sort has
+            # executed), and jax's lowering cache would hand the
+            # module-level sampler function the executable an earlier
+            # engine already ran — a fresh wrapper compiles a fresh one
+            fn = functools.partial(fn)
         t0 = time.perf_counter()
         with span("serving.compile", program=str(key)):
             compiled = jax.jit(fn, **jit_kw).lower(
@@ -1621,5 +1650,5 @@ class LLMEngine:
         self._compiled[key] = compiled
         if self._program_cache is not None:
             self._program_cache.store(self._program_fp, prog_name,
-                                      compiled)
+                                      compiled, devices)
         return compiled

@@ -146,7 +146,7 @@ class ServingFleet:
                        respawn=bool(respawn))
         # verdicts held for the boot window: the worker goes silent
         # while it builds its engine, and a spurious terminal DEAD
-        # mid-boot would wedge the rank forever (the rendezvous
+        # mid-boot would hang the rank forever (the rendezvous
         # deadline below still bounds a boot that never completes)
         self.monitor.hold_verdict(
             rank, self.config.fleet_config.rendezvous_timeout_s)

@@ -191,7 +191,7 @@ class DisaggregatedEngine:
                         getattr(req, "finish_reason", None), "decode")
                     live_decode.discard(drid)
             # a full round with no event anywhere means the split is
-            # wedged (e.g. decode forever refusing imports) — fail
+            # stuck (e.g. decode forever refusing imports) — fail
             # loudly rather than spin
             stall = 0 if progressed else stall + 1
             if stall > 1024:

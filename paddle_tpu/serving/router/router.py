@@ -79,7 +79,7 @@ class RouterConfig:
       rotation with its whole program ladder ready (and the boot time
       measured cold-vs-warm).
     - `stall_rounds`: consecutive event-free step rounds before
-      :meth:`Router.generate` declares the fleet wedged instead of
+      :meth:`Router.generate` declares the fleet stuck instead of
       spinning forever.
     - `sleep`: injectable backoff sleeper (tests pass a no-op).
     """
@@ -173,13 +173,28 @@ class Router:
                 raise ValueError(
                     "pass a model (with optional engine_config) or an "
                     "engine_factory")
+            import copy
+
+            import jax
+            import numpy as np
+
             from paddle_tpu.serving.aot_cache import AOTProgramCache
-            from paddle_tpu.serving.engine import LLMEngine
+            from paddle_tpu.serving.engine import EngineConfig, LLMEngine
             if isinstance(program_cache, str):
                 program_cache = AOTProgramCache(program_cache)
 
             def engine_factory(index):
-                return LLMEngine(model, engine_config,
+                cfg = engine_config or EngineConfig()
+                if cfg.mesh is None:
+                    # one chip per replica: replica i lives on device i
+                    # (wrapping when replicas outnumber devices), not
+                    # all of them on the default device
+                    devices = jax.devices()
+                    cfg = copy.copy(cfg)
+                    cfg.mesh = jax.sharding.Mesh(
+                        np.asarray([devices[index % len(devices)]]),
+                        ("tp",))
+                return LLMEngine(model, cfg,
                                  program_cache=program_cache,
                                  clock=clock)
 

@@ -14,7 +14,7 @@ the global mesh.  The worker proves the multi-host path end to end:
   path: ``multihost_utils.process_allgather`` + reduce).
 
 Results are written as one JSON file per rank (argv[1] is the output
-directory); the parent asserts on them — a crashed or wedged worker
+directory); the parent asserts on them — a crashed or hung worker
 simply never writes its file.
 """
 import json

@@ -22,9 +22,9 @@ Covers the three HBM-roofline fronts and their satellites:
 - **perfgate**: ratchet semantics (an improvement without
   --write-baseline still PASSES and prints the ratchet prompt) and the
   ``--diff`` table; the remat bench lane's honest keys.
-- **bench.py probe reaping**: a deadlined probe's process GROUP is
-  killed (stub sleeper with a child — both die), per the BENCH_r05
-  "left running, not killed" leak.
+- **bench.py worker limits**: a worker past its time limit is killed
+  with its whole process GROUP (stub sleeper with a child — both die)
+  and reported as a failed lane.
 - **serving token identity**: fused-LN serving produces tokens
   identical to the unfused engine, request for request.
 """
@@ -567,42 +567,37 @@ class TestOptimizedTargetContracts:
         assert any(n.endswith("/ln2") for n in names), names
 
 
-# ------------------------------------------------- bench probe reap
-class TestBenchProbeKill:
-    def test_timeout_kills_probe_process_group(self, tmp_path):
+# ------------------------------------------------- bench worker limit
+class TestBenchWorkerLimit:
+    def test_limit_kills_worker_process_group(self, tmp_path):
         """Stub sleeper: a parent that spawns a child then sleeps —
-        after the deadline, _kill_process_group must take down BOTH
-        (the BENCH_r05 leak was the whole point: 'left running, not
-        killed')."""
+        past the limit, _finish must take down BOTH (the worker runs in
+        its own session, so its children die with it) and report the
+        lane as failed."""
         bench = _load_bench()
-        out = tmp_path / "probe.out"
         pidfile = tmp_path / "child.pid"
-        # child pid goes to a SIDE file: stdout is the JSON channel
-        # _await_json reads, and a bare pid line would parse as JSON
         code = ("import subprocess,sys,time\n"
                 "c=subprocess.Popen([sys.executable,'-c',"
                 "'import time;time.sleep(120)'])\n"
                 f"open({str(pidfile)!r},'w').write(str(c.pid))\n"
+                "print('{\"partial\": 1}', flush=True)\n"
                 "time.sleep(120)\n")
-        with open(out, "w") as fh:
-            proc = subprocess.Popen([sys.executable, "-c", code],
-                                    stdout=fh,
-                                    stderr=subprocess.DEVNULL,
-                                    start_new_session=True)
-        proc._ptpu_outpath = str(out)
+        proc = subprocess.Popen([sys.executable, "-c", code],
+                                stdout=subprocess.PIPE, text=True,
+                                start_new_session=True)
         try:
-            res, err, exited = bench._await_json(proc, 1.0)
-            assert res is None and not exited
             # wait for the child pid to appear so the group is complete
-            for _ in range(50):
+            for _ in range(100):
                 if pidfile.exists() and pidfile.read_text().strip():
                     break
                 time.sleep(0.1)
             child_pid = int(pidfile.read_text().strip())
-            assert bench._kill_process_group(proc)
+            rc, result = bench._finish(proc, 1.0)
+            # a killed worker's partial output is not a result
+            assert (rc, result) == (124, None)
             assert proc.poll() is not None
             # the CHILD must be gone too (process-group kill, not a
-            # parent-only kill that orphans the claim holder)
+            # parent-only kill that orphans it)
             for _ in range(50):
                 try:
                     os.kill(child_pid, 0)
@@ -621,12 +616,18 @@ class TestBenchProbeKill:
             except (OSError, ProcessLookupError):
                 pass
 
-    def test_kill_process_group_on_exited_proc_is_false(self):
+    def test_worker_exit_code_and_result_propagate(self):
         bench = _load_bench()
-        proc = subprocess.Popen([sys.executable, "-c", "pass"],
-                                start_new_session=True)
-        proc.wait()
-        assert bench._kill_process_group(proc) is False
+
+        def run(code):
+            return bench._finish(subprocess.Popen(
+                [sys.executable, "-c", code], stdout=subprocess.PIPE,
+                text=True, start_new_session=True), 30.0)
+
+        assert run("print('noise'); print('{\"k\": 2}')") == (0, {"k": 2})
+        assert run("import sys; print('{\"k\": 2}'); sys.exit(3)") == \
+            (3, None)
+        assert run("print('no json here')") == (1, None)
 
 
 # ------------------------------------------- serving token identity

@@ -23,7 +23,7 @@ test_resilience.py + test_fleet.py).
 
 Kept deliberately small (1 CPU device per child, tiny collectives)
 so the wall cost is coordinator startup, not compute; generous
-deadlines absorb slow CI boxes, and failure modes (port clash, wedged
+deadlines absorb slow CI boxes, and failure modes (port clash, hung
 rendezvous) surface as missing result files with captured child logs.
 """
 import json
@@ -90,7 +90,7 @@ def test_two_process_all_reduce_via_launch(tmp_path):
                 out, _ = p.communicate()
                 pytest.fail(
                     f"rank {rank} did not finish within {DEADLINE_S}s "
-                    f"— coordinator rendezvous wedged?\n--- child log "
+                    f"— coordinator rendezvous hung?\n--- child log "
                     f"---\n{out[-2000:]}")
             outputs[rank] = out
             assert p.returncode == 0, (

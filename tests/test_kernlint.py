@@ -625,7 +625,10 @@ def test_profile_traced_interiors_opt_in_and_roundtrip():
 def test_chip_spec_carries_vmem_budget():
     from paddle_tpu.observability import profile
 
-    spec = profile.default_chip()
+    spec = profile.chip_spec("TPU v5 lite")
+    assert spec is profile.V5E
+    with pytest.raises(ValueError, match="no published peaks"):
+        profile.chip_spec("cpu")
     assert spec.vmem_mb == 16.0
     assert spec.vmem_bytes == 16 << 20
     assert spec.to_dict()["vmem_mb"] == 16.0

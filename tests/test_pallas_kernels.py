@@ -95,3 +95,51 @@ class TestFusedNorms:
         for a, b_ in zip(g, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=1e-4, atol=1e-4)
+
+
+class TestKernelsUnderRecompute:
+    """recompute()'s backward takes a vjp of a region whose ops each took
+    their own vjp on the tape.  That outer differentiation must meet the
+    kernels' custom_vjp calls, never a raw pallas_call (which has no usable
+    JVP): the first GPT train step on a chip died exactly there, and the
+    CPU gate never saw it because attention runs the XLA path off-TPU."""
+
+    def test_recompute_grads_match_plain(self):
+        import paddle_tpu as P
+        import paddle_tpu.nn.functional as F
+        from paddle_tpu.core.dispatch import apply
+        from paddle_tpu.distributed.recompute import recompute
+
+        class Block(P.nn.Layer):
+            def __init__(self):
+                super().__init__()
+                self.ln1 = P.nn.LayerNorm(32)
+                self.ln2 = P.nn.LayerNorm(32)
+                self.qkv = P.nn.Linear(32, 96)
+
+            def forward(self, x):
+                b, s, h = x.shape
+                n = F.layer_norm(x, 32, self.ln1.weight, self.ln1.bias,
+                                 fused=True)
+                q, k, v = (t.reshape([b, s, 2, 16])
+                           for t in self.qkv(n).split(3, axis=-1))
+                a = apply(lambda q, k, v: flash_attention_bshd(
+                    q, k, v, causal=True, interpret=True), q, k, v)
+                x, y = F.fused_ln_residual(
+                    a.reshape([b, s, h]), x, self.ln2.weight,
+                    self.ln2.bias, fused=True)
+                return x + y
+
+        def grads(use_recompute):
+            P.seed(0)
+            blk = Block()
+            x = P.to_tensor(np.random.default_rng(0).standard_normal(
+                (2, 16, 32)).astype(np.float32))
+            x.stop_gradient = False
+            out = recompute(blk, x) if use_recompute else blk(x)
+            (out ** 2).mean().backward()
+            return [x.grad.numpy()] + [p.grad.numpy()
+                                       for p in blk.parameters()]
+
+        for got, want in zip(grads(True), grads(False)):
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)

@@ -776,7 +776,7 @@ class TestServingFleet:
     def test_monitor_hold_verdict_spans_boot_silence(self):
         """A rank mid-boot goes beat-silent for longer than
         dead_after_s; the boot-phase hold must cap it at SUSPECT
-        (DEAD is terminal — a spurious verdict would wedge the rank
+        (DEAD is terminal — a spurious verdict would hang the rank
         forever), and releasing the hold restarts the staleness clock
         so the first post-boot beat is not raced by leftover age."""
         kv = fleet.LocalKVClient()

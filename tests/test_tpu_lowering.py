@@ -59,6 +59,23 @@ class TestFlashAttention:
                                         1024, 1024, False),
             _sd((b, h, s, d)), _sd((b, h, s, d)), _sd((b, h, s, d)))
 
+    def test_gpt355m_fwd_bwd_lowers(self):
+        """The shape GPT-355M trains at (batch 4, 16 heads of 64, seq
+        2048), through the public entry with the default blocks."""
+        from paddle_tpu.ops.pallas.flash_attention import (
+            flash_attention_bhsd)
+
+        shape = (4, 16, 2048, 64)
+
+        def f(q, k, v):
+            return jnp.sum(flash_attention_bhsd(
+                q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+        txt = _lower_tpu(jax.grad(f, argnums=(0, 1, 2)),
+                         _sd(shape), _sd(shape), _sd(shape))
+        for kernel in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+            assert kernel in txt, f"{kernel} missing from the lowering"
+
 
 class TestNorms:
     def test_layer_norm_lowers(self):
@@ -75,6 +92,55 @@ class TestNorms:
         _lower_tpu(lambda x, w: fused_rms_norm(x, w, 1e-6, None, False),
                    _sd((256, 1024), jnp.float32),
                    _sd((1024,), jnp.float32))
+
+    # the backward kernels write per-block partial sums: a multi-block
+    # shape (8192 rows = 64 blocks) is what exposes an illegal block
+    _ROWS, _HIDDEN = 8192, 1024
+
+    def test_layer_norm_bwd_lowers(self):
+        from paddle_tpu.ops.pallas.norm import fused_layer_norm
+
+        def f(x, w, b):
+            return jnp.sum(fused_layer_norm(x, w, b, 1e-5, None, False)
+                           .astype(jnp.float32))
+
+        txt = _lower_tpu(jax.grad(f, argnums=(0, 1, 2)),
+                         _sd((self._ROWS, self._HIDDEN)),
+                         _sd((self._HIDDEN,), jnp.float32),
+                         _sd((self._HIDDEN,), jnp.float32))
+        assert "_ln_bwd_kernel_plain" in txt
+
+    @pytest.mark.parametrize("act", [None, "gelu"])
+    def test_ln_residual_bwd_lowers(self, act):
+        from paddle_tpu.ops.pallas.norm import fused_ln_residual
+
+        def f(x, r, w, b):
+            h, y = fused_ln_residual(x, r, w, b, 1e-5, act, None, False)
+            return jnp.sum(h.astype(jnp.float32)) + jnp.sum(
+                y.astype(jnp.float32))
+
+        txt = _lower_tpu(jax.grad(f, argnums=(0, 1, 2, 3)),
+                         _sd((self._ROWS, self._HIDDEN)),
+                         _sd((self._ROWS, self._HIDDEN)),
+                         _sd((self._HIDDEN,), jnp.float32),
+                         _sd((self._HIDDEN,), jnp.float32))
+        assert "_ln_res_kernel" in txt and "_ln_bwd_kernel_res" in txt
+
+
+class TestFusedAdam:
+    @pytest.mark.parametrize("guard", [False, True])
+    def test_adam_update_lowers(self, guard):
+        from paddle_tpu.ops.pallas.optim import fused_adam_update
+
+        def f(p, g, m, v, lr):
+            return fused_adam_update(
+                p, g, m, v, lr, 0.1, 0.001, beta1=0.9, beta2=0.999,
+                eps=1e-8, weight_decay=0.01, guard=guard, interpret=False)
+
+        shape = (1024, 4096)          # a multi-block GPT-355M fc1 weight
+        txt = _lower_tpu(f, _sd(shape, jnp.float32), _sd(shape),
+                         _sd(shape), _sd(shape), _sd((), jnp.float32))
+        assert "_adam_kernel" in txt
 
 
 class TestRingBlocks:

@@ -6,26 +6,19 @@ Mosaic compiler on an actual TPU chip. Round 2 shipped a kernel that passed
 every interpret-mode test and died on silicon with a tiling error — this
 suite exists so that class of bug fails in CI, not in the benchmark.
 
-Run: `python -m pytest tests_tpu/ -q` on a host with a TPU attached.
-The whole suite auto-skips when no TPU backend is available.
+Run: `python -m pytest tests_tpu/ -q` on a machine with a TPU. Without one —
+or with one that fails to initialise — the run is an error, never a green
+column of skips.
 """
+import jax
 import pytest
 
-
-def _tpu_available():
-    try:
-        import jax
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
-_HAS_TPU = _tpu_available()
-
-
-def pytest_collection_modifyitems(config, items):
-    if _HAS_TPU:
-        return
-    skip = pytest.mark.skip(reason="no TPU backend available")
-    for item in items:
-        item.add_marker(skip)
+def pytest_sessionstart(session):
+    platform = jax.devices()[0].platform     # raises if the TPU fails to init
+    if platform != "tpu":
+        raise pytest.UsageError(
+            f"tests_tpu/ needs a TPU backend; jax found {platform!r}")
+    enable_compile_cache()

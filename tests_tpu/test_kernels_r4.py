@@ -2,8 +2,9 @@
 never been compiled on silicon — Pallas ring attention blocks, the int8
 quantized-linear MXU dot, and the fused incubate ops.
 
-Auto-skips off-TPU (conftest). These run the REAL Mosaic compiler / MXU
-int8 path; interpret-mode passes do not count (the r2 lesson).
+These run the REAL Mosaic compiler / MXU int8 path; interpret-mode
+passes do not count (the r2 lesson). Without a TPU the suite errors
+(conftest).
 """
 import numpy as np
 import pytest

@@ -1,5 +1,5 @@
 """Compiled-mode (real TPU) tests for the r5 surfaces: sparse conv
-gather paths and the ERNIE bench lane model. Auto-skip off-TPU."""
+gather paths and the ERNIE bench lane model."""
 import numpy as np
 
 import paddle_tpu as P

@@ -3,8 +3,8 @@
 The stdlib-only analyzers (tracelint's AST pass, racelint) must import
 `paddle_tpu.analysis` WITHOUT executing the real paddle_tpu/__init__.py
 (which imports jax) — the gates have to stay fast enough to run on
-every CI invocation, and a wedged accelerator claim must not hang a
-lint.  Installing a bare package module with the right ``__path__``
+every CI invocation, and a lint has no business taking the chip.
+Installing a bare package module with the right ``__path__``
 lets submodule imports resolve normally.  No-op when paddle_tpu is
 already imported (e.g. under pytest).
 """

@@ -64,8 +64,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(1, os.path.join(REPO, "tools"))
 
-# static analysis must never claim (or wedge on) the TPU: every target
-# traces in interpret mode, so the CPU backend is always right here
+# static analysis never takes the chip: every target traces in
+# interpret mode, so the CPU backend is always right here
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 DEFAULT_BASELINE = os.path.join(REPO, "tools", "kernlint_baseline.json")

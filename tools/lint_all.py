@@ -162,7 +162,7 @@ def main(argv=None):
         t0 = time.time()
         budget = _GATE_TIMEOUT_S.get(name, _DEFAULT_TIMEOUT_S)
         try:
-            # a wedged backend init must FAIL the gate, not hang CI
+            # a gate that hangs must FAIL, not hang CI
             proc = subprocess.run(cmd, cwd=REPO, capture_output=True,
                                   text=True, timeout=budget)
         except subprocess.TimeoutExpired:

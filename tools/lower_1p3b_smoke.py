@@ -1,8 +1,8 @@
 """TPU-lowering smoke of the 1.3B-shaped GPT train step on the CPU host:
 2 layers at full width (hidden 2048, seq 2048, 50304 vocab, bf16 params,
-bf16 moments, remat, fused chunked CE) exported for platform=tpu — the
-wedge-safe pre-check before the watcher runs the 24-layer compile on
-silicon."""
+bf16 moments, remat, fused chunked CE) exported for platform=tpu — a
+dialect-level pre-check that costs no chip time before the 24-layer
+compile runs there."""
 import numpy as np
 import jax
 from jax import export

@@ -58,8 +58,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(1, os.path.join(REPO, "tools"))
 
-# static analysis must never claim (or wedge on) the TPU: the audit is
-# shape-only, so the CPU backend is always the right one here
+# static analysis never takes the chip: the audit is shape-only, so
+# the CPU backend is always the right one here
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 DEFAULT_BASELINE = os.path.join(REPO, "tools", "numlint_baseline.json")

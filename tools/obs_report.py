@@ -43,7 +43,7 @@ Usage:
 The demo compiles the tiny-config GPT hybrid train step, perturbs ONE
 input's shape to force a retrace, and shows the resulting recompile
 event naming the perturbed argument — the "why did this recompile"
-workflow end to end (CPU-only; never touches a TPU claim).
+workflow end to end (CPU-only; never touches the chip).
 """
 from __future__ import annotations
 
@@ -112,6 +112,7 @@ def live_roofline():
     train_step(ids, labels)                 # warm step 2
     jaxpr, _ = train_step.traced_program(ids, labels)
     report = profile.profile_traced(jaxpr, where="<gpt_hybrid_train>",
+                                    chip=profile.V5E,
                                     include_interiors=True)
     return profile.reconcile(report, "jit.train_step")
 

@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """perfgate — machine-checked perf budgets from DETERMINISTIC cost models.
 
-BENCH wall-times depend on the host, the chip, and the claim being up —
-a CI gate can't block on them.  What IS stable run-to-run is the cost
+BENCH wall-times depend on the host and the chip — a CI gate can't
+block on them.  What IS stable run-to-run is the cost
 model: the roofline profiler's analytic bytes/flops per traced step
 (observability.profile), the shardlint liveness/padding estimates
 (analysis.cost_audit), and the serving engine's declared lifetime
 compile bound.  perfgate traces the flagship programs on CPU (no
-compile, no TPU claim), extracts those numbers, and compares them
+compile, no chip), extracts those numbers, and compares them
 against the checked-in baseline (tools/perf_baseline.json) — so every
 future bytes/step optimization (ROADMAP item 5: bf16 activations,
 fused optimizer, Pallas LN) lands against a machine-checked budget
@@ -40,7 +40,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 # the gate is trace-only (shape-level): the CPU backend is always the
-# right one — a wedged TPU claim must never hang CI
+# right one, and a gate has no business taking the chip
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 DEFAULT_BASELINE = os.path.join(REPO, "tools", "perf_baseline.json")
@@ -110,7 +110,8 @@ def gpt_roofline_report(optimized=True, remat=None, guard=False):
                                                    remat=remat,
                                                    guard=guard)
     jaxpr, infos = train_step.traced_program(ids, labels)
-    report = profile.profile_traced(jaxpr, where="<gpt_hybrid_train>")
+    report = profile.profile_traced(jaxpr, where="<gpt_hybrid_train>",
+                                    chip=profile.V5E)
     _findings, cost = audit_memory(jaxpr, where="<gpt_hybrid_train>",
                                    inputs=infos)
     return report, cost
@@ -178,7 +179,7 @@ def target_serving():
         serving.EngineConfig(max_num_seqs=4, page_size=8, max_model_len=64,
                              prefill_buckets=(16, 32)))
     try:
-        reports = profile.profile_engine(engine)
+        reports = profile.profile_engine(engine, chip=profile.V5E)
         decode = reports.get("decode")
         return {
             "compile_bound": engine.config.compile_bound,

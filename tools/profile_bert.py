@@ -2,7 +2,7 @@
 
 Answers "where does the other ~70% of MFU go" with data rather than
 guesswork: XLA cost analysis of the compiled step gives flops and HBM
-bytes; bytes/step over the measured step time vs the ~819 GB/s v5e HBM
+bytes; bytes/step over the measured step time vs the chip's HBM peak
 tells whether the step is bandwidth-bound (like ResNet) or occupancy-
 bound; the dot-shape census from the compiled HLO shows how much of the
 time sits in GEMMs too narrow to fill the 128x128 MXU.
@@ -20,8 +20,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-HBM_GBPS = 819.0   # v5e
 
 
 def _fused_bert(P, cfg):
@@ -72,11 +70,15 @@ def main():
                          "mfu 0.35 push)")
     args = ap.parse_args()
 
-    import jax
-
     import paddle_tpu as P
     import paddle_tpu.nn.functional as F
     from paddle_tpu.models.bert import BertConfig, BertForPretraining
+    from paddle_tpu.observability.profile import attached_chip
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    dev, chip = attached_chip()            # no TPU, unknown kind: error
+    print(f"device: {dev.platform} {dev.device_kind}", flush=True)
+    enable_compile_cache()
 
     P.seed(0)
     cfg = BertConfig(dropout=0.0, attention_dropout=0.0)
@@ -109,32 +111,27 @@ def main():
     loss = train_step(ids, labels)
     loss.block_until_ready()
 
-    flops = bytes_acc = None
-    try:
-        entry = next(iter(train_step._compiled.values())); jitted, state_list = entry.jitted, entry.state_list
-        compiled = jitted.lower([t._value for t in state_list],
-                                [ids._value, labels._value]).compile()
-        cost = compiled.cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-        flops = float(cost.get("flops", 0.0))
-        bytes_acc = float(cost.get("bytes accessed", 0.0))
-        print(f"xla flops/step: {flops:.3e}  bytes/step: {bytes_acc:.3e}")
-        # dot-shape census: which GEMM shapes carry the flops
-        hlo = compiled.as_text()
-        shapes = {}
-        for m in re.finditer(
-                r"= (bf16|f32)\[([0-9,]+)\][^=]*? dot\(", hlo):
-            key = f"{m.group(1)}[{m.group(2)}]"
-            shapes[key] = shapes.get(key, 0) + 1
-        top = sorted(shapes.items(), key=lambda kv: -kv[1])[:12]
-        print("dot output shapes (count):")
-        for k, c in top:
-            print(f"  {c:4d}x {k}")
-        print("fusions:", hlo.count(" fusion("),
-              " custom-calls:", hlo.count("custom-call("),
-              " copies:", hlo.count(" copy("))
-    except Exception as e:  # noqa: BLE001
-        print("cost/HLO analysis failed:", e)
+    entry = next(iter(train_step._compiled.values()))
+    compiled = entry.jitted.lower([t._value for t in entry.state_list],
+                                  [ids._value, labels._value]).compile()
+    cost = compiled.cost_analysis()
+    flops = float(cost["flops"])
+    bytes_acc = float(cost["bytes accessed"])
+    print(f"xla flops/step: {flops:.3e}  bytes/step: {bytes_acc:.3e}")
+    # dot-shape census: which GEMM shapes carry the flops
+    hlo = compiled.as_text()
+    shapes = {}
+    for m in re.finditer(
+            r"= (bf16|f32)\[([0-9,]+)\][^=]*? dot\(", hlo):
+        key = f"{m.group(1)}[{m.group(2)}]"
+        shapes[key] = shapes.get(key, 0) + 1
+    top = sorted(shapes.items(), key=lambda kv: -kv[1])[:12]
+    print("dot output shapes (count):")
+    for k, c in top:
+        print(f"  {c:4d}x {k}")
+    print("fusions:", hlo.count(" fusion("),
+          " custom-calls:", hlo.count("custom-call("),
+          " copies:", hlo.count(" copy("))
 
     # free-running step time (bench's mode: serial dependence via state)
     t0 = time.perf_counter()
@@ -145,20 +142,15 @@ def main():
 
     tok_s = args.batch * args.seq / dt
     print(f"step {dt*1e3:.1f} ms  {tok_s:.0f} tokens/s")
-    if flops:
-        mfu = flops / dt / 197e12
-        print(f"mfu (xla flops): {mfu:.3f}")
-    if bytes_acc:
-        bw = bytes_acc / dt / 1e9
-        util = bw / HBM_GBPS
-        print(f"hbm: {bytes_acc/1e9:.2f} GB/step -> {bw:.0f} GB/s "
-              f"({util:.1%} of {HBM_GBPS:.0f})")
-        if flops and bytes_acc:
-            ai = flops / bytes_acc
-            print(f"arithmetic intensity {ai:.0f} flop/byte "
-                  f"(v5e ridge ~{197e12/HBM_GBPS/1e9:.0f}) -> "
-                  f"{'COMPUTE' if ai > 197e12/(HBM_GBPS*1e9) else 'MEMORY'}"
-                  "-bound in the roofline sense")
+    print(f"mfu (xla flops): {flops / dt / chip.peak_flops:.3f}")
+    bw = bytes_acc / dt / 1e9
+    print(f"hbm: {bytes_acc/1e9:.2f} GB/step -> {bw:.0f} GB/s "
+          f"({bw / chip.hbm_gbs:.1%} of {chip.hbm_gbs:.0f})")
+    ai = flops / bytes_acc
+    print(f"arithmetic intensity {ai:.0f} flop/byte "
+          f"({chip.name} ridge ~{chip.ridge:.0f}) -> "
+          f"{'COMPUTE' if ai > chip.ridge else 'MEMORY'}"
+          "-bound in the roofline sense")
 
 
 if __name__ == "__main__":

@@ -5,7 +5,7 @@ BASELINE.json Fleet configs center on. Default config is a ~350M-param
 GPT (hidden 1024, 24 layers) at seq 2048 with recompute — the largest
 that fits v5e HBM (16 GB) comfortably with AdamW fp32 states.
 
-Run ON TPU (never kill it mid-run):
+Run on the chip (fails without one):
   python tools/profile_gpt.py [--hidden 1024] [--layers 24]
       [--batch 4] [--seq 2048] [--iters 6]
 
@@ -56,16 +56,15 @@ def main():
         args.param_dtype = args.param_dtype or "bfloat16"
         args.moment_dtype = args.moment_dtype or "bfloat16"
 
-    import jax
-
     import paddle_tpu as P
-    import paddle_tpu.nn.functional as F
     from paddle_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
                                        GPTPretrainingCriterion)
+    from paddle_tpu.observability.profile import attached_chip
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
 
-    dev = jax.devices()[0]
-    print(f"device: {dev.platform} {getattr(dev, 'device_kind', '')}",
-          flush=True)
+    dev, chip = attached_chip()            # no TPU, unknown kind: error
+    print(f"device: {dev.platform} {dev.device_kind}", flush=True)
+    enable_compile_cache()
 
     P.seed(0)
     cfg = GPTConfig(vocab_size=50304, hidden_size=args.hidden,
@@ -124,9 +123,10 @@ def main():
     # higher — this reports MODEL mfu (useful work), like the bench.
     flops_per_token = 6.0 * n_params + \
         6.0 * args.layers * args.hidden * args.seq
-    mfu = tok_s * flops_per_token / 197e12
+    mfu = tok_s * flops_per_token / chip.peak_flops
     out = {"metric": "gpt_train_tokens_s", "value": round(tok_s, 1),
            "unit": "tokens/sec/chip", "platform": dev.platform,
+           "device_kind": dev.device_kind,
            "params_m": round(n_params / 1e6, 1),
            "batch": args.batch, "seq": args.seq,
            "ms_per_step": round(dt * 1e3, 1),
@@ -138,11 +138,6 @@ def main():
            "flops_per_token_g": round(flops_per_token / 1e9, 2),
            "mfu": round(mfu, 4)}
     print(json.dumps(out), flush=True)
-    notes = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_NOTES.md")
-    stamp = time.strftime("%Y-%m-%d %H:%M UTC", time.gmtime())
-    with open(notes, "a") as fh:
-        fh.write(f"\n- tools/profile_gpt.py {stamp}: `{json.dumps(out)}`\n")
     return 0
 
 

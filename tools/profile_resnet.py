@@ -33,7 +33,13 @@ def main():
 
     import paddle_tpu as P
     import paddle_tpu.nn.functional as F
+    from paddle_tpu.observability.profile import attached_chip
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
     from paddle_tpu.vision.models import resnet50
+
+    dev, chip = attached_chip()            # no TPU, unknown kind: error
+    print(f"device: {dev.platform} {dev.device_kind}", flush=True)
+    enable_compile_cache()
 
     P.seed(0)
     model = resnet50(num_classes=1000, data_format=args.data_format)
@@ -63,36 +69,23 @@ def main():
     loss = train_step(x, y)
     loss.block_until_ready()
 
-    compiled = None
-    try:
-        entry = next(iter(train_step._compiled.values())); jitted, state_list = entry.jitted, entry.state_list
-        compiled = jitted.lower([t._value for t in state_list],
-                                [x._value, y._value]).compile()
-    except Exception as e:
-        print("could not re-lower compiled step:", e)
-    if compiled is not None:
-        try:
-            cost = compiled.cost_analysis()
-            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-            print("xla cost_analysis flops:", cost.get("flops"))
-            print("  bytes accessed:", cost.get("bytes accessed"))
-        except Exception as e:
-            print("cost_analysis failed:", e)
-        try:
-            hlo = compiled.as_text()
-            convs = re.findall(r"(\S+) = (\S+) convolution\(", hlo)
-            dt = {}
-            for _, sig in re.findall(r"= ((?:bf16|f32|f16|s8|s32)[^ ]*) "
-                                     r"(convolution|dot)\(", hlo):
-                dt[sig.split("[")[0]] = dt.get(sig.split("[")[0], 0) + 1
-            print("conv/dot output dtypes:", dt)
-            n_f32_conv = len(re.findall(r"= f32[^=]*convolution\(", hlo))
-            print("f32 convolutions:", n_f32_conv)
-            print("fusions:", hlo.count(" fusion("),
-                  " all-reduce:", hlo.count("all-reduce("),
-                  " copies:", hlo.count(" copy("))
-        except Exception as e:
-            print("hlo inspect failed:", e)
+    entry = next(iter(train_step._compiled.values()))
+    compiled = entry.jitted.lower([t._value for t in entry.state_list],
+                                  [x._value, y._value]).compile()
+    cost = compiled.cost_analysis()
+    print("xla cost_analysis flops:", cost["flops"])
+    print("  bytes accessed:", cost["bytes accessed"])
+    hlo = compiled.as_text()
+    dt = {}
+    for sig, _ in re.findall(r"= ((?:bf16|f32|f16|s8|s32)[^ ]*) "
+                             r"(convolution|dot)\(", hlo):
+        dt[sig.split("[")[0]] = dt.get(sig.split("[")[0], 0) + 1
+    print("conv/dot output dtypes:", dt)
+    n_f32_conv = len(re.findall(r"= f32[^=]*convolution\(", hlo))
+    print("f32 convolutions:", n_f32_conv)
+    print("fusions:", hlo.count(" fusion("),
+          " all-reduce:", hlo.count("all-reduce("),
+          " copies:", hlo.count(" copy("))
 
     # per-step timing: individually synced (exposes per-call overhead) ...
     ts = []
@@ -110,20 +103,17 @@ def main():
     loss.block_until_ready()
     per_step_stream = (time.perf_counter() - t0) / args.iters
 
-    dev = jax.devices()[0]
     import importlib.util as _u
     _spec = _u.spec_from_file_location(
         "bench", os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "bench.py"))
     _bench = _u.module_from_spec(_spec)
     _spec.loader.exec_module(_bench)
-    peak = _bench._lookup(_bench._PEAK_TFLOPS,
-                          getattr(dev, "device_kind", ""), 197.0) * 1e12
     flops_img = _bench._RESNET50_TRAIN_FLOPS  # FLOPs (2x MACs), like bench
     for name, t in [("synced", per_step_synced), ("stream", per_step_stream)]:
         img_s = args.batch / t
         print(f"{name}: {t*1e3:.1f} ms/step  {img_s:.0f} img/s  "
-              f"mfu={img_s*flops_img/peak:.3f}")
+              f"mfu={img_s*flops_img/chip.peak_flops:.3f}")
 
     if args.trace:
         with jax.profiler.trace(args.trace):

@@ -39,8 +39,8 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# static analysis must never claim (or wedge on) the TPU: the audit is
-# shape-only, so the CPU backend is always the right one here
+# static analysis never takes the chip: the audit is shape-only, so
+# the CPU backend is always the right one here
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 DEFAULT_BASELINE = os.path.join(REPO, "tools", "shardlint_baseline.json")
