@@ -15,6 +15,7 @@ import numpy as np
 
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.framework.state import _rng
+from paddle_tpu.observability.spans import span
 
 
 class Dataset:
@@ -704,6 +705,22 @@ class DataLoader:
         return None
 
     def __iter__(self):
+        """Every path's batches, each ``next()`` of the path under an
+        ``io.next`` span: from the consumer asking to the batch being
+        ready, once per batch delivered (the ``yield`` lies outside)."""
+        batches, end = self._iter_path(), object()
+        try:
+            while True:
+                with span("io.next") as wait:
+                    batch = next(batches, end)
+                    if batch is end:
+                        wait.discard()
+                        return
+                yield batch
+        finally:
+            batches.close()
+
+    def _iter_path(self):
         nat = self._native_iter()
         if nat is not None:
             yield from nat

@@ -307,10 +307,10 @@ class StaticFunction:
         return names
 
     def __call__(self, *args, **kwargs):
-        with _span(self._span_name):
-            return self._call(args, kwargs)
+        with _span(self._span_name) as call_span:
+            return self._call(args, kwargs, call_span)
 
-    def _call(self, args, kwargs):
+    def _call(self, args, kwargs, call_span):
         in_treedef, tensor_vals, static_leaves = self._flatten_inputs(
             args, kwargs)
 
@@ -337,6 +337,7 @@ class StaticFunction:
                 reg_ver,
             )
             entry = self._compiled.get(key)
+            call_span.set(hit=entry is not None)
             event = None
             if entry is None:
                 prior_keys = list(self._compiled)

@@ -7,9 +7,13 @@ uses.  It does two things:
   bounded in-process ring buffer — cheap enough (<~2 us/span: two
   monotonic clock reads and a deque append) to leave on in production,
   exportable as Chrome-trace JSON via :mod:`observability.export`;
-- when a jax profiler capture is active (`profiler.in_profiler_mode()`),
-  ALSO opens a ``jax.profiler.TraceAnnotation`` so the span shows up on
-  the TensorBoard/Perfetto timeline next to the XLA device activity.
+- while ANY jax profiler capture is active — ``paddle_tpu.profiler``,
+  a bare ``jax.profiler.start_trace`` / ``trace``, or one asked for
+  through ``jax.profiler.start_server`` (jax's own
+  ``TraceAnnotation.is_enabled()``) — ALSO opens a
+  ``jax.profiler.TraceAnnotation`` carrying the span's attributes as the
+  event's stats, so the span lies on the capture's clock next to the XLA
+  device activity.
 
 Spans inside a ``to_static``-traced function fire at TRACE time (host
 side), which is exactly when the interesting wall-clock cost (retrace +
@@ -36,6 +40,8 @@ import threading
 import time
 import uuid
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "span", "SpanRecord", "SpanRecorder", "recorder",
@@ -270,6 +276,12 @@ class span:
         """This span's id under its trace (None untraced / unentered)."""
         return getattr(self, "_sid", None)
 
+    def set(self, **attrs):
+        """Attributes known only at the end (``admitted``, ``hit``):
+        set before the exit, they reach the ring-buffer record; the
+        capture's event keeps what was known at entry."""
+        self.attrs = {**self.attrs, **attrs} if self.attrs else attrs
+
     def __enter__(self):
         if not _state[0]:
             self._t0 = None
@@ -289,47 +301,33 @@ class span:
         else:
             self._sid = None
         self._ann = None
-        # under an active jax capture the span also lands on the
-        # device-side timeline; import resolved lazily once so a bare
-        # `observability` import stays light
-        if _in_profiler_mode():
-            import jax
-            self._ann = jax.profiler.TraceAnnotation(self.name)
+        # under ANY active jax capture: on its timeline, attrs as stats
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name, **(self.attrs or {}))
             self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
-    def __exit__(self, exc_type, exc, tb):
+    def __exit__(self, exc_type, exc, tb, record=True):
         if self._t0 is None:
             return False
         dur = time.perf_counter_ns() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         _tls.depth = self._depth
+        trace = ()
         if self._sid is not None:
             _tls.ctx = self._prev
-            ctx = self._ctx
+            trace = (self._ctx.trace_id, self._sid,
+                     self._ctx.parent_span_id)
+        if record:
             _RECORDER.record(SpanRecord(
                 self.name, self._t0, dur, self._depth,
-                threading.get_ident(), self.attrs,
-                trace_id=ctx.trace_id, span_id=self._sid,
-                parent_id=ctx.parent_span_id))
-        else:
-            _RECORDER.record(SpanRecord(
-                self.name, self._t0, dur, self._depth,
-                threading.get_ident(), self.attrs))
+                threading.get_ident(), self.attrs, *trace))
         return False
 
-
-def _in_profiler_mode():
-    # bound lazily: paddle_tpu.profiler imports the observability
-    # registry inside its shim functions, so a module-level circular
-    # import is avoided by resolving the flag holder on first use
-    global _profiler_flag
-    if _profiler_flag is None:
-        from paddle_tpu import profiler
-        _profiler_flag = profiler._profiler_mode
-    return _profiler_flag[0]
-
-
-_profiler_flag = None
+    def discard(self):
+        """Close now, unrecorded (the ``with`` exit is then a no-op):
+        an exhausted iterator's last ``next`` waited for nothing."""
+        self.__exit__(None, None, None, record=False)
+        self._t0 = None

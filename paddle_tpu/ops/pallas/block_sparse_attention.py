@@ -175,6 +175,7 @@ def _bsa_fwd(q, k, v, kt, counts, scale, block_q, block_k, interpret):
     )
     o, lse8 = pl.pallas_call(
         kernel,
+        name="block_sparse_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, qp.shape[1], d), q.dtype),

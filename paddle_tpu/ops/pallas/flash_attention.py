@@ -266,6 +266,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
             return (bh, ki, 0)
     o, lse8 = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -370,6 +371,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
         block_k=block_k, seq_k=sk)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=[
             _vmem_spec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -395,6 +397,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
         block_k=block_k, seq_q=sq, seq_k=sk)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             _vmem_spec((1, block_q, d), q_index),

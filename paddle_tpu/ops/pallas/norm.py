@@ -75,8 +75,9 @@ def _pad_rows(x, block):
     return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
 
-def _run_rows_kernel(kernel, x2, extras, block_rows, interpret):
-    """Run a row-block kernel over [rows, hidden] (rows padded to block)."""
+def _run_rows_kernel(kernel, name, x2, extras, block_rows, interpret):
+    """Run a row-block kernel over [rows, hidden] (rows padded to block);
+    ``name`` is the kernel's name in the compiled program and the trace."""
     rows, hidden = x2.shape
     xp = _pad_rows(x2, block_rows)
     grid = (xp.shape[0] // block_rows,)
@@ -85,6 +86,7 @@ def _run_rows_kernel(kernel, x2, extras, block_rows, interpret):
         in_specs.append(_vmem_spec((1, hidden), lambda i: (0, 0)))
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=_vmem_spec((block_rows, hidden), lambda i: (i, 0)),
@@ -124,7 +126,7 @@ def _ln_fwd_impl(x, weight, bias, eps, block_rows, interpret):
                 _ln_kernel, eps=eps, has_affine=False)):
             _k(x_ref, None, None, o_ref)
         extras = []
-    y2 = _run_rows_kernel(kernel, x2, extras,
+    y2 = _run_rows_kernel(kernel, "layer_norm_fwd", x2, extras,
                           _auto_block_rows(x2.shape[0], x2.dtype,
                                            block_rows), interpret)
     return y2.reshape(x.shape), None, None
@@ -238,7 +240,7 @@ def _ln_bwd_kernel_res(h_ref, gy_ref, gh_ref, w_ref, b_ref, dx_ref,
                  eps=eps, act=act)
 
 
-def _run_ln_multi(kernel, rows_in, vecs, rows_out_dtypes, n_partials,
+def _run_ln_multi(kernel, name, rows_in, vecs, rows_out_dtypes, n_partials,
                   block_rows, interpret):
     """Row-block kernel with several [rows, hidden] inputs/outputs plus
     per-block f32 partial-sum outputs, returned as (grid, hidden) and
@@ -260,7 +262,7 @@ def _run_ln_multi(kernel, rows_in, vecs, rows_out_dtypes, n_partials,
                                        jnp.float32)
                   for _ in range(n_partials)]
     outs = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret,
     )(*xp, *[v[None, :] for v in vecs])
     n_rows_out = len(rows_out_dtypes)
@@ -280,11 +282,11 @@ def _ln_bwd_pallas(h, weight, bias, gy, gh, eps, act, block_rows,
     br = _auto_block_rows(h2.shape[0], h2.dtype, block_rows)
     if gh is None:
         kernel = functools.partial(_ln_bwd_kernel_plain, eps=eps, act=act)
-        rows_in = [h2, gy2]
+        name, rows_in = "layer_norm_bwd", [h2, gy2]
     else:
         kernel = functools.partial(_ln_bwd_kernel_res, eps=eps, act=act)
-        rows_in = [h2, gy2, gh.reshape(-1, hidden)]
-    dx2, dwp, dbp = _run_ln_multi(kernel, rows_in, [weight, b],
+        name, rows_in = "ln_residual_bwd", [h2, gy2, gh.reshape(-1, hidden)]
+    dx2, dwp, dbp = _run_ln_multi(kernel, name, rows_in, [weight, b],
                                   [h.dtype], 2, br, interpret)
     dw = dwp.sum(axis=0).astype(weight.dtype)
     db = dbp.sum(axis=0).astype(bias.dtype) if bias is not None else None
@@ -319,7 +321,7 @@ def _ln_res_fwd_impl(x, residual, weight, bias, eps, act, block_rows,
     b = bias if bias is not None else jnp.zeros_like(weight)
     br = _auto_block_rows(x2.shape[0], jnp.dtype(out_dtype), block_rows)
     kernel = functools.partial(_ln_res_kernel, eps=eps, act=act)
-    h2, y2 = _run_ln_multi(kernel, [x2, r2], [weight, b],
+    h2, y2 = _run_ln_multi(kernel, "ln_residual_fwd", [x2, r2], [weight, b],
                            [out_dtype, out_dtype], 0, br, interpret)
     return h2.reshape(x.shape), y2.reshape(x.shape)
 
@@ -401,7 +403,7 @@ def fused_rms_norm(x, weight, eps=1e-6, block_rows=None, interpret=None):
         def kernel(x_ref, o_ref):
             _rms_kernel(x_ref, None, o_ref, eps=eps, has_affine=False)
         extras = []
-    y2 = _run_rows_kernel(kernel, x2, extras,
+    y2 = _run_rows_kernel(kernel, "rms_norm_fwd", x2, extras,
                           block_rows or DEFAULT_BLOCK_ROWS, interpret)
     return y2.reshape(x.shape)
 

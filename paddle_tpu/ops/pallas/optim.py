@@ -159,6 +159,7 @@ def fused_adam_update(p, g, m, v, lr, c1, c2, *, beta1, beta2, eps,
             jax.ShapeDtypeStruct((grid[0] * 8, 128), jnp.float32))
     outs = pl.pallas_call(
         kernel,
+        name="adamw_fused",
         grid=grid,
         in_specs=[_vmem_spec((1, 4), lambda i: (0, 0))]
         + [_vmem_spec((br, cols), blk) for _ in range(4)],

@@ -866,6 +866,14 @@ class LLMEngine:
         this step; a preemption surfaces as ``(request_id, None, False)``
         (the request re-enters the queue and will be replayed)."""
         events = []
+        with span("serving.step", running=self.num_running,
+                  waiting=self.queue_depth) as step_span:
+            admitted = self._step_inner(events)
+            step_span.set(admitted=admitted, tokens=sum(
+                1 for _rid, tok, _fin in events if tok is not None))
+        return events
+
+    def _step_inner(self, events):
         self._expire_deadlines(events)
         with span("serving.admit"):
             admitted = self._admit(events)
@@ -882,7 +890,7 @@ class LLMEngine:
                 f"tokens) cannot be admitted — the page pool "
                 f"({self._alloc.num_free_pages} free) is too small")
         self._refresh_gauges()
-        return events
+        return admitted
 
     def generate(self, prompts, sampling_params=None):
         """Sync facade: serve `prompts` (list of token-id lists) to
@@ -1020,7 +1028,7 @@ class LLMEngine:
 
     # -------------------------------------------------------- decode
     def _decode_step(self, events):
-        with span("serving.decode"):
+        with span("serving.decode", live=self.num_running):
             self._decode_step_inner(events)
 
     def _decode_step_inner(self, events):
@@ -1208,6 +1216,10 @@ class LLMEngine:
         the ABSOLUTE index of the token being sampled = the row's cache
         length AFTER its input token was appended — which is exactly
         `total_len` host-side."""
+        with span("serving.sample", width=width):
+            return self._sample_inner(logits, reqs, width)
+
+    def _sample_inner(self, logits, reqs, width):
         seeds = np.zeros((width,), np.int32)
         pos = np.zeros((width,), np.int32)
         temps = np.zeros((width,), np.float32)

@@ -1,0 +1,118 @@
+"""The flash attention kernels keep their names through the chip's own
+compiler: compiled here for one DESCRIBED v5e chip (no chip attached —
+on-chip-measurement guide, section 2) at the two benchmark cells' sizes,
+the program holds ``%flash_fwd`` / ``%flash_dq`` / ``%flash_dkv``.  The
+device trace names an event by its HLO instruction, so these names are
+what ``breakdown.device_ops`` and a per-kernel roofline find.
+
+The topology is described inside a module fixture (never at import: one
+process at a time may load the TPU's library), and every compile runs in
+this one file so that one xdist worker owns it.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+# (batch, heads, seq, head_dim), dtype, causal — as the cells call them
+CELLS = {
+    "gpt355m_train": ((4, 16, 2048, 64), jnp.float32, True),
+    "bert_base_train": ((48, 12, 512, 64), jnp.bfloat16, False),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """{(cell, pass): HLO text}, each program compiled once, with the
+    persistent compile cache off (an entry written for a described chip
+    cannot be read back without one, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    texts = {}
+    try:
+        for cell, (shape, dtype, causal) in CELLS.items():
+            arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            row = jax.ShapeDtypeStruct(shape[:3], jnp.float32,
+                                       sharding=one_chip)
+            scale = shape[-1] ** -0.5
+            blocks = (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K, False)
+
+            def forward(q, k, v, causal=causal, scale=scale, blocks=blocks):
+                return fa._flash_bhsd(q, k, v, causal, scale, *blocks)
+
+            def backward(q, k, v, do, lse, delta, causal=causal,
+                         scale=scale, blocks=blocks):
+                return fa._flash_bwd_impl(q, k, v, do, lse, delta, causal,
+                                          scale, *blocks)
+
+            def loss(q, k, v, forward=forward):
+                return jnp.sum(forward(q, k, v).astype(jnp.float32))
+
+            texts[cell, "forward"] = jax.jit(forward).lower(
+                arg, arg, arg).compile().as_text()
+            texts[cell, "backward"] = jax.jit(backward).lower(
+                arg, arg, arg, arg, row, row).compile().as_text()
+            texts[cell, "grad"] = jax.jit(
+                jax.grad(loss, argnums=(0, 1, 2))).lower(
+                    arg, arg, arg).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return texts
+
+
+def _kernel_calls(text):
+    """{instruction name: line} of the program's Mosaic calls."""
+    return {ln.split(" = ")[0].strip().lstrip("%"): ln
+            for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln}
+
+
+def _attention_shape(cell):
+    b, h, s, d = CELLS[cell][0]
+    return "[%d,%d,%d]" % (b * h, s, d)
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("which,kernel", [
+    ("forward", "flash_fwd"), ("backward", "flash_dq"),
+    ("backward", "flash_dkv")])
+def test_kernel_name_is_the_compiled_instruction(compiled_text, cell, which,
+                                                 kernel):
+    calls = {name: ln for name, ln in
+             _kernel_calls(compiled_text[cell, which]).items()
+             if name.split(".")[0] == kernel}
+    assert calls, f"no %{kernel} custom-call in the {which} program"
+    for ln in calls.values():
+        # the reader of flash_attn_roofline finds attention by this layout
+        assert _attention_shape(cell) in ln.split(" custom-call(")[0]
+        assert f"/{kernel}/pallas_call" in ln            # op_name metadata
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_differentiated_step_keeps_the_kernel_in_the_name(compiled_text,
+                                                          cell, kernel):
+    # under jax.grad the instruction carries the transforms round the
+    # kernel's name (%jvp_flash_fwd_, %transpose_jvp_flash_dq__): the
+    # name is still there, and no call is anonymous (%jvp__ before)
+    calls = _kernel_calls(compiled_text[cell, "grad"])
+    assert len(calls) == 3
+    assert sum(kernel in name for name in calls) == 1, sorted(calls)
+    assert not any(name.split(".")[0].strip("_") in ("jvp", "transpose_jvp")
+                   for name in calls)

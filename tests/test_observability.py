@@ -709,3 +709,241 @@ class TestTelemetryIsolation:
         assert f not in dy2static._fail_cache
         assert getattr(f, "_ptd2s_variant", None) is not None
         assert out is f._ptd2s_variant
+
+
+# ============================================== spans on a capture's clock
+def _capture_events(tmp_path, body):
+    """Host events {name: [stats dict, ...]} of a BARE jax capture round
+    ``body`` — nothing of ``paddle_tpu.profiler`` is called."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(
+                    {k: v for k, v in ev.stats})
+    return events
+
+
+class TestSpansReachAnyCapture:
+    def test_bare_jax_capture_holds_the_span_and_its_attrs(self, tmp_path):
+        assert not profiler.in_profiler_mode()
+
+        def body():
+            with obs.span("capture.probe", request="req-9", bucket=128,
+                          tokens=77) as s:
+                s.set(hit=True)          # the record's, not the event's
+
+        events = _capture_events(tmp_path, body)
+        assert len(events["capture.probe"]) == 1
+        stats = events["capture.probe"][0]
+        assert str(stats["request"]) == "req-9"
+        assert int(stats["bucket"]) == 128 and int(stats["tokens"]) == 77
+        assert obs.recorder().spans()[-1].attrs == {
+            "request": "req-9", "bucket": 128, "tokens": 77, "hit": True}
+
+    def test_disabled_spans_write_no_event(self, tmp_path):
+        def body():
+            prev = obs.set_enabled(False)
+            try:
+                with obs.span("capture.off-probe", k=1):
+                    pass
+            finally:
+                obs.set_enabled(prev)
+            with obs.span("capture.on-probe"):
+                pass
+
+        events = _capture_events(tmp_path, body)
+        assert "capture.off-probe" not in events
+        assert len(events["capture.on-probe"]) == 1
+
+    def test_discard_leaves_no_record_and_restores_depth(self):
+        rec = obs.recorder()
+        before = rec.total_recorded
+        with obs.span("discard.outer"):
+            with obs.span("discard.probe") as s:
+                s.discard()
+            with obs.span("discard.kept"):
+                pass
+        names = [r.name for r in rec.spans()[-2:]]
+        assert rec.total_recorded == before + 2
+        assert names == ["discard.kept", "discard.outer"]
+        assert rec.spans()[-2].depth == 1
+
+
+def _inside(child, parent):
+    return (child.depth > parent.depth
+            and child.thread_id == parent.thread_id
+            and parent.start_ns <= child.start_ns
+            and child.start_ns + child.dur_ns
+            <= parent.start_ns + parent.dur_ns)
+
+
+def _tiny_engine():
+    from paddle_tpu import serving
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    P.seed(0)
+    mcfg = GPTConfig(vocab_size=64, hidden_size=16, num_layers=1,
+                     num_heads=2, max_seq_len=32, dropout=0.0,
+                     attention_dropout=0.0)
+    return serving.LLMEngine(
+        GPTForCausalLM(mcfg),
+        serving.EngineConfig(max_num_seqs=2, page_size=4, max_model_len=16,
+                             prefill_buckets=(8,)))
+
+
+@pytest.fixture(scope="module")
+def engine_step_records():
+    """One tiny CPU engine serving three prompts through two slots: the
+    ring buffer's records of that run and the engine's own counts."""
+    from paddle_tpu import serving
+    engine = _tiny_engine()
+    rec = obs.recorder()
+    prev_cap = rec.capacity
+    rec.set_capacity(1 << 16)
+    before = rec.total_recorded
+    try:
+        ctx = obs.TraceContext.new("ambient")
+        with obs.use_context(ctx):     # as under a fleet server's verb
+            results = engine.generate(
+                [[1, 2, 3], [4, 5, 6, 7], [8, 9]],
+                serving.SamplingParams(max_new_tokens=4, temperature=0.0))
+        records = rec.spans()[-(rec.total_recorded - before):]
+        counts = (engine.metrics.prefill_steps, engine.metrics.decode_steps)
+        delivered = sum(len(r.output_token_ids) for r in results)
+    finally:
+        rec.set_capacity(prev_cap)
+        engine.shutdown()
+    return records, counts, delivered, ctx
+
+
+class TestEngineStepSpans:
+    @pytest.mark.parametrize("name", ["serving.prefill", "serving.decode",
+                                      "serving.sample", "serving.admit"])
+    def test_every_child_lies_inside_a_step(self, engine_step_records, name):
+        records = engine_step_records[0]
+        steps = [r for r in records if r.name == "serving.step"]
+        children = [r for r in records if r.name == name]
+        assert steps and children
+        for c in children:
+            assert sum(_inside(c, s) for s in steps) == 1, c
+
+    def test_counts_equal_the_engines_own(self, engine_step_records):
+        records, (prefills, decodes), delivered, _ = engine_step_records
+        by = {}
+        for r in records:
+            by.setdefault(r.name, []).append(r)
+        assert len(by["serving.prefill"]) == prefills == 3
+        assert len(by["serving.decode"]) == decodes
+        # the one blocking fetch of every prefill and every decode step
+        assert len(by["serving.sample"]) == prefills + decodes
+        assert {r.attrs["width"] for r in by["serving.sample"]} == {1, 2}
+        steps = by["serving.step"]
+        assert sum(s.attrs["admitted"] for s in steps) == prefills
+        assert sum(s.attrs["tokens"] for s in steps) == delivered == 12
+        assert all(d.attrs["live"] in (1, 2) for d in by["serving.decode"])
+        assert steps[0].attrs["waiting"] == 3
+        assert steps[0].attrs["running"] == 0
+
+    def test_sample_is_the_child_of_prefill_and_decode(
+            self, engine_step_records):
+        records = engine_step_records[0]
+        parents = [r for r in records
+                   if r.name in ("serving.prefill", "serving.decode")]
+        for s in (r for r in records if r.name == "serving.sample"):
+            holders = [p for p in parents if _inside(s, p)]
+            assert len(holders) == 1 and s.depth == holders[0].depth + 1
+
+    def test_step_never_parents_a_requests_trace(self, engine_step_records):
+        # serving.step belongs to no request: untraced requests under an
+        # ambient context keep it as the thread's parent, but a request's
+        # own trace (ctx=req.trace) never hangs below a step
+        from paddle_tpu import serving
+        engine = _tiny_engine()
+        rec = obs.recorder()
+        before = rec.total_recorded
+        try:
+            birth = obs.TraceContext("req-trace", "router.1")
+            with obs.use_context(birth):
+                rid = engine.add_request(
+                    [1, 2, 3], serving.SamplingParams(max_new_tokens=2,
+                                                      temperature=0.0))
+            with obs.use_context(obs.TraceContext("other-trace", "verb.7")):
+                while engine.has_unfinished():
+                    engine.step()
+        finally:
+            engine.shutdown()
+        records = rec.spans()[-(rec.total_recorded - before):]
+        step_ids = {r.span_id for r in records if r.name == "serving.step"}
+        mine = [r for r in records if r.trace_id == "req-trace"]
+        assert {"serving.prefill", "serving.finish"} <= {r.name for r in mine}
+        for r in mine:
+            if r.name in ("serving.prefill", "serving.finish"):
+                assert r.parent_id == "router.1"
+                assert r.attrs["request"] == rid
+            assert r.parent_id not in step_ids
+
+
+class _TenRows(P.io.Dataset):
+    def __len__(self):
+        return 10
+
+    def __getitem__(self, i):
+        return np.full((3,), i, np.float32), np.int64(i)
+
+
+class TestLoaderAndJitSpans:
+    @pytest.mark.parametrize("workers,processes", [
+        (0, None), (2, None), (2, False)],
+        ids=["inline", "forked-workers", "threads"])
+    def test_io_next_once_per_batch_delivered(self, workers, processes):
+        rec = obs.recorder()
+        before = rec.total_recorded
+        loader = P.io.DataLoader(_TenRows(), batch_size=3,
+                                 num_workers=workers,
+                                 use_process_workers=processes)
+        batches = list(loader)
+        waits = [r for r in rec.spans()[-(rec.total_recorded - before):]
+                 if r.name == "io.next"]
+        assert len(batches) == 4 and len(waits) == 4
+        assert {r.depth for r in waits} == {0}
+
+    def test_io_next_on_the_native_path_and_an_early_close(self):
+        rec = obs.recorder()
+        before = rec.total_recorded
+        ds = P.io.TensorDataset(
+            [P.to_tensor(np.arange(20, dtype=np.float32).reshape(10, 2))])
+        assert len(list(P.io.DataLoader(ds, batch_size=5))) == 2
+        feed = iter(P.io.DataLoader(_TenRows(), batch_size=2, num_workers=2))
+        next(feed)
+        feed.close()                  # the workers' clean-up still runs
+        waits = [r for r in rec.spans()[-(rec.total_recorded - before):]
+                 if r.name == "io.next"]
+        assert len(waits) == 3
+
+    def test_jit_span_says_whether_the_program_was_cached(self):
+        @P.jit.to_static
+        def hit_probe(x):
+            return (x * 2).sum()
+
+        rec = obs.recorder()
+        before = rec.total_recorded
+        x = P.to_tensor(np.ones((4,), np.float32))
+        for _ in range(3):
+            hit_probe(x)
+        hits = [r.attrs["hit"]
+                for r in rec.spans()[-(rec.total_recorded - before):]
+                if r.name == "jit.hit_probe"]
+        assert hits == [False, True, True]

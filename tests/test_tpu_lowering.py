@@ -73,7 +73,7 @@ class TestFlashAttention:
 
         txt = _lower_tpu(jax.grad(f, argnums=(0, 1, 2)),
                          _sd(shape), _sd(shape), _sd(shape))
-        for kernel in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+        for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
             assert kernel in txt, f"{kernel} missing from the lowering"
 
 
@@ -108,7 +108,7 @@ class TestNorms:
                          _sd((self._ROWS, self._HIDDEN)),
                          _sd((self._HIDDEN,), jnp.float32),
                          _sd((self._HIDDEN,), jnp.float32))
-        assert "_ln_bwd_kernel_plain" in txt
+        assert "layer_norm_bwd" in txt
 
     @pytest.mark.parametrize("act", [None, "gelu"])
     def test_ln_residual_bwd_lowers(self, act):
@@ -124,7 +124,7 @@ class TestNorms:
                          _sd((self._ROWS, self._HIDDEN)),
                          _sd((self._HIDDEN,), jnp.float32),
                          _sd((self._HIDDEN,), jnp.float32))
-        assert "_ln_res_kernel" in txt and "_ln_bwd_kernel_res" in txt
+        assert "ln_residual_fwd" in txt and "ln_residual_bwd" in txt
 
 
 class TestFusedAdam:
@@ -140,7 +140,7 @@ class TestFusedAdam:
         shape = (1024, 4096)          # a multi-block GPT-355M fc1 weight
         txt = _lower_tpu(f, _sd(shape, jnp.float32), _sd(shape),
                          _sd(shape), _sd(shape), _sd((), jnp.float32))
-        assert "_adam_kernel" in txt
+        assert "adamw_fused" in txt
 
 
 class TestRingBlocks:
