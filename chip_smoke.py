@@ -211,8 +211,8 @@ def trainer_phase(sz, on_tpu):
     kernels = mosaic_kernels(text)
     print(f"  Mosaic kernels in the lowered step: {kernels}", flush=True)
     if on_tpu:
-        for name in ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
-                     "_ln_kernel", "_ln_bwd_kernel_plain"):
+        for name in ("flash_fwd", "flash_dq", "flash_dkv",
+                     "layer_norm_fwd", "layer_norm_bwd"):
             check(kernels.get(name, 0) >= 1,
                   f"lowered step calls the Mosaic kernel {name}")
     else:
@@ -290,7 +290,7 @@ def check_against_dense(model, result, sz):
           f"{len(result.output_token_ids)} tokens equal, {ties} bf16 ties)")
 
 
-def server_phase(model, sz):
+def server_phase(model, sz, on_tpu):
     from paddle_tpu import serving
     from paddle_tpu.observability import recompile_log
     from paddle_tpu.serving.aot_cache import AOTProgramCache
@@ -303,7 +303,10 @@ def server_phase(model, sz):
     cache = AOTProgramCache(serving_aot_dir())
     engine = serving.LLMEngine(model, engine_config(sz), program_cache=cache)
     boot = engine.warmup()
-    print(f"  boot: {boot}", flush=True)
+    print(f"  boot: {boot}; attention: {engine.attention_path}", flush=True)
+    if on_tpu:
+        check(engine.attention_path.startswith("paged_decode/"),
+              "a bf16 engine on the TPU decodes through the Pallas kernel")
 
     prompts, sps = make_requests(sz)
     t0 = time.perf_counter()
@@ -483,7 +486,7 @@ def main():
 
     if args.chips == 1:
         model, _ = trainer_phase(sz, on_tpu)
-        server_phase(model, sz)
+        server_phase(model, sz, on_tpu)
     else:
         sz = dict(sz, layers=min(sz["layers"], FOUR_CHIP_DEPTH),
                   batch=2 * sz["batch"], seq=sz["seq"] // 2)

@@ -33,6 +33,15 @@ Three layers of API, outermost first:
   :func:`paged_prefill_append`, :func:`paged_attend`) — trace-safe
   building blocks usable inside any jit/to_static program.
 
+Two pool layouts, told apart by rank: the head-major 4-D pool above —
+the XLA composition, the definition of the mathematics, the only path
+on the CPU, under a multi-device mesh and for quantized pools — and ROW
+pages ``[num_pages, page_size, n_head*head_dim]``, which decode through
+the Pallas kernel :func:`paddle_tpu.ops.pallas.paged_attention.
+paged_decode` and whose appends are row scatters XLA does in place.
+:func:`row_pages_default` says which of the two a pool on this platform
+should be; the step functions follow the pool they are handed.
+
 Quantized pools: the per-page-scaled int8/fp8 variants of the step
 functions live in :mod:`paddle_tpu.quantization.kv_cache` (same page
 geometry, pools become ``(codes, scales)`` pairs, ~0.52x bytes/token
@@ -56,6 +65,7 @@ __all__ = [
     "paged_attention_decode",
     "paged_decode_step",
     "paged_prefill_append",
+    "row_pages_default",
 ]
 
 
@@ -272,6 +282,19 @@ class PagedKVCache:
         self.seq_lens._set_value(merged)
 
 
+def row_pages_default(dtype, num_heads, head_dim, page_size):
+    """Whether a plain pool of this geometry is stored as ROW pages and
+    decoded by the Pallas kernel: on a TPU, outside any program GSPMD
+    has to partition (``ops.pallas.kernel_default``), for the dtypes and
+    page shapes the kernel takes.  Everything else keeps the head-major
+    pool and the XLA composition."""
+    from paddle_tpu.ops.pallas import kernel_default
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_decode_supported
+    return kernel_default() and paged_decode_supported(
+        dtype, num_heads, head_dim, page_size)
+
+
 def paged_attend(q, k_pages, v_pages, tables, lens, page_size, scale=None):
     """Shared attention core: [b, h, 1, d] queries over each row's
     gathered pages, masked at `lens` — used by the stateful step, the
@@ -309,12 +332,28 @@ def paged_decode_step(q, k_new, v_new, k_pages, v_pages, tables, lens,
     Returns (out, k_pages, v_pages); the caller owns the lens update —
     a multi-layer engine calls this once per layer with the SAME lens
     and advances lens once per step.
+
+    Row pages (3-D pools) take the Pallas kernel: the new rows are
+    scattered first — in place, the kernel is the pool's only reader —
+    and the kernel then reads the ``ceil((lens[b]+1)/page_size)`` live
+    pages of each row instead of the whole table width.
     """
     lens = lens.astype(jnp.int32)
     page_idx = lens // page_size
     offs = lens % page_size
     page_ids = jnp.take_along_axis(tables, page_idx[:, None],
                                    axis=1)[:, 0]          # [b]
+    if k_pages.ndim == 3:
+        from paddle_tpu.ops.pallas import on_tpu
+        from paddle_tpu.ops.pallas.paged_attention import paged_decode
+        b, hd = q.shape[0], k_pages.shape[-1]
+        k_pages = k_pages.at[page_ids, offs].set(
+            k_new.reshape(b, hd).astype(k_pages.dtype))
+        v_pages = v_pages.at[page_ids, offs].set(
+            v_new.reshape(b, hd).astype(v_pages.dtype))
+        out = paged_decode(q, k_pages, v_pages, tables, lens + 1,
+                           scale=scale, interpret=not on_tpu())
+        return out, k_pages, v_pages
     # scatter each row's token into its page/offset — the pool-dtype
     # narrowing is EXPLICIT (numlint-visible cast, and jax deprecates
     # the implicit f32->bf16 scatter cast) rather than hidden in the
@@ -342,7 +381,8 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     lands in page ``tables[b, t // page_size]`` at offset
     ``t % page_size``; positions >= lens[b] go to the garbage page 0.
 
-    k_new/v_new: [b, h, S, d].  Returns (k_pages, v_pages).
+    k_new/v_new: [b, h, S, d].  Returns (k_pages, v_pages).  Row pages
+    (3-D pools) take each token's heads as one row.
     """
     b, h, S, d = k_new.shape
     t = jnp.arange(S, dtype=jnp.int32)
@@ -356,6 +396,13 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     page_ids = jnp.where(valid, page_ids, 0)
     flat_pages = page_ids.reshape(-1)                      # [b*S]
     flat_offs = jnp.tile(offs, b)
+    if k_pages.ndim == 3:
+        kt = jnp.swapaxes(k_new, 1, 2).reshape(b * S, h * d)
+        vt = jnp.swapaxes(v_new, 1, 2).reshape(b * S, h * d)
+        return (k_pages.at[flat_pages, flat_offs].set(
+                    kt.astype(k_pages.dtype)),
+                v_pages.at[flat_pages, flat_offs].set(
+                    vt.astype(v_pages.dtype)))
     # explicit pool-dtype narrowing (see paged_decode_step)
     kt = jnp.swapaxes(k_new, 1, 2).reshape(b * S, h, d) \
         .astype(k_pages.dtype)                             # [b*S, h, d]

@@ -13,7 +13,9 @@ few files":
 - **Fingerprint.**  :func:`engine_fingerprint` hashes everything a
   compiled program's correctness depends on — model config, engine
   geometry (slots/pages/buckets/dtype), parameter tree (names, shapes,
-  dtypes — never values), mesh spec, jax/jaxlib versions, and the
+  dtypes — never values), mesh spec, the decode attention path (XLA
+  composition, or the Pallas kernel and its revision — the programs'
+  CODE is otherwise not hashed), jax/jaxlib versions, and the
   platform and kind of each device the programs run on
   (:func:`program_devices`).  Any component changing produces a
   DIFFERENT fingerprint directory, so invalidation is structural: stale
@@ -75,12 +77,17 @@ def program_devices(params, mesh=None):
                   key=lambda d: d.id)
 
 
-def engine_fingerprint(model_config, engine_config, params, mesh=None):
+def engine_fingerprint(model_config, engine_config, params, mesh=None,
+                       attention="xla"):
     """Hex digest naming the compiled-program family of one engine.
 
     `params` contributes structure (sorted name/shape/dtype) and
     placement, never values — weights can be hot-swapped under a
-    fingerprint because XLA compiled against their avals.
+    fingerprint because XLA compiled against their avals.  `attention`
+    names what the decode attention was built from
+    (``LLMEngine.attention_path``: ``"xla"`` or the Pallas kernel with
+    its revision) — the one part of the CODE the digest covers, so two
+    trees that differ there never share an executable.
     """
     import jaxlib
 
@@ -103,6 +110,7 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None):
                    # an extra output — structurally different family
                    bool(getattr(ec, "guard", False))),
         "mesh": _mesh_desc(mesh),
+        "attention": str(attention),
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "devices": [(d.platform, d.device_kind)

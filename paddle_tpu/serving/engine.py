@@ -51,7 +51,11 @@ from paddle_tpu.core.dispatch import apply
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.incubate.nn.paged_attention import (PageAllocator,
                                                     paged_decode_step,
-                                                    paged_prefill_append)
+                                                    paged_prefill_append,
+                                                    row_pages_default)
+from paddle_tpu.ops.pallas.paged_attention import (PAGED_DECODE_REVISION,
+                                                   from_row_pages,
+                                                   to_row_pages)
 from paddle_tpu.quantization.kv_cache import (quantized_decode_step,
                                               quantized_prefill_append,
                                               resolve_kv_cache_dtype)
@@ -304,11 +308,22 @@ class LLMEngine:
                             for k, v in self._params.items()}
 
         B, P = cfg.max_num_seqs, cfg.max_pages_per_seq
-        pool_shape = (cfg.num_pages, self._num_heads, cfg.page_size,
-                      self._head_dim)
         # kv_cache_dtype narrows the pool STORAGE only: quantized pools
         # are (codes, scales) pairs with one f32 scale per (page, head)
         self._kv_quant = resolve_kv_cache_dtype(cfg.kv_cache_dtype)
+        # plain pools off-mesh on a TPU are ROW pages [pages, page,
+        # heads*head_dim] and decode through the Pallas kernel; every
+        # other engine keeps the head-major pool of the XLA composition
+        # (incubate/nn/paged_attention.py has both)
+        self._kv_rows = (self._kv_quant is None and self._mesh is None
+                         and row_pages_default(
+                             cfg.dtype, self._num_heads, self._head_dim,
+                             cfg.page_size))
+        pool_shape = (
+            (cfg.num_pages, cfg.page_size,
+             self._num_heads * self._head_dim) if self._kv_rows else
+            (cfg.num_pages, self._num_heads, cfg.page_size,
+             self._head_dim))
 
         # allocated where they live (device= takes a sharding too): a
         # pinned replica must not stage its pools through device 0
@@ -374,7 +389,8 @@ class LLMEngine:
         if program_cache is not None:
             from paddle_tpu.serving.aot_cache import engine_fingerprint
             self._program_fp = engine_fingerprint(
-                mc, cfg, self._params, self._mesh)
+                mc, cfg, self._params, self._mesh,
+                attention=self.attention_path)
 
         self._compiled = {}
         self._requests = {}          # live (queued or running) only
@@ -486,6 +502,15 @@ class LLMEngine:
             return jax.device_put(value, self._device)
         return jax.device_put(
             value, sharding if sharding is not None else self._repl_sharding)
+
+    @property
+    def attention_path(self):
+        """What the decode program's attention was built from — the
+        AOT fingerprint's term for it: the Pallas kernel at its
+        revision, or the XLA composition."""
+        if not self._kv_rows:
+            return "xla"
+        return f"paged_decode/{PAGED_DECODE_REVISION}"
 
     @property
     def program_fingerprint(self):
@@ -679,11 +704,17 @@ class LLMEngine:
         L = int(self._lens[slot])
         pages = list(self._alloc.owned_pages(slot))
         layers = []
+
+        def owned(pool):
+            # the handoff format is head-major [n, heads, page, d]
+            # whatever the local pool layout is
+            blk = np.asarray(pool)[pages]
+            return (from_row_pages(blk, self._num_heads)
+                    if self._kv_rows else blk)
+
         for k_pool, v_pool in zip(self._k_pools, self._v_pools):
             if self._kv_quant is None:
-                layers.append({
-                    "k": np.asarray(k_pool)[pages],
-                    "v": np.asarray(v_pool)[pages]})
+                layers.append({"k": owned(k_pool), "v": owned(v_pool)})
             else:
                 layers.append({
                     "k_codes": np.asarray(k_pool[0])[pages],
@@ -811,13 +842,20 @@ class LLMEngine:
         for pos, page in enumerate(pages):
             self._tables[slot, pos] = page
         idx = np.asarray(pages)
+
+        def local(blk):
+            # head-major handoff block -> this engine's pool layout
+            blk = np.asarray(blk)
+            return jnp.asarray(to_row_pages(blk) if self._kv_rows
+                               else blk)
+
         for li in range(self._num_layers):
             blk = state["layers"][li]
             if self._kv_quant is None:
                 self._k_pools[li] = self._k_pools[li].at[idx].set(
-                    jnp.asarray(blk["k"]))
+                    local(blk["k"]))
                 self._v_pools[li] = self._v_pools[li].at[idx].set(
-                    jnp.asarray(blk["v"]))
+                    local(blk["v"]))
             else:
                 kc, ks = self._k_pools[li]
                 vc, vs = self._v_pools[li]
@@ -1028,7 +1066,15 @@ class LLMEngine:
 
     # -------------------------------------------------------- decode
     def _decode_step(self, events):
-        with span("serving.decode", live=self.num_running):
+        # pages_live: the pages this step's attention has to read, from
+        # the host-side lengths (before the capacity pass evicts anyone)
+        page = self.config.page_size
+        pages_live = sum(-(-(int(self._lens[s]) + 1) // page)
+                         for s, r in enumerate(self._slots)
+                         if r is not None)
+        self.metrics.pages_live = pages_live
+        with span("serving.decode", live=self.num_running,
+                  pages_live=pages_live, kernel=self._kv_rows):
             self._decode_step_inner(events)
 
     def _decode_step_inner(self, events):

@@ -103,6 +103,7 @@ class EngineMetrics:
         self.running = 0
         self.pages_in_use = 0
         self.pages_total = 0
+        self.pages_live = 0          # pages the last decode step read
         self.health = "healthy"      # engine-pushed health-state name
         self.health_state = reg.gauge(
             "serving_health_state", labels=labels,
@@ -116,6 +117,9 @@ class EngineMetrics:
         self.page_occupancy_gauge = reg.gauge(
             "serving_page_occupancy", labels=labels,
             help="KV page-pool occupancy fraction (0..1)")
+        self.pages_live_gauge = reg.gauge(
+            "serving_pages_live", labels=labels,
+            help="KV pages the last decode step's attention had to read")
         # histograms (seconds) — registry-owned, engine-labeled
         self.ttft = reg.histogram(
             "serving_ttft_seconds", labels=labels,
@@ -165,6 +169,7 @@ class EngineMetrics:
         self.page_occupancy_gauge.set(
             self.pages_in_use / self.pages_total if self.pages_total
             else 0.0)
+        self.pages_live_gauge.set(self.pages_live)
 
     def note_aot_load(self):
         """One program loaded from the persisted AOT cache — NOT a
@@ -214,6 +219,7 @@ class EngineMetrics:
             "pages": {
                 "in_use": self.pages_in_use,
                 "total": self.pages_total,
+                "live": self.pages_live,
                 "utilization": round(
                     self.pages_in_use / self.pages_total, 4)
                 if self.pages_total else 0.0,
