@@ -59,3 +59,16 @@ def flash_step_work(cfg: dict, batch: int, seq: int) -> tuple[float, float]:
     flops = layers * 6 * 2.0 * batch * seq * keys * h
     nbytes = layers * 12 * batch * seq * h * 2.0
     return flops, nbytes
+
+
+def paged_decode_work(cfg: dict, live_tokens: float,
+                      itemsize: int) -> tuple[float, float]:
+    """(FLOPs, bytes) that decode attention needs to read ``live_tokens``
+    cached positions (summed over slots and steps) in every layer, whatever
+    kernel runs it: the K and the V row of each position, heads x head_dim
+    values of ``itemsize`` bytes each, once; QK^T and PV are 2 x head_dim
+    multiply-adds a head and position.  The query, the output and the new
+    row's append are a slot's one row each and are not counted."""
+    width = cfg["num_attention_heads"] * cfg["head_dim"]
+    rows = cfg["num_hidden_layers"] * float(live_tokens)
+    return 4.0 * rows * width, 2.0 * rows * width * itemsize

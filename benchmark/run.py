@@ -27,7 +27,7 @@ import types  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import compare, harness  # noqa: E402
+from benchmark import compare, harness, hostspans  # noqa: E402
 
 
 def parse_args(argv=None):
@@ -122,6 +122,16 @@ def open_cell(args):
     return manifest, ctx, harness.load_module("runners", ctx.cell["runner"])
 
 
+def breakdown_of(summary, profile):
+    """The result line's ``breakdown``: the ten device operations that took
+    most time, and the device's idle seconds by what the host was doing
+    (``hostspans.idle_gaps``: the program's innermost span, ``outside`` all
+    of them, or ``short`` for gaps too short to place), ten rows at most."""
+    gaps = hostspans.idle_gaps(profile) or []
+    return {"device_ops": summary["top_ops"][:10],
+            "idle_gaps": [row[:2] for row in gaps[:10]]}
+
+
 def finish(ctx, manifest, out):
     """Choose the metrics of this run's kind, read the trace, judge the
     numbers compared, and build the result line."""
@@ -139,13 +149,15 @@ def finish(ctx, manifest, out):
     metrics, breakdown = {}, None
     if ctx.trace:
         from benchmark import xplane
-        path = ctx.capture.xplane_path()
-        summary = xplane.summarize(path) if path else None
+        # parsed once: the per-layer readers get the same capture from
+        # ``hostspans.load_current``
+        profile = hostspans.load(ctx.capture.xplane_path())
+        summary = (xplane.reduce_events(xplane.device_events(profile))
+                   if profile is not None else None)
         if summary is not None:
             device["busy_s"] = summary["busy_s"]
             device["window_s"] = summary["window_s"]
-            breakdown = {"device_ops": summary["top_ops"][:10],
-                         "idle_gaps": []}
+            breakdown = breakdown_of(summary, profile)
         record = dict(out["record"], trace=summary, spans=ctx.spans.durations,
                       window_s=out["window_s"], cfg=ctx.cfg,
                       traffic=ctx.traffic, peak=ctx.peak, chips=ctx.chips,

@@ -92,7 +92,7 @@ def drive(ctx, engine, requests, seconds, ramp=(), clock=time.perf_counter,
     tokens = [[] for _ in range(n)]
     finished = [False] * n
     failed, late = 0, []
-    decode_only, steps, queue = [], 0, []
+    decode_only, steps, queue, step_prefills = [], 0, [], []
     drain = ctx.traffic["drain_seconds"]
     hard_stop = seconds + drain
     index_of, events = offer_ramp(ctx, engine, ramp)
@@ -144,7 +144,8 @@ def drive(ctx, engine, requests, seconds, ramp=(), clock=time.perf_counter,
             t_e = clock()
             steps += 1
             queue.append((t_e - t0, engine.queue_depth))
-            if engine.metrics.prefill_steps == prefills and not closed:
+            step_prefills.append(engine.metrics.prefill_steps - prefills)
+            if not step_prefills[-1] and not closed:
                 decode_only.append(t_e - t_s)
             for rid, tok, fin in events:
                 k = index_of.get(rid)
@@ -169,7 +170,47 @@ def drive(ctx, engine, requests, seconds, ramp=(), clock=time.perf_counter,
             "tokens": tokens, "finished": finished,
             "failed": failed + never, "late": late,
             "window_end": window_end, "decode_only": decode_only,
-            "steps": steps, "queue": queue}
+            "steps": steps, "queue": queue, "step_prefills": step_prefills}
+
+
+def gap_modes(served):
+    """Where the tail of the token gaps sits: every gap of the window with
+    the number of prefills in the engine steps it spans, as {0 | 1 | 2:
+    [gaps in ms]} (2 = two or more).  A running stream gets one token a
+    step, so a gap is one step: a plain decode step, or one that one or
+    more admitted prompts' prefills stalled."""
+    step_of = {t: i for i, (t, _depth) in enumerate(served["queue"])}
+    before = np.concatenate([[0], np.cumsum(served["step_prefills"])])
+    modes = {0: [], 1: [], 2: []}
+    for times in served["token_times"]:
+        times = [t for t in times if t >= 0.0]
+        for a, b in zip(times, times[1:]):
+            n = int(before[step_of[b] + 1] - before[step_of[a] + 1])
+            modes[min(n, 2)].append(1e3 * (b - a))
+    return modes
+
+
+def note_window(ctx, served, gaps):
+    """What kind of window it was, for whoever reads the run: how the
+    waiting queue moved and which steps the gaps' 95th percentile lies in."""
+    third = served["window_end"] / 3
+    depth = [[q for t, q in served["queue"] if lo <= t < lo + third]
+             for lo in (0.0, third, 2 * third)]
+    ctx.note("waiting queue, mean by thirds of the window: " + " -> ".join(
+        f"{np.mean(d):.1f}" if d else "-" for d in depth))
+    if not gaps:
+        return
+    modes, p95 = gap_modes(served), stats.percentile(gaps, 95)
+    parts = []
+    for n, label in ((0, "0"), (1, "1"), (2, "2+")):
+        v = modes[n]
+        if v:
+            parts.append(f"{label}: {100 * len(v) / len(gaps):.2f}% "
+                         f"(median {stats.median(v):.2f} ms, "
+                         f"{100 * sum(g <= p95 for g in v) / len(v):.1f}% "
+                         f"of them <= p95)")
+    ctx.note(f"token gaps by prefills in their step: {'; '.join(parts)}; "
+             f"p95 {p95:.3f} ms of {len(gaps)} gaps")
 
 
 def checked_sample(requests, served, how_many, seed):
@@ -278,6 +319,7 @@ def run(ctx):
              f"mean {1e3 * np.mean(late) if late else 0:.2f} ms, max "
              f"{1e3 * max(late) if late else 0:.2f} ms")
 
+    note_window(ctx, served, gaps)
     picks = checked_sample(requests, served, mix["checked_requests"], ctx.seed)
     ctx.checked = (requests, served, picks)      # for tools and tests
     numbers = {}

@@ -51,3 +51,15 @@ def test_serve_flops_one_request():
     # prompt 3, 2 new tokens: positions 0..3 are fed (4), keys 1+2+3+4 = 10
     want = 2 * body * 4 + 4 * 24 * 1024 * 10 + 2 * 50304 * 1024 * 2
     assert flops.serve_flops(c, 3, 2) == want
+
+
+def test_paged_decode_work():
+    c = cfg("gpt3-medium")
+    # 1000 cached positions: a K and a V row of 16 x 64 bf16 values each in
+    # 24 layers; QK^T and PV are 2 x 2 x 64 FLOPs a head and position
+    f, b = flops.paged_decode_work(c, 1000, 2)
+    assert b == 24 * 1000 * 2 * 1024 * 2
+    assert f == 24 * 1000 * 16 * 4 * 64
+    assert f / b == 1.0            # 1 FLOP a byte in bf16: bound by bytes
+    assert flops.paged_decode_work(c, 1000, 4) == (f, 2 * b)
+    assert flops.paged_decode_work(c, 0, 2) == (0.0, 0.0)

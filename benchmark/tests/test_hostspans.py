@@ -50,7 +50,7 @@ def _hand_made(offset_us=1000.0):
         _ev("serving.prefill", 10150, 2200, request="req-0", bucket=128),
         _ev("DoEnqueueProgram", 10200, 40, run_id=1),
         _ev("serving.sample", 12250, 80, width=1),
-        _ev("serving.decode", 12450, 6400, live=2),
+        _ev("serving.decode", 12450, 6400, live=2, pages_live=40),
         _ev("DoEnqueueProgram", 12800, 40, run_id=2),
         _ev("serving.sample", 17850, 900, width=2),
         _ev("DoEnqueueProgram", 18600, 40, run_id=3),
@@ -58,7 +58,7 @@ def _hand_made(offset_us=1000.0):
         _ev("serve.step", 20500, 6000),
         _ev("serving.step", 20520, 5900, running=2, waiting=0),
         _ev("serving.admit", 20540, 20),
-        _ev("serving.decode", 20600, 5700, live=2),
+        _ev("serving.decode", 20600, 5700, live=2, pages_live=41),
         _ev("DoEnqueueProgram", 20900, 40, run_id=4),
         _ev("serving.sample", 25950, 300, width=2),
     ]
@@ -233,3 +233,77 @@ def test_span_trace_puts_each_sleep_down_to_its_span():
     assert hs.self_time(parent) + sum(c.seconds for c in parent.children) \
         == pytest.approx(parent.seconds)
     assert hs.self_time(parent) >= 0.004           # the sleep in it alone
+
+
+def test_the_result_lines_breakdown_holds_the_gap_table():
+    from benchmark import run, xplane
+    got = run.breakdown_of(xplane.summarize(SPANS), hs.load(SPANS))
+    rows = got["idle_gaps"]
+    assert rows and len(rows) <= 10 and json.loads(json.dumps(rows)) == rows
+    assert all(isinstance(n, str) and isinstance(s, float) and s > 0
+               for n, s in rows)
+    # the two sleeps in io.b (3 + 5 ms), the one in serving.step alone, the
+    # one outside every span, and the gaps too short to place
+    table = dict(rows)
+    assert set(table) >= {"io.b", "serving.step", "outside"}
+    assert 0.008 <= table["io.b"] <= 0.013
+    full = hs.idle_gaps(hs.load(SPANS))
+    assert rows == [r[:2] for r in full]
+    assert got["device_ops"] and len(got["device_ops"]) <= 10
+
+
+# ------------------------------------------- paged_decode_roofline.serve
+def _paged_run(profile_ops):
+    from benchmark import peaks
+    cfg = {"num_attention_heads": 16, "head_dim": 64, "num_hidden_layers": 24}
+    traffic = {"engine": {"page_size": 16, "dtype": "bfloat16"}}
+    return {"trace": {"op_seconds": profile_ops}, "chips": 1, "cfg": cfg,
+            "peak": peaks.peak_for("TPU v5 lite"), "traffic": traffic}
+
+
+def test_paged_decode_roofline_reads_pages_live_over_the_kernels_time(
+        monkeypatch):
+    from benchmark import harness
+    reader = harness.load_module("layer_metrics", "paged_decode_roofline.serve")
+    monkeypatch.setattr(hs, "load_current", _hand_made)
+    # the two decode spans read 40 + 41 pages of 16 rows: K and V rows of
+    # 1024 bf16 values in 24 layers = 81 * 16 * 24 * 2 * 1024 * 2 bytes
+    need = 81 * 16 * 24 * 2 * 1024 * 2
+    least = need / 819e9
+    ops = {"%paged_decode.3 = bf16[32,1,1024]{2,1,0} custom-call(...)":
+           4 * least,
+           "%paged_decode.7 = bf16[32,1,1024]{2,1,0} custom-call(...)":
+           4 * least,
+           "%fusion.1 = bf16[32,1024]{1,0} fusion(%paged_decode.3)": 1.0}
+    assert reader.read(_paged_run(ops)) == pytest.approx(100.0 / 8)
+
+
+def test_paged_decode_roofline_reads_nothing_without_the_kernel(monkeypatch):
+    from benchmark import harness
+    reader = harness.load_module("layer_metrics", "paged_decode_roofline.serve")
+    monkeypatch.setattr(hs, "load_current", _hand_made)
+    no_kernel = {"%fusion.1 = bf16[32,1024]{1,0} fusion(%paged_decode.3)": 1.0}
+    assert reader.read(_paged_run(no_kernel)) is None
+    assert reader.read(dict(_paged_run(no_kernel), trace=None)) is None
+    # the kernel ran, but the capture holds no serving.decode span
+    monkeypatch.setattr(hs, "load_current", lambda: hs.load(SMALL))
+    some = {"%paged_decode.3 = bf16[32,1,1024]{2,1,0} custom-call(...)": 1.0}
+    assert reader.read(_paged_run(some)) is None
+
+
+def test_occupancy_reads_how_full_the_engine_ran_from_the_spans():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "idle_gaps_tool", os.path.join(os.path.dirname(HERE), "tools",
+                                       "idle_gaps.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    occ = tool.occupancy(hs.host_spans(_hand_made()))
+    assert occ["decode_spans"] == 2 and occ["widest_live"] == 2
+    assert occ["share_at_widest"] == 1.0 and occ["mean_pages_live"] == 40.5
+    # step 1 (waiting 1) starts the capture, step 2 (waiting 0) starts in
+    # its second third; no step starts in the last
+    assert occ["waiting_by_thirds"] == [1.0, 0.0, None]
+    assert tool.occupancy(hs.host_spans(hs.load(SMALL))) is None
+    rep = tool.report(_hand_made())
+    assert rep["occupancy"] == occ and rep["idle_gaps"]

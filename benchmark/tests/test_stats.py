@@ -2,6 +2,8 @@
 stall in it."""
 import math
 
+import pytest
+
 from benchmark import stats
 
 
@@ -38,3 +40,22 @@ def test_due_time_counts_the_wait_a_stall_imposes():
 def test_gaps_are_pooled_over_requests():
     gaps = stats.gaps_ms([[0.0, 0.01, 0.03], [1.0], [2.0, 2.5]])
     assert [round(g, 6) for g in gaps] == [10.0, 20.0, 500.0]
+
+
+def test_gap_modes_count_the_prefills_in_the_steps_a_gap_spans():
+    from benchmark.runners import serve
+    # five engine steps ending at 1..5 (x 10 ms); steps 2 and 5 held one
+    # prefill, step 4 two.  Stream A got a token in every step, stream B
+    # (admitted in step 2) from step 2 on but none in step 4 (preempted);
+    # a ramp request's first token (stamped -1) opens no gap.
+    ends = [0.01, 0.02, 0.03, 0.04, 0.05]
+    served = {"queue": [(t, 0) for t in ends],
+              "step_prefills": [0, 1, 0, 2, 1],
+              "token_times": [[-1.0] + ends, [0.02, 0.03, 0.05], []]}
+    modes = serve.gap_modes(served)
+    assert modes[0] == pytest.approx([10.0, 10.0])         # A: 2->3; B: 2->3
+    assert modes[1] == pytest.approx([10.0, 10.0])         # A: 1->2, 4->5
+    assert modes[2] == pytest.approx([10.0, 20.0])         # A: 3->4; B: 3->5
+    assert sum(map(len, modes.values())) == len(
+        stats.gaps_ms([[t for t in ts if t >= 0] for ts in
+                       served["token_times"]]))

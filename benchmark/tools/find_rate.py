@@ -77,6 +77,8 @@ def main():
                                     if t), default=None)}
         print(json.dumps(row), flush=True)
         rows.append(row)
+        while engine.has_unfinished():    # a rate above the knee leaves a
+            engine.step()                 # queue: the next starts empty
     engine.shutdown()
     os.makedirs(os.path.dirname(os.path.abspath(a.out)), exist_ok=True)
     with open(a.out, "w") as f:

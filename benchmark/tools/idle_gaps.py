@@ -1,6 +1,8 @@
 """One traced run of a cell, then what the host did in the device's idle
-gaps: the clock offset bracket, the gap table (the shape
-``breakdown.idle_gaps`` wants) and every span's count, median and self time.
+gaps: the clock offset bracket, the gap table (``breakdown.idle_gaps`` holds
+its first two columns), every span's count, median and self time, and how
+full the engine ran (the ``live``, ``pages_live`` and ``waiting`` that
+``serving.decode`` and ``serving.step`` carry).
 
     python benchmark/tools/idle_gaps.py --workload <cell> --seed 1 \
         --seconds 30 [--out chiprun_out/gaps_<cell>.json]
@@ -26,7 +28,8 @@ def report(profile):
     busy = hostspans.device_busy(profile)
     gaps = hostspans.idle_gaps(profile)
     by_name = {}
-    for s in hostspans.host_spans(profile):
+    host_spans = hostspans.host_spans(profile)
+    for s in host_spans:
         rec = by_name.setdefault(s.name, {"count": 0, "ms": [], "self_s": 0.0})
         rec["count"] += 1
         rec["ms"].append(1e3 * s.seconds)
@@ -40,7 +43,32 @@ def report(profile):
     return {"offset_ms": (None if bracket is None
                           else [bracket[0] / 1e6, bracket[1] / 1e6]),
             "window_s": window, "idle_s": idle, "idle_gaps": gaps,
-            "spans": spans}
+            "occupancy": occupancy(host_spans), "spans": spans}
+
+
+def occupancy(spans):
+    """How full the engine ran, from the spans' own attributes: the share of
+    ``serving.decode`` spans at the widest batch seen, the mean pages their
+    attention had to read, and the mean ``waiting`` of ``serving.step`` in
+    each third of the capture.  ``None`` where no engine step was traced."""
+    decode = [s for s in spans if s.name == "serving.decode"]
+    steps = [s for s in spans if s.name == "serving.step"]
+    if not decode or not steps:
+        return None
+    live = [int(s.stats.get("live", 0)) for s in decode]
+    pages = [int(s.stats.get("pages_live", 0)) for s in decode]
+    t0, t1 = steps[0].start, steps[-1].end
+    thirds = [[], [], []]
+    for s in steps:
+        k = min(2, int(3 * (s.start - t0) / max(t1 - t0, 1)))
+        thirds[k].append(int(s.stats.get("waiting", 0)))
+    widest = max(live)
+    return {"decode_spans": len(decode), "widest_live": widest,
+            "share_at_widest": live.count(widest) / len(live),
+            "mean_live": sum(live) / len(live),
+            "mean_pages_live": sum(pages) / len(pages),
+            "waiting_by_thirds": [sum(t) / len(t) if t else None
+                                  for t in thirds]}
 
 
 def show(rep, out=sys.stdout):
@@ -67,6 +95,13 @@ def show(rep, out=sys.stdout):
         if all(r[0] == "short" for r in gaps):
             print("no gap reaches the threshold: every idle interval is "
                   "under `short`", file=out)
+    occ = rep["occupancy"]
+    if occ is not None:
+        print(f"engine: {occ['decode_spans']} serving.decode spans, "
+              f"{100 * occ['share_at_widest']:.2f}% of them with "
+              f"{occ['widest_live']} slots live (mean {occ['mean_live']:.2f}),"
+              f" mean pages_live {occ['mean_pages_live']:.1f}; waiting by "
+              f"thirds {occ['waiting_by_thirds']}", file=out)
     print(f"{'span':28s} {'count':>7s} {'median ms':>10s} {'max ms':>10s} "
           f"{'total s':>9s} {'self s':>9s}", file=out)
     for name, r in rep["spans"].items():
