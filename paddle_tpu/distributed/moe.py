@@ -16,6 +16,13 @@ work), tokens over capacity are dropped (the residual connection carries
 them), and expert parallelism is a sharding annotation on the expert axis
 of the [E, C, d] dispatched activations — XLA's partitioner inserts the
 same all-to-all the reference issues by hand through NCCL.
+
+:class:`DroplessMoELayer` is the other formulation, for routers whose
+load may not be capped (DeepSeek-V3's sigmoid router): token-expert pairs
+are sorted by expert and every expert held runs over its own contiguous
+rows in ONE grouped product (``jax.lax.ragged_dot``; work proportional to
+``tokens x top_k``), so no token is dropped at any load and no ``[n, E,
+C]`` tensor exists.
 """
 from __future__ import annotations
 
@@ -31,6 +38,7 @@ from paddle_tpu.nn import initializer as I
 __all__ = [
     "BaseGate", "NaiveGate", "GShardGate", "SwitchGate",
     "MoELayer", "StackedExpertFFN", "dispatch_combine",
+    "DroplessMoELayer", "dropless_experts", "sigmoid_topk_route",
 ]
 
 
@@ -313,3 +321,130 @@ class MoELayer(nn.Layer):
         out = _constrain(out, self.ep_axis, None, None)
         y = paddle_tpu.einsum("nec,ecd->nd", combine, out)
         return y.reshape(orig_shape)
+
+
+# ------------------------------------------------------------- dropless
+def sigmoid_topk_route(h, gate_w, gate_bias, top_k, scale,
+                       norm_topk_prob=True):
+    """DeepSeek-V3's router (``scoring_func: sigmoid``, ``topk_method:
+    noaux_tc`` with one group) on tokens ``h [n, d]``: scores in float32
+    whatever ``h`` is stored in, the choice made on ``score + gate_bias``
+    (``e_score_correction_bias``), the weights from the plain scores of
+    the chosen, normalised over them and scaled.  Returns (weights
+    ``[n, k]`` f32, experts ``[n, k]`` int32)."""
+    import jax
+    import jax.numpy as jnp
+    g = jnp.matmul(h.astype(jnp.float32), gate_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    sc = jax.nn.sigmoid(g)
+    _, idx = jax.lax.top_k(sc + gate_bias.astype(jnp.float32), top_k)
+    top = jnp.take_along_axis(sc, idx, -1)
+    if norm_topk_prob:
+        top = top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+    return top * scale, idx.astype(jnp.int32)
+
+
+def dropless_experts(h, weights, idx, w13, w2, first=0):
+    """Routed experts without a capacity: ``sum_i weights[:, i] *
+    E_idx[:, i](h)`` over the experts HELD, ``E(h) = (silu(h W1) * (h
+    W3)) W2``.
+
+    ``h [n, d]``; ``weights`` / ``idx [n, k]`` from a router over ALL
+    experts; ``w13 [E_held, d, 2f]`` (``[W1 | W3]``) and ``w2 [E_held, f,
+    d]`` the stacked weights of experts ``first .. first + E_held - 1``.
+    A pair whose expert is held elsewhere adds nothing here (its share of
+    the result is another holder's).  The ``n * k`` pairs are sorted by
+    expert, so each expert's rows are contiguous and the two grouped
+    products do ``n * k`` rows of work; rows come back to their tokens by
+    the inverse permutation (a gather).  Returns (out ``[n, d]`` in ``h``'s dtype,
+    tokens per held expert ``[E_held]`` int32)."""
+    import jax
+    import jax.numpy as jnp
+    n, k = idx.shape
+    held = w13.shape[0]
+    local = idx.reshape(-1) - first
+    mine = (local >= 0) & (local < held)
+    local = jnp.where(mine, local, held)        # others sort to the end
+    order = jnp.argsort(local, stable=True)
+    counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    rows = h[order // k]                                     # [n*k, d]
+    a = jax.lax.ragged_dot(rows, w13, counts,
+                           preferred_element_type=jnp.float32)
+    gate, up = jnp.split(a, 2, -1)
+    act = (jax.nn.silu(gate) * up).astype(h.dtype)
+    y = jax.lax.ragged_dot(act, w2, counts,
+                           preferred_element_type=jnp.float32)
+    # rows past the held experts' are not this holder's: weight 0
+    wts = jnp.where(mine, weights.reshape(-1), 0.0)[order]
+    y = y * wts[:, None]
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    return y[back].reshape(n, k, -1).sum(1).astype(h.dtype), counts
+
+
+class DroplessMoELayer(nn.Layer):
+    """Sigmoid-routed experts with shared experts, nothing dropped
+    (DeepSeek-V3 / Kanana-2; equations in docs/serving.md "Latent pool
+    and dropless experts").
+
+    The layer is told which contiguous range of experts it holds
+    (``held = (first, count)``, default all): it routes over all
+    ``num_experts`` and computes the held experts' part of the result —
+    what expert parallelism asks of each holder; the shared experts are
+    every holder's alike (``shared=False`` leaves them to one holder).
+    Stacked leaves: ``w13 [count, d, 2f]``, ``w2 [count, f, d]``.
+
+    ``forward`` returns the layer's output; the tokens each held expert
+    got in that call are kept in ``last_counts`` (a traced ``[count]``
+    int32 inside a compiled program) for whoever counts the routing.
+    """
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, n_shared=0,
+                 routed_scaling_factor=1.0, norm_topk_prob=True, held=None,
+                 shared=True, initializer_range=0.02, make_parameter=None):
+        super().__init__()
+        self.d_model, self.num_experts, self.top_k = d_model, num_experts, top_k
+        self.scale, self.norm_topk_prob = routed_scaling_factor, norm_topk_prob
+        self.first, count = held if held is not None else (0, num_experts)
+        self.last_counts = None
+        make = make_parameter or (
+            lambda shape, std: self.create_parameter(
+                shape, default_initializer=(
+                    I.Normal(0.0, std) if std else I.Constant(0.0))))
+        std = initializer_range
+        self.gate_weight = make([d_model, num_experts], std)
+        # e_score_correction_bias: fitted while training, loaded with the
+        # weights; it moves the choice and never enters a weight
+        self.gate_bias = make([num_experts], 0.0)
+        self.w13 = make([count, d_model, 2 * d_expert], std)
+        self.w2 = make([count, d_expert, d_model], std)
+        self.has_shared = bool(shared and n_shared)
+        if self.has_shared:
+            self.shared_w13 = make([d_model, 2 * n_shared * d_expert], std)
+            self.shared_w2 = make([n_shared * d_expert, d_model], std)
+
+    def forward(self, x):
+        import jax
+        import jax.numpy as jnp
+
+        def fn(v, gw, gb, w13, w2, *shared):
+            h = v.reshape(-1, v.shape[-1])
+            weights, idx = sigmoid_topk_route(
+                h, gw, gb, self.top_k, self.scale, self.norm_topk_prob)
+            out, counts = dropless_experts(h, weights, idx, w13, w2,
+                                           self.first)
+            if shared:
+                a = jnp.matmul(h, shared[0],
+                               preferred_element_type=jnp.float32)
+                gate, up = jnp.split(a, 2, -1)
+                out = out + jnp.matmul(
+                    (jax.nn.silu(gate) * up).astype(h.dtype), shared[1],
+                    preferred_element_type=jnp.float32).astype(h.dtype)
+            return out.reshape(v.shape), counts
+
+        shared = ((self.shared_w13, self.shared_w2) if self.has_shared
+                  else ())
+        out, counts = apply(fn, x, self.gate_weight, self.gate_bias,
+                            self.w13, self.w2, *shared)
+        self.last_counts = counts
+        return out

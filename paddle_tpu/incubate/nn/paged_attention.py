@@ -47,6 +47,15 @@ functions live in :mod:`paddle_tpu.quantization.kv_cache` (same page
 geometry, pools become ``(codes, scales)`` pairs, ~0.52x bytes/token
 vs bf16) — the serving engine selects them via
 ``EngineConfig(kv_cache_dtype=)``; see docs/quantization.md.
+
+Latent pools (MLA, ``models/deepseek_v3.py``): ONE pool a layer of row
+pages ``[num_pages, page_size, width]`` — a token's row is ``[c | k_r]``
+padded with zeros to whole lane tiles (:func:`latent_pool_width`; the
+device's tiled layout pads the minor dimension so in any case), keys and
+values are both read from it.  :func:`latent_prefill_append`
+scatters a prompt's rows, :func:`latent_decode_step` appends one row a slot
+and attends in the absorbed form (:func:`latent_attend` is the XLA
+composition and the definition of the mathematics).
 """
 from __future__ import annotations
 
@@ -61,6 +70,11 @@ from paddle_tpu.core.tensor import Tensor
 __all__ = [
     "PageAllocator",
     "PagedKVCache",
+    "latent_attend",
+    "latent_decode_path",
+    "latent_decode_step",
+    "latent_pool_width",
+    "latent_prefill_append",
     "paged_attend",
     "paged_attention_decode",
     "paged_decode_step",
@@ -421,3 +435,87 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, seq_lens,
         lambda qv, kpg, vpg, tbl, ln: paged_attend(
             qv, kpg, vpg, tbl, ln, page_size, scale),
         q, k_pages, v_pages, block_tables, seq_lens)
+
+
+# --------------------------------------------------------- latent pools
+def latent_pool_width(row_width):
+    """A latent pool's minor dimension: the row, padded to whole lane
+    tiles of 128."""
+    return -(-int(row_width) // 128) * 128
+
+
+def latent_decode_path(dtype, rank, width, page_size):
+    """What decode attention over a latent pool of this geometry runs on
+    this platform: ``"mla_paged_decode"`` (the Pallas kernel: on a TPU,
+    outside any program GSPMD has to partition, for the pools it takes)
+    or ``"xla"`` (:func:`latent_attend`)."""
+    from paddle_tpu.ops.pallas import kernel_default
+    from paddle_tpu.ops.pallas.mla_paged_attention import \
+        mla_paged_decode_supported
+    if kernel_default() and mla_paged_decode_supported(dtype, rank, width,
+                                                       page_size):
+        return "mla_paged_decode"
+    return "xla"
+
+
+def _pad_to(x, width):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def latent_prefill_append(rows, pages, tables, lens, page_size):
+    """Scatter a prompt's latent rows: row t of sequence b lands in page
+    ``tables[b, t // page_size]`` at offset ``t % page_size``; positions
+    >= lens[b] go to the garbage page 0.  ``rows [b, S, w]``, ``pages [N,
+    page, W >= w]``.  Returns the pool."""
+    rows = _pad_to(rows, pages.shape[-1])
+    b, S, w = rows.shape
+    t = jnp.arange(S, dtype=jnp.int32)
+    page_idx = jnp.minimum(t // page_size, tables.shape[1] - 1)
+    page_ids = jnp.where(t[None, :] < lens[:, None].astype(jnp.int32),
+                         tables[:, page_idx], 0)             # [b, S]
+    return pages.at[page_ids.reshape(-1), jnp.tile(t % page_size, b)].set(
+        rows.reshape(b * S, w).astype(pages.dtype))
+
+
+def latent_attend(q, pages, tables, lens, rank, page_size, scale):
+    """Absorbed latent attention, the XLA composition: ``q [b, H, W]``
+    (``[q~ | q_r | 0]``, unscaled) over each slot's first ``lens[b]`` rows
+    of ``pages [N, page, W]``; scores over the whole row, the weighted sum
+    over its first ``rank`` columns -> ``[b, H, rank]``.  Both
+    contractions accumulate in float32, the softmax is float32, the
+    probabilities are rounded to the pool's dtype once."""
+    b = q.shape[0]
+    P = tables.shape[1]
+    seq = pages[tables].reshape(b, P * page_size, pages.shape[-1])
+    s = jnp.einsum("bhw,btw->bht", (q * scale).astype(pages.dtype), seq,
+                   preferred_element_type=jnp.float32)
+    live = jnp.arange(P * page_size)[None, None, :] < lens[:, None, None]
+    s = jnp.where(live, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(pages.dtype)
+    return jnp.einsum("bht,btr->bhr", p, seq[..., :rank],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def latent_decode_step(q, row_new, pages, tables, lens, rank, page_size,
+                       scale):
+    """One decode step over a latent pool WITHOUT length bookkeeping:
+    write each slot's new row at position ``lens[b]`` (in place — a row
+    scatter), then attend over ``lens[b] + 1`` rows.  ``q [b, H, w]``,
+    ``row_new [b, w]``, ``pages [N, page, W >= w]``.  Returns (u ``[b, H,
+    rank]``, pool).  The attention is what :func:`latent_decode_path`
+    says: the Pallas kernel ``mla_paged_decode``, which reads only the
+    live pages and each row once, or :func:`latent_attend`."""
+    lens = lens.astype(jnp.int32)
+    width = pages.shape[-1]
+    q, row_new = _pad_to(q, width), _pad_to(row_new, width)
+    page_ids = jnp.take_along_axis(tables, (lens // page_size)[:, None],
+                                   axis=1)[:, 0]
+    pages = pages.at[page_ids, lens % page_size].set(
+        row_new.astype(pages.dtype))
+    if latent_decode_path(pages.dtype, rank, width, page_size) != "xla":
+        from paddle_tpu.ops.pallas.mla_paged_attention import \
+            mla_paged_decode
+        return mla_paged_decode(q, pages, tables, lens + 1, rank=rank,
+                                scale=scale), pages
+    return latent_attend(q, pages, tables, lens + 1, rank, page_size,
+                         scale), pages

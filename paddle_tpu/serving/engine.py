@@ -50,9 +50,15 @@ from paddle_tpu.observability import (TraceContext, current_context,
 from paddle_tpu.core.dispatch import apply
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.incubate.nn.paged_attention import (PageAllocator,
+                                                    latent_decode_path,
+                                                    latent_decode_step,
+                                                    latent_pool_width,
+                                                    latent_prefill_append,
                                                     paged_decode_step,
                                                     paged_prefill_append,
                                                     row_pages_default)
+from paddle_tpu.ops.pallas.mla_paged_attention import \
+    MLA_PAGED_DECODE_REVISION
 from paddle_tpu.ops.pallas.paged_attention import (PAGED_DECODE_REVISION,
                                                    from_row_pages,
                                                    to_row_pages)
@@ -193,6 +199,14 @@ class PagedKVContext:
     ``(codes, scales)`` pairs and routes writes/reads through the
     quantized step functions — decode dequantizes in-trace with f32
     score/value accumulation.
+
+    A model that declares a LATENT cache (``kv_cache_spec()["kind"] ==
+    "latent"``: one row ``[c | k_r]`` a token) finds its pools in
+    ``k_pools`` alone (``v_pools`` is empty — keys and values are read
+    from the same row) and calls :meth:`latent_prefill` /
+    :meth:`latent_decode` in :meth:`attend`'s place; a model with
+    experts reports each expert layer's load through
+    :meth:`note_expert_counts`.
     """
 
     def __init__(self, k_pools, v_pools, tables, lens, page_size, mode,
@@ -205,16 +219,68 @@ class PagedKVContext:
         self.mode = mode
         self.quant = quant
         self._layer = 0
+        self.expert_counts = []      # one traced [experts] int32 a layer
 
-    def attend(self, q, k, v):
-        """q/k/v: Tensor [b, s, n_head, head_dim] -> Tensor same shape
-        (attention output); writes this layer's K/V into its pools."""
+    def _next_layer(self):
         li = self._layer
         self._layer += 1
         if li >= len(self.k_pools):
             raise RuntimeError(
                 f"model has more attention layers ({li + 1}+) than the "
                 f"engine allocated pools for ({len(self.k_pools)})")
+        return li
+
+    def latent_prefill(self, q, k, v, rows):
+        """Prefill of a latent-cache layer, expanded form: dense causal
+        attention of ``q`` / ``k [b, s, H, d_qk]`` and ``v [b, s, H,
+        d_v]`` (Tensors) -> ``[b, s, H, d_v]``; the prompt's latent
+        ``rows [b, s, w]`` are scattered into this layer's pool."""
+        li = self._next_layer()
+
+        def fn(qv, kv, vv, rv):
+            out = _dense_causal_attention(
+                jnp.swapaxes(qv, 1, 2), jnp.swapaxes(kv, 1, 2),
+                jnp.swapaxes(vv, 1, 2))
+            self.k_pools[li] = latent_prefill_append(
+                rv, self.k_pools[li], self.tables, self.lens,
+                self.page_size)
+            return jnp.swapaxes(out, 1, 2)
+
+        return apply(fn, q, k, v, rows)
+
+    def latent_decode(self, q, rows, rank, scale):
+        """Decode of a latent-cache layer, absorbed form: append each
+        slot's new row ``rows [b, 1, w]``, then attend ``q [b, 1, H, w]``
+        (``[q~ | q_r]``, unscaled) over the slot's live rows; the sum is
+        over a row's first ``rank`` columns -> ``[b, 1, H, rank]``."""
+        li = self._next_layer()
+
+        def fn(qv, rv):
+            u, self.k_pools[li] = latent_decode_step(
+                qv[:, 0], rv[:, 0], self.k_pools[li], self.tables,
+                self.lens, rank, self.page_size, scale)
+            return u[:, None]
+
+        return apply(fn, q, rows)
+
+    def note_expert_counts(self, counts):
+        """An expert layer's tokens per expert in this forward (traced
+        ``[experts]`` int32), for the engine's routing counters."""
+        self.expert_counts.append(counts)
+
+    def expert_stats(self):
+        """``[experts_hit, expert_tokens_max]`` int32: experts that got
+        at least one token, summed over layers, and the heaviest
+        expert's tokens in the worst layer — padding rows of the batch
+        or bucket included, as the program routed them."""
+        counts = jnp.stack(self.expert_counts)               # [L, E]
+        return jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(
+            jnp.int32)
+
+    def attend(self, q, k, v):
+        """q/k/v: Tensor [b, s, n_head, head_dim] -> Tensor same shape
+        (attention output); writes this layer's K/V into its pools."""
+        li = self._next_layer()
 
         def fn(qv, kv, vv):
             qT = jnp.swapaxes(qv, 1, 2)            # [b, h, s, d]
@@ -275,7 +341,19 @@ class LLMEngine:
       ``hidden_size`` (head_dim = hidden_size // num_heads);
     - ``model(input_ids, position_ids=..., kv_ctx=...)`` returns
       ``[b, s, vocab]`` logits, with every attention layer delegating to
-      ``kv_ctx.attend(q, k, v)`` when a context is passed.
+      ``kv_ctx.attend(q, k, v)`` when a context is passed;
+    - optionally ``model.kv_cache_spec()`` declares what a layer caches.
+      Absent (GPT): a K and a V pool a layer of ``num_heads x head_dim``.
+      ``{"kind": "latent", "row_width": w, "value_width": r,
+      "num_layers": n}``
+      (``models/deepseek_v3.py``): ONE pool a layer of row pages
+      ``[num_pages, page_size, w padded to lane tiles]``, written and read
+      through ``kv_ctx.latent_prefill`` / ``latent_decode``; such a model
+      takes ``logits_positions=`` in prefill (the head on one position a
+      row) and, if it has expert layers (``num_expert_layers``), reports
+      their load through ``kv_ctx.note_expert_counts``.  The latent pool
+      is plain and on one device: ``kv_cache_dtype``, a multi-device
+      ``mesh`` and the page hand-off refuse it by name.
 
     Public surface: :meth:`add_request`, :meth:`step`, :meth:`generate`,
     :attr:`metrics`, :meth:`shutdown`.
@@ -308,6 +386,29 @@ class LLMEngine:
                             for k, v in self._params.items()}
 
         B, P = cfg.max_num_seqs, cfg.max_pages_per_seq
+        # what a layer caches is the MODEL's to declare; no declaration
+        # is GPT's K and V of heads x head_dim
+        spec = (model.kv_cache_spec() if hasattr(model, "kv_cache_spec")
+                else {"kind": "kv"})
+        self._kv_kind = spec["kind"]
+        if self._kv_kind not in ("kv", "latent"):
+            raise ValueError(f"unknown kv cache kind {self._kv_kind!r}")
+        self._latent = self._kv_kind == "latent"
+        self._moe_layers = int(getattr(model, "num_expert_layers", 0))
+        self._moe_experts = int(getattr(mc, "n_routed_experts", 0))
+        self._moe_top_k = int(getattr(mc, "num_experts_per_tok", 0))
+        if self._latent:
+            self._num_layers = int(spec["num_layers"])
+            self._latent_row = int(spec["row_width"])
+            self._latent_rank = int(spec["value_width"])
+            if cfg.kv_cache_dtype is not None:
+                raise ValueError(
+                    f"kv_cache_dtype={cfg.kv_cache_dtype!r}: a 'latent' "
+                    f"pool is stored plain (no quantized latent pool)")
+            if self._mesh is not None:
+                raise ValueError(
+                    "mesh: a 'latent' pool has no head axis to shard "
+                    "and lives on one device")
         # kv_cache_dtype narrows the pool STORAGE only: quantized pools
         # are (codes, scales) pairs with one f32 scale per (page, head)
         self._kv_quant = resolve_kv_cache_dtype(cfg.kv_cache_dtype)
@@ -315,15 +416,19 @@ class LLMEngine:
         # heads*head_dim] and decode through the Pallas kernel; every
         # other engine keeps the head-major pool of the XLA composition
         # (incubate/nn/paged_attention.py has both)
-        self._kv_rows = (self._kv_quant is None and self._mesh is None
-                         and row_pages_default(
+        self._kv_rows = (not self._latent and self._kv_quant is None
+                         and self._mesh is None and row_pages_default(
                              cfg.dtype, self._num_heads, self._head_dim,
                              cfg.page_size))
-        pool_shape = (
-            (cfg.num_pages, cfg.page_size,
-             self._num_heads * self._head_dim) if self._kv_rows else
-            (cfg.num_pages, self._num_heads, cfg.page_size,
-             self._head_dim))
+        if self._latent:
+            pool_shape = (cfg.num_pages, cfg.page_size,
+                          latent_pool_width(self._latent_row))
+        else:
+            pool_shape = (
+                (cfg.num_pages, cfg.page_size,
+                 self._num_heads * self._head_dim) if self._kv_rows else
+                (cfg.num_pages, self._num_heads, cfg.page_size,
+                 self._head_dim))
 
         # allocated where they live (device= takes a sharding too): a
         # pinned replica must not stage its pools through device 0
@@ -338,7 +443,14 @@ class LLMEngine:
                               device=pool_dev))
 
         self._k_pools = [_pool() for _ in range(self._num_layers)]
-        self._v_pools = [_pool() for _ in range(self._num_layers)]
+        # a latent row holds keys and values both: no second pool
+        self._v_pools = ([] if self._latent else
+                         [_pool() for _ in range(self._num_layers)])
+        self._moe_stats = None       # the last program's expert_stats
+        # whether decode attention is a Pallas kernel (a span attribute)
+        self._decode_kernel = bool(
+            self._kv_rows or (self._latent
+                              and self._latent_decode_path() != "xla"))
         self._tables = np.zeros((B, P), np.int32)      # host-canonical
         self._lens = np.zeros((B,), np.int32)          # host-canonical
         self._alloc = PageAllocator(cfg.num_pages, B, P)
@@ -508,9 +620,18 @@ class LLMEngine:
         """What the decode program's attention was built from — the
         AOT fingerprint's term for it: the Pallas kernel at its
         revision, or the XLA composition."""
+        if self._latent:
+            path = self._latent_decode_path()
+            return "latent/" + (path if path == "xla" else
+                                f"{path}/{MLA_PAGED_DECODE_REVISION}")
         if not self._kv_rows:
             return "xla"
         return f"paged_decode/{PAGED_DECODE_REVISION}"
+
+    def _latent_decode_path(self):
+        return latent_decode_path(
+            self.config.dtype, self._latent_rank,
+            latent_pool_width(self._latent_row), self.config.page_size)
 
     @property
     def program_fingerprint(self):
@@ -694,6 +815,7 @@ class LLMEngine:
         `release` (default) the request leaves this engine entirely —
         slot, pages and live-table entry — so prefill workers stay
         empty-handed between handoffs."""
+        self._refuse_latent("export_page_state")
         req = self._requests.get(request_id)
         if req is None or req.slot is None:
             raise ValueError(
@@ -773,6 +895,7 @@ class LLMEngine:
         :class:`AdmissionRejected` when no slot/pages are free or this
         engine is DRAINING (the exporter still holds the state dict and
         can retry elsewhere)."""
+        self._refuse_latent("import_page_state")
         cfg = self.config
         geo = state["geometry"]
         mine = {"page_size": cfg.page_size,
@@ -874,6 +997,13 @@ class LLMEngine:
                   pages=n_pages, tokens=L):
             pass
         return rid
+
+    def _refuse_latent(self, what):
+        if self._latent:
+            raise NotImplementedError(
+                f"{what}: the page hand-off format is K and V blocks by "
+                f"head; a 'latent' pool has none (adopt_request replays "
+                f"the tokens instead)")
 
     def has_unfinished(self):
         return (self.scheduler.has_waiting()
@@ -1021,10 +1151,32 @@ class LLMEngine:
         L = len(tokens)
         bucket = self.scheduler.bucket_for_len(L)
         with span("serving.prefill", ctx=req.trace,
-                  request=req.request_id, bucket=bucket, tokens=L):
-            self._prefill_inner(req, events, cfg, t0, tokens, L, bucket)
+                  request=req.request_id, bucket=bucket,
+                  tokens=L) as span_:
+            self._prefill_inner(req, events, cfg, t0, tokens, L, bucket,
+                                span_)
 
-    def _prefill_inner(self, req, events, cfg, t0, tokens, L, bucket):
+    def _note_experts(self, span_, rows):
+        """After a sample fetch that carried a program's expert stats:
+        put them on the program's span and into the counters.  `rows`:
+        the tokens the program routed in every expert layer."""
+        if not self._moe_layers or self._moe_stats is None:
+            return
+        hit, tokens_max = (int(x) for x in self._moe_stats)
+        self._moe_stats = None
+        span_.set(experts_hit=hit, expert_tokens_max=tokens_max)
+        # a capture keeps a span's attributes as they were at entry, so
+        # the same numbers also go down as a marker the capture can read
+        with span("serving.experts", experts_hit=hit,
+                  expert_tokens_max=tokens_max, rows=rows,
+                  layers=self._moe_layers):
+            pass
+        self.metrics.note_experts(
+            rows * self._moe_top_k * self._moe_layers, tokens_max,
+            rows * self._moe_top_k / self._moe_experts)
+
+    def _prefill_inner(self, req, events, cfg, t0, tokens, L, bucket,
+                       span_):
         slot = self._slots.index(None)
         self._slots[slot] = req
         req.slot = slot
@@ -1039,13 +1191,14 @@ class LLMEngine:
         length = np.array([L], np.int32)
 
         fn = self._get_prefill(bucket)
-        last_logits, self._k_pools, self._v_pools = fn(
+        last_logits, self._k_pools, self._v_pools, *stats = fn(
             self._params, self._k_pools, self._v_pools,
             self._place(self._tables[slot:slot + 1]), self._place(ids),
             self._place(pos_ids), self._place(length))
         self._lens[slot] = L
 
-        tok = self._sample(last_logits, [req], width=1)[0]
+        tok = self._sample(last_logits, [req], width=1, carry=stats)[0]
+        self._note_experts(span_, bucket)
         now = self.metrics.clock()
         self.metrics.prefill_steps += 1
         self.metrics.prefill_step_s.observe(now - t0)
@@ -1074,10 +1227,11 @@ class LLMEngine:
                          if r is not None)
         self.metrics.pages_live = pages_live
         with span("serving.decode", live=self.num_running,
-                  pages_live=pages_live, kernel=self._kv_rows):
-            self._decode_step_inner(events)
+                  pages_live=pages_live,
+                  kernel=self._decode_kernel) as span_:
+            self._decode_step_inner(events, span_)
 
-    def _decode_step_inner(self, events):
+    def _decode_step_inner(self, events, span_):
         cfg = self.config
         t0 = self.metrics.clock()
         # chaos hook: injected pool exhaustion drives ONE deterministic
@@ -1140,6 +1294,10 @@ class LLMEngine:
                 raise
             self._recover_decode_fault(e, events)
             return
+        stats = ()
+        if self._moe_layers:
+            *out, last = out
+            stats = (last,)
         if cfg.guard:
             logits, self._k_pools, self._v_pools, flags = out
             live = self._quarantine_flagged(live, flags, events)
@@ -1148,7 +1306,9 @@ class LLMEngine:
         self._decode_fault_streak = 0
 
         reqs = [self._slots[s] for s in range(cfg.max_num_seqs)]
-        toks = self._sample(logits, reqs, width=cfg.max_num_seqs)
+        toks = self._sample(logits, reqs, width=cfg.max_num_seqs,
+                            carry=stats)
+        self._note_experts(span_, cfg.max_num_seqs)
         for s, r in live:
             self._lens[s] += 1
         now = self.metrics.clock()
@@ -1257,15 +1417,17 @@ class LLMEngine:
                       exc=type(exc).__name__)
 
     # ------------------------------------------------------ sampling
-    def _sample(self, logits, reqs, width):
+    def _sample(self, logits, reqs, width, carry=()):
         """reqs: per-row Request or None (padding rows).  Position is
         the ABSOLUTE index of the token being sampled = the row's cache
         length AFTER its input token was appended — which is exactly
-        `total_len` host-side."""
+        `total_len` host-side.  `carry`: the expert stats of the program
+        that made `logits` (a model with expert layers), which ride this
+        step's one blocking fetch into ``_moe_stats``."""
         with span("serving.sample", width=width):
-            return self._sample_inner(logits, reqs, width)
+            return self._sample_inner(logits, reqs, width, carry)
 
-    def _sample_inner(self, logits, reqs, width):
+    def _sample_inner(self, logits, reqs, width, carry):
         seeds = np.zeros((width,), np.int32)
         pos = np.zeros((width,), np.int32)
         temps = np.zeros((width,), np.float32)
@@ -1281,10 +1443,13 @@ class LLMEngine:
             top_ks[i] = sp.top_k
             top_ps[i] = sp.top_p
         fn = self._get_sampler(width)
-        out = fn(self._place(logits), self._place(seeds),
-                 self._place(pos), self._place(temps),
-                 self._place(top_ks), self._place(top_ps))
-        return [int(t) for t in np.asarray(out)]
+        out = np.asarray(fn(
+            self._place(logits), self._place(seeds), self._place(pos),
+            self._place(temps), self._place(top_ks), self._place(top_ps),
+            *carry))
+        if carry:
+            self._moe_stats = out[width:]
+        return [int(t) for t in out[:width]]
 
     # ------------------------------------------------- finish / evict
     def _observe_resume(self, req, now):
@@ -1359,7 +1524,7 @@ class LLMEngine:
         m.sync_gauges()    # queue-depth / page-occupancy scrape gauges
 
     # ------------------------------------------------- compiled steps
-    def _run_model(self, params, ids, pos_ids, ctx):
+    def _run_model(self, params, ids, pos_ids, ctx, **kw):
         """Traced: rebind params, run the cache-aware forward."""
         sd = self._model.state_dict()
         saved = [(t, t._value) for t in sd.values()]
@@ -1368,7 +1533,7 @@ class LLMEngine:
                 t._value = params[k]
             with no_grad():
                 out = self._model(Tensor(ids), position_ids=Tensor(pos_ids),
-                                  kv_ctx=ctx)
+                                  kv_ctx=ctx, **kw)
             return out._value
         finally:
             for t, v in saved:
@@ -1403,6 +1568,13 @@ class LLMEngine:
             ctx = PagedKVContext(k_pools, v_pools, row_table, length,
                                  cfg.page_size, "prefill",
                                  quant=self._kv_quant)
+            if self._latent:
+                # the model's head runs on the last REAL token alone
+                last = self._run_model(
+                    params, ids, pos_ids, ctx,
+                    logits_positions=Tensor(length - 1))[:, 0]
+                return (last.astype(jnp.float32), ctx.k_pools,
+                        ctx.v_pools) + self._expert_stats(ctx)
             logits = self._run_model(params, ids, pos_ids, ctx)
             # logits [1, bucket, V] -> the last REAL token's row
             last = jnp.take_along_axis(
@@ -1417,6 +1589,11 @@ class LLMEngine:
             jnp.zeros((1, bucket), jnp.int32),
             jnp.zeros((1,), jnp.int32)), (1, 2), \
             self._step_out_shardings()
+
+    def _expert_stats(self, ctx):
+        """The extra output of a program of a model with expert layers
+        (nothing for any other model)."""
+        return (ctx.expert_stats(),) if self._moe_layers else ()
 
     def _guard_flags(self, logits, k_pools, v_pools, tables, lens):
         """Traced per-slot anomaly flags ``[B, 2]`` f32: column 0 is
@@ -1466,7 +1643,8 @@ class LLMEngine:
                 logits = logits[:, 0].astype(jnp.float32) + poison
                 flags = self._guard_flags(logits, ctx.k_pools,
                                           ctx.v_pools, tables, lens)
-                return (logits, ctx.k_pools, ctx.v_pools, flags)
+                return (logits, ctx.k_pools, ctx.v_pools,
+                        flags) + self._expert_stats(ctx)
 
             return decode, (
                 self._params, self._k_pools, self._v_pools,
@@ -1483,7 +1661,7 @@ class LLMEngine:
                                  quant=self._kv_quant)
             logits = self._run_model(params, tokens, lens[:, None], ctx)
             return (logits[:, 0].astype(jnp.float32),
-                    ctx.k_pools, ctx.v_pools)
+                    ctx.k_pools, ctx.v_pools) + self._expert_stats(ctx)
 
         return decode, (
             self._params, self._k_pools, self._v_pools,
@@ -1503,13 +1681,21 @@ class LLMEngine:
 
     def _sampler_program(self, width):
         V = int(self._model.config.vocab_size)
-        return sample_tokens, (
+        fn, carry = sample_tokens, ()
+        if self._moe_layers:
+            # the expert stats of the program that made the logits ride
+            # the token fetch: one array comes back, not two
+            def fn(logits, seeds, pos, temps, top_ks, top_ps, stats):
+                return jnp.concatenate([sample_tokens(
+                    logits, seeds, pos, temps, top_ks, top_ps), stats])
+            carry = (jnp.zeros((2,), jnp.int32),)
+        return fn, (
             jnp.zeros((width, V), jnp.float32),
             jnp.zeros((width,), jnp.int32),
             jnp.zeros((width,), jnp.int32),
             jnp.zeros((width,), jnp.float32),
             jnp.zeros((width,), jnp.int32),
-            jnp.ones((width,), jnp.float32)), (), \
+            jnp.ones((width,), jnp.float32)) + carry, (), \
             (self._repl_sharding if self._mesh is not None else None)
 
     def _get_prefill(self, bucket):

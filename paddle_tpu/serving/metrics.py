@@ -149,6 +149,23 @@ class EngineMetrics:
         self.decode_step_s = reg.histogram(
             "serving_decode_step_seconds", labels=labels,
             help="decode step wall time")
+        # expert routing (models with expert layers only): created by
+        # the first note_experts, so an engine without experts registers
+        # nothing and snapshots as before
+        self.moe_tokens_routed = 0   # token-expert pairs, all layers
+        self.moe_imbalance = None    # histogram of heaviest / mean load
+
+    def note_experts(self, pairs, tokens_max, mean_load):
+        """One program's routing: `pairs` token-expert pairs over all
+        expert layers, the heaviest expert's tokens (worst layer) and
+        the mean load of an expert in a layer."""
+        if self.moe_imbalance is None:
+            self.moe_imbalance = registry().histogram(
+                "serving_moe_expert_imbalance", labels=self.labels,
+                help="heaviest expert's tokens over the mean load, "
+                     "worst layer of a program")
+        self.moe_tokens_routed += int(pairs)
+        self.moe_imbalance.observe(float(tokens_max) / mean_load)
 
     def release(self):
         """Release this instance's claim on its registry instruments —
@@ -191,7 +208,11 @@ class EngineMetrics:
         """Plain-dict view of everything (stable keys; see
         docs/serving.md 'Metrics reference')."""
         elapsed = max(self.clock() - self.started_t, 1e-9)
+        moe = ({} if self.moe_imbalance is None else {"moe": {
+            "tokens_routed": self.moe_tokens_routed,
+            "expert_imbalance": self.moe_imbalance.summary()}})
         return {
+            **moe,
             "uptime_s": round(elapsed, 3),
             "requests": {
                 "received": self.requests_received,

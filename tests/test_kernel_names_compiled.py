@@ -116,3 +116,35 @@ def test_differentiated_step_keeps_the_kernel_in_the_name(compiled_text,
     assert sum(kernel in name for name in calls) == 1, sorted(calls)
     assert not any(name.split(".")[0].strip("_") in ("jvp", "transpose_jvp")
                    for name in calls)
+
+
+def test_mla_paged_decode_compiles_at_the_cell_size(one_chip):
+    """The latent decode kernel through Mosaic at
+    ``kanana2_serve_reasoning``'s sizes (32 slots x 256 pages of 16 rows
+    of 640 = 576 padded to lane tiles): it keeps its name and the pool is
+    read where it lies (no copy of a pool-shaped operand)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas.mla_paged_attention import mla_paged_decode
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda q, p, t, n: mla_paged_decode(
+            q, p, t, n, rank=512, scale=192 ** -0.5)).lower(
+                S((32, 32, 640), jnp.bfloat16),
+                S((8193, 16, 640), jnp.bfloat16),
+                S((32, 256), jnp.int32), S((32,), jnp.int32)
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = _kernel_calls(text)
+    assert [n.split("%")[-1].split(".")[0] for n in calls] == [
+        "mla_paged_decode"]
+    assert not [ln for ln in text.splitlines()
+                if "[8193,16,640]" in ln.split(" = ")[-1].split("(")[0]
+                and (" copy(" in ln or " convert(" in ln)]
