@@ -14,8 +14,9 @@ few files":
   compiled program's correctness depends on — model config, engine
   geometry (slots/pages/buckets/dtype), parameter tree (names, shapes,
   dtypes — never values), mesh spec, the decode attention path (XLA
-  composition, or the Pallas kernel and its revision — the programs'
-  CODE is otherwise not hashed), jax/jaxlib versions, and the
+  composition, or the Pallas kernel and its revision) and the sampler's
+  revision (the programs' CODE is otherwise not hashed), jax/jaxlib
+  versions, and the
   platform and kind of each device the programs run on
   (:func:`program_devices`).  Any component changing produces a
   DIFFERENT fingerprint directory, so invalidation is structural: stale
@@ -53,6 +54,7 @@ import jax
 from jax.experimental import serialize_executable as se
 
 from paddle_tpu.observability import span
+from paddle_tpu.serving import sampler
 
 __all__ = ["AOTProgramCache", "engine_fingerprint", "program_devices"]
 
@@ -86,8 +88,9 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None,
     fingerprint because XLA compiled against their avals.  `attention`
     names what the decode attention was built from
     (``LLMEngine.attention_path``: ``"xla"`` or the Pallas kernel with
-    its revision) — the one part of the CODE the digest covers, so two
-    trees that differ there never share an executable.
+    its revision); with the sampler's revision it is the part of the
+    CODE the digest covers, so two trees that differ in either never
+    share an executable.
     """
     import jaxlib
 
@@ -111,6 +114,7 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None,
                    bool(getattr(ec, "guard", False))),
         "mesh": _mesh_desc(mesh),
         "attention": str(attention),
+        "sampler": sampler.SAMPLER_REVISION,
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "devices": [(d.platform, d.device_kind)

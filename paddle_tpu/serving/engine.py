@@ -71,7 +71,7 @@ from paddle_tpu.resilience.health import HealthMonitor
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.request import (GenerationResult, Request,
                                         RequestState, SamplingParams)
-from paddle_tpu.serving.sampler import sample_tokens
+from paddle_tpu.serving.sampler import sample_tokens, sampler_path
 from paddle_tpu.serving.scheduler import (AdmissionRejected, Scheduler,
                                           default_buckets)
 
@@ -1424,10 +1424,6 @@ class LLMEngine:
         `total_len` host-side.  `carry`: the expert stats of the program
         that made `logits` (a model with expert layers), which ride this
         step's one blocking fetch into ``_moe_stats``."""
-        with span("serving.sample", width=width):
-            return self._sample_inner(logits, reqs, width, carry)
-
-    def _sample_inner(self, logits, reqs, width, carry):
         seeds = np.zeros((width,), np.int32)
         pos = np.zeros((width,), np.int32)
         temps = np.zeros((width,), np.float32)
@@ -1442,11 +1438,18 @@ class LLMEngine:
             temps[i] = sp.temperature
             top_ks[i] = sp.top_k
             top_ps[i] = sp.top_p
-        fn = self._get_sampler(width)
-        out = np.asarray(fn(
-            self._place(logits), self._place(seeds), self._place(pos),
-            self._place(temps), self._place(top_ks), self._place(top_ps),
-            *carry))
+        # the searches this call's program will run: it branches on the
+        # same three facts of the same operands
+        draws, any_k, any_p = sampler_path(temps, top_ks, top_ps)
+        path = ("greedy" if not draws else "top_k+top_p" if any_k and any_p
+                else "top_k" if any_k else "top_p" if any_p else "draw")
+        self.metrics.sampler_paths[path] += 1
+        with span("serving.sample", width=width, path=path):
+            fn = self._get_sampler(width)
+            out = np.asarray(fn(
+                self._place(logits), self._place(seeds), self._place(pos),
+                self._place(temps), self._place(top_ks),
+                self._place(top_ps), *carry))
         if carry:
             self._moe_stats = out[width:]
         return [int(t) for t in out[:width]]

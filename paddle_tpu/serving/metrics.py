@@ -14,6 +14,7 @@ source; see docs/serving.md 'Metrics reference').
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
 import weakref
@@ -149,6 +150,9 @@ class EngineMetrics:
         self.decode_step_s = reg.histogram(
             "serving_decode_step_seconds", labels=labels,
             help="decode step wall time")
+        # sample calls by the work their batch asked of the sampler:
+        # greedy | draw | top_k | top_p | top_k+top_p
+        self.sampler_paths = collections.Counter()
         # expert routing (models with expert layers only): created by
         # the first note_experts, so an engine without experts registers
         # nothing and snapshots as before
@@ -255,4 +259,5 @@ class EngineMetrics:
             "e2e_latency_ms": self.e2e_latency.summary(),
             "prefill_step_ms": self.prefill_step_s.summary(),
             "decode_step_ms": self.decode_step_s.summary(),
+            "sampler_paths": dict(self.sampler_paths),
         }

@@ -195,16 +195,16 @@ class _LogitTap:
 
     def __init__(self, engine):
         self.rows = {}
-        inner = engine._sample_inner
+        inner = engine._sample
 
-        def tapped(logits, reqs, width, carry):
+        def tapped(logits, reqs, width, carry=()):
             arr = np.asarray(logits)
             for i, r in enumerate(reqs):
                 if r is not None:
                     self.rows[(r.request_id, r.total_len)] = arr[i]
             return inner(logits, reqs, width, carry)
 
-        engine._sample_inner = tapped
+        engine._sample = tapped
 
 
 def test_engine_prefill_then_decode_matches_reference(model, weights):

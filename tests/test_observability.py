@@ -866,6 +866,45 @@ class TestEngineStepSpans:
             holders = [p for p in parents if _inside(s, p)]
             assert len(holders) == 1 and s.depth == holders[0].depth + 1
 
+    @pytest.mark.parametrize("knobs,path", [
+        ([dict(temperature=0.0)] * 2, "greedy"),
+        # the benchmark cells' mix: half greedy, half 0.8 / top-p 0.95
+        ([dict(temperature=0.0), dict(temperature=0.8, top_p=0.95)],
+         "top_p"),
+        ([dict(temperature=0.8), dict(temperature=0.0, top_k=5)], "draw"),
+        ([dict(temperature=0.0, top_p=0.5), dict(temperature=0.8, top_k=5)],
+         "top_k"),
+        ([dict(temperature=0.0), dict(temperature=0.8, top_k=5, top_p=0.9)],
+         "top_k+top_p"),
+    ])
+    def test_sample_span_and_counter_say_what_the_batch_asked(self, knobs,
+                                                              path):
+        """`path` names the searches the step's sampler program runs: a
+        greedy row's knobs ask for none, and a greedy request's prefill
+        sample (width 1) is `greedy` whatever its neighbours ask."""
+        from paddle_tpu import serving
+        engine = _tiny_engine()
+        rec = obs.recorder()
+        before = rec.total_recorded
+        try:
+            engine.generate([[1, 2, 3], [4, 5, 6, 7]], [
+                serving.SamplingParams(max_new_tokens=3, seed=i, **kw)
+                for i, kw in enumerate(knobs)])
+            samples = [r for r in rec.spans()[-(rec.total_recorded - before):]
+                       if r.name == "serving.sample"]
+            counted = engine.metrics.snapshot()["sampler_paths"]
+        finally:
+            engine.shutdown()
+        decodes = [r.attrs["path"] for r in samples if r.attrs["width"] == 2]
+        assert decodes == [path] * 2
+        prefills = [r.attrs["path"] for r in samples
+                    if r.attrs["width"] == 1]
+        assert len(prefills) == 2
+        assert prefills.count("greedy") == sum(
+            kw["temperature"] == 0.0 for kw in knobs)
+        assert sum(counted.values()) == len(samples) == 4
+        assert counted[path] >= 2
+
     def test_step_never_parents_a_requests_trace(self, engine_step_records):
         # serving.step belongs to no request: untraced requests under an
         # ambient context keep it as the thread's parent, but a request's

@@ -8,13 +8,14 @@ anything.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as P
 from paddle_tpu import serving
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt3_tiny
 from paddle_tpu.serving.request import Request, RequestState
-from paddle_tpu.serving.sampler import sample_tokens
+from paddle_tpu.serving.sampler import sample_tokens, sampler_path
 from paddle_tpu.serving.scheduler import (Scheduler, bucket_for,
                                           default_buckets)
 
@@ -193,6 +194,126 @@ class TestSampler:
             lg, temperatures=np.full(3, 1.0, np.float32),
             top_ps=np.full(3, 1e-6, np.float32))))
         np.testing.assert_array_equal(out, np.argmax(np.asarray(lg), -1))
+
+    # ---- the threshold search draws what the sorting sampler drew
+    def _rows(self, v, width, seed, temps, ks, ps):
+        """One seeded batch: the knobs cycle over the rows, each list
+        from another offset, so a batch mixes every combination."""
+        rng = np.random.default_rng(seed)
+        lg = (rng.standard_normal((width, v)) * 3).astype(np.float32)
+
+        def pick(xs, off):
+            return np.asarray([xs[(i + off) % len(xs)]
+                               for i in range(width)])
+        return self._args(
+            jnp.asarray(lg),
+            seeds=rng.integers(0, 2 ** 31 - 1, width).astype(np.int32),
+            positions=rng.integers(0, 4096, width).astype(np.int32),
+            temperatures=pick(temps, seed).astype(np.float32),
+            top_ks=pick(ks, seed // 2).astype(np.int32),
+            top_ps=pick(ps, seed // 3).astype(np.float32))
+
+    def _same_as_sorted(self, args):
+        got = np.asarray(jax.jit(sample_tokens)(*args))
+        want = np.asarray(jax.jit(jax.vmap(_sample_row_sorted))(*args))
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("seed", range(6))
+    @pytest.mark.parametrize("v,width", [(1000, 1), (1000, 32),
+                                         (50304, 32)])
+    def test_mixed_rows_draw_the_sorted_token(self, v, width, seed):
+        self._same_as_sorted(self._rows(
+            v, width, seed, temps=(0.0, 0.8, 1.5), ks=(0, 1, 20, v),
+            ps=(1.0, 0.95, 0.5, 1e-6, 0.95)))
+
+    def test_widest_vocabulary_draws_the_sorted_token(self):
+        self._same_as_sorted(self._rows(
+            128256, 32, 11, temps=(0.0, 0.8, 1.5), ks=(0, 20, 128256),
+            ps=(1.0, 0.95, 0.5, 1e-6, 0.95)))
+
+    @pytest.mark.parametrize("case,temps,ks,ps", [
+        ("all_greedy", (0.0,), (0, 20), (1.0, 0.5)),
+        ("no_top_p", (0.0, 0.8, 1.5), (0, 20), (1.0,)),
+        ("no_top_k", (0.0, 0.8, 1.5), (0,), (0.95, 0.5)),
+        ("draw_only", (0.8, 1.5), (0,), (1.0,)),
+        ("both_in_every_row", (0.8,), (20,), (0.5,)),
+    ])
+    @pytest.mark.parametrize("width", [1, 32])
+    def test_each_branch_draws_the_sorted_token(self, case, temps, ks, ps,
+                                                width):
+        args = self._rows(1000, width, 5, temps, ks, ps)
+        self._same_as_sorted(args)
+        path = tuple(map(bool, sampler_path(*map(np.asarray, args[3:]))))
+        want = {"all_greedy": (False, False, False),
+                "no_top_p": (None, None, False),
+                "no_top_k": (None, False, None),
+                "draw_only": (True, False, False),
+                "both_in_every_row": (True, True, True)}[case]
+        assert all(w is None or w == got for w, got in zip(want, path))
+
+    @pytest.mark.parametrize("seed", range(3))
+    def test_ties_at_the_kth_value_are_kept(self, seed):
+        """Logits on a coarse grid: the k-th value is shared by many
+        tokens, all of which stay (`scaled < kth` drops none of them)."""
+        args = list(self._rows(1000, 32, seed, temps=(0.8, 1.5),
+                               ks=(1, 5, 20, 500), ps=(1.0, 0.9)))
+        args[0] = jnp.round(args[0])
+        self._same_as_sorted(tuple(args))
+
+    @pytest.mark.parametrize("seed", range(3))
+    def test_rows_with_minus_inf_logits(self, seed):
+        args = list(self._rows(1000, 32, seed, temps=(0.0, 0.8, 1.5),
+                               ks=(0, 20, 1000), ps=(1.0, 0.95, 0.5)))
+        banned = np.random.default_rng(seed).random((32, 1000)) < 0.7
+        args[0] = jnp.where(jnp.asarray(banned), -jnp.inf, args[0])
+        self._same_as_sorted(tuple(args))
+
+    @pytest.mark.parametrize("width", [1, 4])
+    def test_no_sort_in_the_compiled_sampler(self, width):
+        def primitives(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+        args = self._rows(1000, width, 0, (0.8,), (20,), (0.5,))
+        names = set(primitives(jax.make_jaxpr(sample_tokens)(*args).jaxpr))
+        assert "cond" in names and names & {"while", "scan"}
+        assert not {n for n in names if "sort" in n or "top_k" in n}
+
+
+def _sample_row_sorted(logits, seed, position, temperature, top_k, top_p):
+    """The sampler as it was while it sorted the vocabulary, verbatim:
+    the reference `sample_tokens` must keep drawing from."""
+    _NEG_INF = jnp.finfo(jnp.float32).min
+    V = logits.shape[0]
+    logits = logits.astype(jnp.float32)
+
+    # temperature; <=0 means greedy (selected at the end)
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+
+    # top-k: mask everything below the k-th largest logit (k<=0: off)
+    k = jnp.where(top_k <= 0, V, jnp.clip(top_k, 1, V))
+    desc = jnp.sort(scaled)[::-1]
+    kth = desc[jnp.maximum(k - 1, 0)]
+    scaled = jnp.where(scaled < kth, _NEG_INF, scaled)
+
+    # top-p (nucleus) over the top-k-filtered distribution: keep the
+    # smallest prefix of descending-prob tokens whose mass reaches p
+    probs = jax.nn.softmax(scaled)
+    sp = jnp.sort(probs)[::-1]
+    cum = jnp.cumsum(sp)
+    keep_sorted = (cum - sp) < top_p        # mass BEFORE this token < p
+    keep_sorted = keep_sorted.at[0].set(True)  # never drop the argmax
+    pmin = jnp.min(jnp.where(keep_sorted, sp, jnp.inf))
+    log_probs = jnp.where(probs >= pmin, jnp.log(probs), _NEG_INF)
+
+    # Gumbel-max draw from the filtered distribution
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), position)
+    gumbel = jax.random.gumbel(key, (V,), jnp.float32)
+    sampled = jnp.argmax(log_probs + gumbel)
+
+    greedy = jnp.argmax(logits)
+    return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
 # -------------------------------------------------------------- metrics

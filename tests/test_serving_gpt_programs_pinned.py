@@ -1,7 +1,8 @@
 """GPT's serving programs are what they were before the engine learned to
 size its pools from a model's declaration (the PR that brought the latent
-pool): same prefill, decode and sampler jaxprs, same AOT fingerprint, so
-its stored programs and timings do not move.
+pool): same prefill and decode jaxprs, so its timings do not move.  The
+two sampler digests and the fingerprint are those of the sampler that
+searches its cut-offs (`SAMPLER_REVISION` 2); the model's four are older.
 
 The digests are of the jaxprs' text under this container's jax; a jax
 upgrade changes the text, not the programs: regenerate them then from a
@@ -19,10 +20,10 @@ PINNED = {
     "prefill_16": "dcedaa8bd41290b1",
     "prefill_32": "c5c9a83e2d916c04",
     "prefill_64": "800debbb78504944",
-    "sample_1": "81194823d936f36d",
-    "sample_4": "6fc6ca67f9a61ad4",
+    "sample_1": "754b408424c64bc3",
+    "sample_4": "4dd049c980ec47d2",
 }
-FINGERPRINT = "a76271ed9f1503f3e0f46cd2"
+FINGERPRINT = "b5ae386a0e38a4dc77417c47"
 
 
 @pytest.fixture(scope="module")

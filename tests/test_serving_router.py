@@ -135,6 +135,34 @@ class TestAOTProgramCache:
         e1.shutdown()
         e2.shutdown()
 
+    def test_sampler_revision_is_part_of_the_fingerprint(
+            self, tiny_model, monkeypatch):
+        """Two trees that differ only in what the sampler computes never
+        share a stored `sample/<width>`: the second engine compiles its
+        own and leaves the first one's entry where it was."""
+        from paddle_tpu.serving import sampler
+        d = tempfile.mkdtemp(prefix="ptpu_aot_sampler_")
+        try:
+            cache = AOTProgramCache(d)
+            e1 = serving.LLMEngine(tiny_model, _cfg(), program_cache=cache)
+            e1._get_sampler(1)
+            e1._get_sampler(4)
+            monkeypatch.setattr(sampler, "SAMPLER_REVISION",
+                                sampler.SAMPLER_REVISION + 1)
+            e2 = serving.LLMEngine(tiny_model, _cfg(), program_cache=cache)
+            assert e2.program_fingerprint != e1.program_fingerprint
+            assert not cache.entries(e2.program_fingerprint)
+            e2._get_sampler(1)
+            e2._get_sampler(4)
+            assert e2.metrics.aot_cache_loads == 0
+            assert e2.metrics.compile_count == 2
+            for fp in (e1.program_fingerprint, e2.program_fingerprint):
+                assert sorted(cache.entries(fp)) == ["sample_1", "sample_4"]
+            e1.shutdown()
+            e2.shutdown()
+        finally:
+            shutil.rmtree(d, ignore_errors=True)
+
     def test_corrupt_entry_degrades_to_compile(self, tiny_model):
         """A torn cache entry is a miss, not a crash: the engine
         recompiles and REPLACES the bad file."""
