@@ -21,17 +21,17 @@ then attends over the sequence's gathered pages with a length mask.
 Everything jits; the tape differentiates through the gathers if ever
 needed (serving is no_grad).
 
-Three layers of API, outermost first:
+Two layers of API:
 
-- :class:`PagedKVCache` — stateful single-layer cache (Tensor pools +
-  embedded allocator), the standalone/demo surface.
 - :class:`PageAllocator` — the HOST-side page bookkeeping alone
   (free list, per-slot ownership, leak guards). `paddle_tpu.serving`'s
-  engine uses one allocator across all transformer layers while the
-  device pools live as per-layer jnp arrays inside its compiled steps.
+  engine uses one allocator across all transformer layers and keeps the
+  block tables and lengths on the host, while the device pools live as
+  per-layer jnp arrays inside its compiled steps.
 - pure jnp step functions (:func:`paged_decode_step`,
   :func:`paged_prefill_append`, :func:`paged_attend`) — trace-safe
-  building blocks usable inside any jit/to_static program.
+  building blocks usable inside any jit/to_static program.  Which of
+  them a cache kind composes is `paddle_tpu/serving/kv_pool.py`'s.
 
 Two pool layouts, told apart by rank: the head-major 4-D pool above —
 the XLA composition, the definition of the mathematics, the only path
@@ -59,24 +59,17 @@ composition and the definition of the mathematics).
 """
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.core.dispatch import apply, unwrap
-from paddle_tpu.core.tensor import Tensor
-
 __all__ = [
     "PageAllocator",
-    "PagedKVCache",
     "latent_attend",
     "latent_decode_path",
     "latent_decode_step",
     "latent_pool_width",
     "latent_prefill_append",
     "paged_attend",
-    "paged_attention_decode",
     "paged_decode_step",
     "paged_prefill_append",
     "row_pages_default",
@@ -177,125 +170,6 @@ class PageAllocator:
         return True
 
 
-class PagedKVCache:
-    """Shared-pool KV cache with per-sequence block tables.
-
-    num_pages * page_size is the total token capacity shared by ALL
-    sequences — size it to tokens-in-flight, not batch * max_len.
-    """
-
-    def __init__(self, num_pages, page_size, num_heads, head_dim,
-                 batch, max_pages_per_seq, dtype=jnp.float32):
-        self.page_size = int(page_size)
-        self.k_pages = Tensor(jnp.zeros(
-            (num_pages, num_heads, page_size, head_dim), dtype))
-        self.v_pages = Tensor(jnp.zeros(
-            (num_pages, num_heads, page_size, head_dim), dtype))
-        self.block_tables = Tensor(jnp.zeros(
-            (batch, max_pages_per_seq), jnp.int32))
-        self.seq_lens = Tensor(jnp.zeros((batch,), jnp.int32))
-        self._alloc = PageAllocator(num_pages, batch, max_pages_per_seq)
-        self.max_pages_per_seq = int(max_pages_per_seq)
-
-    @property
-    def num_free_pages(self):
-        return self._alloc.num_free_pages
-
-    def owned_pages(self, b):
-        return self._alloc.owned_pages(b)
-
-    # ---- host-side page allocator (the serving loop's bookkeeping) ----
-    def ensure_capacity(self, b, new_len):
-        """Allocate pages so sequence `b` can hold `new_len` tokens.
-
-        A slot growing from zero owned pages is a FRESH sequence: its
-        device seq_len is reset to 0 so a reused slot can never write
-        its first token at a stale offset (the mid-decode-eviction bug:
-        released rows used to keep advancing batch-wide)."""
-        need = self._alloc.pages_needed(new_len, self.page_size)
-        fresh = not self._alloc.owned_pages(b) and need > 0
-        assigned = self._alloc.allocate(b, need)
-        if assigned:
-            tbl = np.array(unwrap(self.block_tables))  # writable host copy
-            for slot, pg in assigned:
-                tbl[b, slot] = pg
-            self.block_tables._set_value(jnp.asarray(tbl))
-        if fresh:
-            lens = np.asarray(unwrap(self.seq_lens)).copy()
-            lens[b] = 0
-            self.seq_lens._set_value(jnp.asarray(lens))
-
-    def release(self, b):
-        """Finished/evicted sequence: its pages return to the pool; its
-        block table resets to the garbage page so further batch-wide
-        appends from this row are harmlessly absorbed.  Idempotent, and
-        double-frees raise instead of silently growing the pool."""
-        self._alloc.release(b)
-        tbl = np.array(unwrap(self.block_tables))
-        tbl[b, :] = 0
-        self.block_tables._set_value(jnp.asarray(tbl))
-        lens = np.asarray(unwrap(self.seq_lens)).copy()
-        lens[b] = 0
-        self.seq_lens._set_value(jnp.asarray(lens))
-
-    def check_invariant(self):
-        return self._alloc.check_invariant()
-
-    def _active_mask(self):
-        """Rows that own pages are live; released rows must not advance
-        their device seq_lens (they'd corrupt the slot on reuse)."""
-        return np.array([bool(self._alloc.owned_pages(b))
-                         for b in range(len(self._alloc._owned))])
-
-    def append_and_attend(self, q, k_new, v_new, scale=None, active=None):
-        """One decode step for every sequence: write each row's new
-        token at its own position, return attention over its pages.
-
-        q/k_new/v_new: [batch, n_head, 1, head_dim].  `active` ([batch]
-        bool, default: rows owning pages) masks which rows' seq_lens
-        advance — inactive rows scatter into the garbage page and stay
-        put, so an evicted slot is bit-exactly fresh when reused.
-        """
-        if active is None:
-            active = self._active_mask()
-        active = jnp.asarray(np.asarray(active), jnp.bool_)
-        out, kp, vp, lens = apply(
-            lambda qv, kv, vv, kpg, vpg, tbl, ln, act: _paged_step(
-                qv, kv, vv, kpg, vpg, tbl, ln, act, self.page_size, scale),
-            q, k_new, v_new, self.k_pages, self.v_pages,
-            self.block_tables, self.seq_lens, active)
-        self.k_pages._set_value(kp._value)
-        self.v_pages._set_value(vp._value)
-        self.seq_lens._set_value(lens._value)
-        return out
-
-    def append_prefill(self, k_new, v_new, lens):
-        """Batched multi-sequence prompt write: scatter each row's first
-        ``lens[b]`` tokens into its pages (token t of row b lands in
-        page ``table[b, t // page]`` at offset ``t % page``).  Callers
-        must have ``ensure_capacity(b, lens[b])``-ed every row first.
-
-        k_new/v_new: [batch, n_head, S, head_dim]; lens: [batch] int.
-        Positions >= lens[b] (padding) are directed to the garbage page.
-
-        Rows NOT being prefilled must pass ``lens[b] == 0``: they
-        scatter nothing and their existing device seq_len is preserved
-        (lens are MERGED, not overwritten), so a partial-batch prefill
-        cannot reset or corrupt rows that are mid-decode.
-        """
-        lens = jnp.asarray(np.asarray(lens), jnp.int32)
-        kp, vp = apply(
-            lambda kv, vv, kpg, vpg, tbl, ln: paged_prefill_append(
-                kv, vv, kpg, vpg, tbl, ln, self.page_size),
-            k_new, v_new, self.k_pages, self.v_pages,
-            self.block_tables, lens)
-        self.k_pages._set_value(kp._value)
-        self.v_pages._set_value(vp._value)
-        merged = jnp.where(lens > 0, lens,
-                           unwrap(self.seq_lens).astype(jnp.int32))
-        self.seq_lens._set_value(merged)
-
-
 def row_pages_default(dtype, num_heads, head_dim, page_size):
     """Whether a plain pool of this geometry is stored as ROW pages and
     decoded by the Pallas kernel: on a TPU, outside any program GSPMD
@@ -311,8 +185,8 @@ def row_pages_default(dtype, num_heads, head_dim, page_size):
 
 def paged_attend(q, k_pages, v_pages, tables, lens, page_size, scale=None):
     """Shared attention core: [b, h, 1, d] queries over each row's
-    gathered pages, masked at `lens` — used by the stateful step, the
-    functional read-only decode, and serving's compiled decode step."""
+    gathered pages, masked at `lens` — the read-only form, and the read
+    of :func:`paged_decode_step` over a head-major pool."""
     b, h, one, d = q.shape
     sc = scale if scale is not None else 1.0 / float(d) ** 0.5
     k_seq = k_pages[tables]                               # [b, P, h, p, d]
@@ -334,9 +208,6 @@ def paged_attend(q, k_pages, v_pages, tables, lens, page_size, scale=None):
                   jnp.finfo(jnp.float32).min)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.matmul(p, v_seq, **pet).astype(q.dtype)    # [b, h, 1, d]
-
-
-_attend_pages = paged_attend  # back-compat alias (pre-serving name)
 
 
 def paged_decode_step(q, k_new, v_new, k_pages, v_pages, tables, lens,
@@ -381,14 +252,6 @@ def paged_decode_step(q, k_new, v_new, k_pages, v_pages, tables, lens,
     return out, k_pages, v_pages
 
 
-def _paged_step(q, k_new, v_new, k_pages, v_pages, tables, lens, active,
-                page_size, scale):
-    out, k_pages, v_pages = paged_decode_step(
-        q, k_new, v_new, k_pages, v_pages, tables, lens, page_size, scale)
-    new_lens = lens.astype(jnp.int32) + active.astype(jnp.int32)
-    return out, k_pages, v_pages, new_lens
-
-
 def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
                          page_size):
     """Batched multi-sequence prompt scatter (pure): token t of row b
@@ -425,16 +288,6 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     k_pages = k_pages.at[flat_pages, :, flat_offs].set(kt)
     v_pages = v_pages.at[flat_pages, :, flat_offs].set(vt)
     return k_pages, v_pages
-
-
-def paged_attention_decode(q, k_pages, v_pages, block_tables, seq_lens,
-                           page_size, scale=None):
-    """Functional read-only form: attention of [b, h, 1, d] queries over
-    already-written pages (positions < seq_lens)."""
-    return apply(
-        lambda qv, kpg, vpg, tbl, ln: paged_attend(
-            qv, kpg, vpg, tbl, ln, page_size, scale),
-        q, k_pages, v_pages, block_tables, seq_lens)
 
 
 # --------------------------------------------------------- latent pools

@@ -87,8 +87,9 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None,
     placement, never values — weights can be hot-swapped under a
     fingerprint because XLA compiled against their avals.  `attention`
     names what the decode attention was built from
-    (``LLMEngine.attention_path``: ``"xla"`` or the Pallas kernel with
-    its revision); with the sampler's revision it is the part of the
+    (``LLMEngine.attention_path``, which the engine's page pool states
+    — serving/kv_pool.py: ``"xla"`` or the Pallas kernel with its
+    revision); with the sampler's revision it is the part of the
     CODE the digest covers, so two trees that differ in either never
     share an executable.
     """

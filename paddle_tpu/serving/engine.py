@@ -35,6 +35,7 @@ unchanged on TPU.
 from __future__ import annotations
 
 import functools
+import inspect
 import time
 import weakref
 from collections import OrderedDict
@@ -49,25 +50,12 @@ from paddle_tpu.observability import (TraceContext, current_context,
                                       note_aot_compile, span)
 from paddle_tpu.core.dispatch import apply
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.incubate.nn.paged_attention import (PageAllocator,
-                                                    latent_decode_path,
-                                                    latent_decode_step,
-                                                    latent_pool_width,
-                                                    latent_prefill_append,
-                                                    paged_decode_step,
-                                                    paged_prefill_append,
-                                                    row_pages_default)
-from paddle_tpu.ops.pallas.mla_paged_attention import \
-    MLA_PAGED_DECODE_REVISION
-from paddle_tpu.ops.pallas.paged_attention import (PAGED_DECODE_REVISION,
-                                                   from_row_pages,
-                                                   to_row_pages)
-from paddle_tpu.quantization.kv_cache import (quantized_decode_step,
-                                              quantized_prefill_append,
-                                              resolve_kv_cache_dtype)
+from paddle_tpu.incubate.nn.paged_attention import PageAllocator
+from paddle_tpu.quantization.kv_cache import resolve_kv_cache_dtype
 from paddle_tpu.resilience.faultinject import fire as _fire
 from paddle_tpu.resilience.faultinject import note_recovery
 from paddle_tpu.resilience.health import HealthMonitor
+from paddle_tpu.serving.kv_pool import make_page_pool
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.request import (GenerationResult, Request,
                                         RequestState, SamplingParams)
@@ -194,30 +182,21 @@ class PagedKVContext:
     - mode "decode": one-token append + attention over the row's pages
       at its own length (ragged).
 
-    `quant` (a :class:`~paddle_tpu.quantization.kv_cache.KVQuantSpec`,
-    None for plain pools) switches the pool entries to per-page-scaled
-    ``(codes, scales)`` pairs and routes writes/reads through the
-    quantized step functions — decode dequantizes in-trace with f32
-    score/value accumulation.
-
-    A model that declares a LATENT cache (``kv_cache_spec()["kind"] ==
-    "latent"``: one row ``[c | k_r]`` a token) finds its pools in
-    ``k_pools`` alone (``v_pools`` is empty — keys and values are read
-    from the same row) and calls :meth:`latent_prefill` /
-    :meth:`latent_decode` in :meth:`attend`'s place; a model with
-    experts reports each expert layer's load through
+    What is written and how it is read back is `pool`'s (serving/
+    kv_pool.py: the engine's cache kind).  A model that declares a
+    LATENT cache calls :meth:`latent_prefill` / :meth:`latent_decode` in
+    :meth:`attend`'s place (its pools are ``k_pools`` alone); a model
+    with experts reports each expert layer's load through
     :meth:`note_expert_counts`.
     """
 
-    def __init__(self, k_pools, v_pools, tables, lens, page_size, mode,
-                 quant=None):
+    def __init__(self, pool, k_pools, v_pools, tables, lens, mode):
+        self.pool = pool
         self.k_pools = list(k_pools)
         self.v_pools = list(v_pools)
         self.tables = tables
         self.lens = lens
-        self.page_size = page_size
         self.mode = mode
-        self.quant = quant
         self._layer = 0
         self.expert_counts = []      # one traced [experts] int32 a layer
 
@@ -238,13 +217,9 @@ class PagedKVContext:
         li = self._next_layer()
 
         def fn(qv, kv, vv, rv):
-            out = _dense_causal_attention(
-                jnp.swapaxes(qv, 1, 2), jnp.swapaxes(kv, 1, 2),
-                jnp.swapaxes(vv, 1, 2))
-            self.k_pools[li] = latent_prefill_append(
-                rv, self.k_pools[li], self.tables, self.lens,
-                self.page_size)
-            return jnp.swapaxes(out, 1, 2)
+            out, self.k_pools[li] = self.pool.prefill(
+                qv, kv, vv, rv, self.k_pools[li], self.tables, self.lens)
+            return out
 
         return apply(fn, q, k, v, rows)
 
@@ -256,10 +231,10 @@ class PagedKVContext:
         li = self._next_layer()
 
         def fn(qv, rv):
-            u, self.k_pools[li] = latent_decode_step(
-                qv[:, 0], rv[:, 0], self.k_pools[li], self.tables,
-                self.lens, rank, self.page_size, scale)
-            return u[:, None]
+            out, self.k_pools[li] = self.pool.decode(
+                qv, rv, rank, scale, self.k_pools[li], self.tables,
+                self.lens)
+            return out
 
         return apply(fn, q, rows)
 
@@ -281,55 +256,16 @@ class PagedKVContext:
         """q/k/v: Tensor [b, s, n_head, head_dim] -> Tensor same shape
         (attention output); writes this layer's K/V into its pools."""
         li = self._next_layer()
+        step = (self.pool.prefill if self.mode == "prefill"
+                else self.pool.decode)
 
         def fn(qv, kv, vv):
-            qT = jnp.swapaxes(qv, 1, 2)            # [b, h, s, d]
-            kT = jnp.swapaxes(kv, 1, 2)
-            vT = jnp.swapaxes(vv, 1, 2)
-            if self.mode == "prefill":
-                out = _dense_causal_attention(qT, kT, vT)
-                if self.quant is not None:
-                    kp, vp = quantized_prefill_append(
-                        kT, vT, self.k_pools[li], self.v_pools[li],
-                        self.tables, self.lens, self.page_size,
-                        self.quant)
-                else:
-                    kp, vp = paged_prefill_append(
-                        kT, vT, self.k_pools[li], self.v_pools[li],
-                        self.tables, self.lens, self.page_size)
-            elif self.quant is not None:
-                out, kp, vp = quantized_decode_step(
-                    qT, kT, vT, self.k_pools[li], self.v_pools[li],
-                    self.tables, self.lens, self.page_size, self.quant)
-            else:
-                out, kp, vp = paged_decode_step(
-                    qT, kT, vT, self.k_pools[li], self.v_pools[li],
-                    self.tables, self.lens, self.page_size)
-            self.k_pools[li] = kp
-            self.v_pools[li] = vp
-            return jnp.swapaxes(out, 1, 2)         # [b, s, h, d]
+            out, self.k_pools[li], self.v_pools[li] = step(
+                qv, kv, vv, self.k_pools[li], self.v_pools[li],
+                self.tables, self.lens)
+            return out
 
         return apply(fn, q, k, v)
-
-
-def _dense_causal_attention(q, k, v):
-    """[b, h, s, d] causal attention (fp32 softmax, deterministic).
-
-    Narrow (bf16/fp16) inputs accumulate both contractions wide and
-    round once at the output (numlint NL101); the f32 path — today's
-    every serving config — is byte-identical to the pre-fix jaxpr.
-    """
-    d = q.shape[-1]
-    s = q.shape[2]
-    narrow = q.dtype in (jnp.bfloat16, jnp.float16)
-    pet = {"preferred_element_type": jnp.float32} if narrow else {}
-    scores = jnp.matmul(q / jnp.sqrt(jnp.float32(d)).astype(q.dtype),
-                        jnp.swapaxes(k, -1, -2), **pet)  # [b, h, s, s]
-    causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
-    scores = jnp.where(causal[None, None], scores.astype(jnp.float32),
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.matmul(probs, v, **pet).astype(q.dtype)
 
 
 class LLMEngine:
@@ -352,8 +288,14 @@ class LLMEngine:
       takes ``logits_positions=`` in prefill (the head on one position a
       row) and, if it has expert layers (``num_expert_layers``), reports
       their load through ``kv_ctx.note_expert_counts``.  The latent pool
-      is plain and on one device: ``kv_cache_dtype``, a multi-device
-      ``mesh`` and the page hand-off refuse it by name.
+      is plain and on one device: ``kv_cache_dtype`` and a multi-device
+      ``mesh`` refuse it by name.
+
+    The declaration and ``EngineConfig(kv_cache_dtype=, dtype=, mesh=)``
+    pick ONE pool object (serving/kv_pool.py) that owns the cache's
+    format: entry shapes, sharding, the traced write and read, the
+    hand-off blocks and the fingerprint's attention term.  The engine
+    keeps the arrays, the allocator, the scheduler and the programs.
 
     Public surface: :meth:`add_request`, :meth:`step`, :meth:`generate`,
     :attr:`metrics`, :meth:`shutdown`.
@@ -366,7 +308,6 @@ class LLMEngine:
         self._model = model
         model.eval()
         mc = model.config
-        self._num_layers = int(mc.num_layers)
         self._num_heads = int(mc.num_heads)
         self._head_dim = int(mc.hidden_size) // int(mc.num_heads)
         if cfg.max_model_len > int(getattr(mc, "max_seq_len",
@@ -386,71 +327,17 @@ class LLMEngine:
                             for k, v in self._params.items()}
 
         B, P = cfg.max_num_seqs, cfg.max_pages_per_seq
-        # what a layer caches is the MODEL's to declare; no declaration
-        # is GPT's K and V of heads x head_dim
-        spec = (model.kv_cache_spec() if hasattr(model, "kv_cache_spec")
-                else {"kind": "kv"})
-        self._kv_kind = spec["kind"]
-        if self._kv_kind not in ("kv", "latent"):
-            raise ValueError(f"unknown kv cache kind {self._kv_kind!r}")
-        self._latent = self._kv_kind == "latent"
+        # the cache kind, chosen once; the arrays are engine state
+        self._pool = make_page_pool(model, cfg, self._mesh)
+        self._k_pools, self._v_pools = self._pool.allocate(self._device)
         self._moe_layers = int(getattr(model, "num_expert_layers", 0))
         self._moe_experts = int(getattr(mc, "n_routed_experts", 0))
         self._moe_top_k = int(getattr(mc, "num_experts_per_tok", 0))
-        if self._latent:
-            self._num_layers = int(spec["num_layers"])
-            self._latent_row = int(spec["row_width"])
-            self._latent_rank = int(spec["value_width"])
-            if cfg.kv_cache_dtype is not None:
-                raise ValueError(
-                    f"kv_cache_dtype={cfg.kv_cache_dtype!r}: a 'latent' "
-                    f"pool is stored plain (no quantized latent pool)")
-            if self._mesh is not None:
-                raise ValueError(
-                    "mesh: a 'latent' pool has no head axis to shard "
-                    "and lives on one device")
-        # kv_cache_dtype narrows the pool STORAGE only: quantized pools
-        # are (codes, scales) pairs with one f32 scale per (page, head)
-        self._kv_quant = resolve_kv_cache_dtype(cfg.kv_cache_dtype)
-        # plain pools off-mesh on a TPU are ROW pages [pages, page,
-        # heads*head_dim] and decode through the Pallas kernel; every
-        # other engine keeps the head-major pool of the XLA composition
-        # (incubate/nn/paged_attention.py has both)
-        self._kv_rows = (not self._latent and self._kv_quant is None
-                         and self._mesh is None and row_pages_default(
-                             cfg.dtype, self._num_heads, self._head_dim,
-                             cfg.page_size))
-        if self._latent:
-            pool_shape = (cfg.num_pages, cfg.page_size,
-                          latent_pool_width(self._latent_row))
-        else:
-            pool_shape = (
-                (cfg.num_pages, cfg.page_size,
-                 self._num_heads * self._head_dim) if self._kv_rows else
-                (cfg.num_pages, self._num_heads, cfg.page_size,
-                 self._head_dim))
-
-        # allocated where they live (device= takes a sharding too): a
-        # pinned replica must not stage its pools through device 0
-        pool_dev = self._pool_sharding or self._device
-
-        def _pool():
-            if self._kv_quant is None:
-                return jnp.zeros(pool_shape, cfg.dtype, device=pool_dev)
-            return (jnp.zeros(pool_shape, self._kv_quant.code_dtype,
-                              device=pool_dev),
-                    jnp.zeros(pool_shape[:2], jnp.float32,
-                              device=pool_dev))
-
-        self._k_pools = [_pool() for _ in range(self._num_layers)]
-        # a latent row holds keys and values both: no second pool
-        self._v_pools = ([] if self._latent else
-                         [_pool() for _ in range(self._num_layers)])
         self._moe_stats = None       # the last program's expert_stats
-        # whether decode attention is a Pallas kernel (a span attribute)
-        self._decode_kernel = bool(
-            self._kv_rows or (self._latent
-                              and self._latent_decode_path() != "xla"))
+        # a model whose forward takes `logits_positions=` runs its head
+        # on the last real prompt token alone
+        self._head_on_last = "logits_positions" in inspect.signature(
+            model.forward).parameters
         self._tables = np.zeros((B, P), np.int32)      # host-canonical
         self._lens = np.zeros((B,), np.int32)          # host-canonical
         self._alloc = PageAllocator(cfg.num_pages, B, P)
@@ -536,10 +423,11 @@ class LLMEngine:
     def _init_mesh(self, mesh):
         """Resolve EngineConfig.mesh into (mesh, shardings).
 
-        tp groundwork (ROADMAP item 3): the paged KV pools shard along
-        the HEAD axis (pool axis 1) and every other operand is either
-        mesh-replicated or weight-sharded by :meth:`_param_sharding`;
-        all programs then lower as SPMD computations over the mesh.
+        tp groundwork (ROADMAP item 3): the paged KV pools shard as
+        their kind says (serving/kv_pool.py: along the HEAD axis) and
+        every other operand is either mesh-replicated or weight-sharded
+        by :meth:`_param_sharding`; all programs then lower as SPMD
+        computations over the mesh.
         A ``{"tp": n}`` dict builds a mesh over the first n devices
         (virtual CPU devices in tests, real chips on TPU).
 
@@ -568,7 +456,6 @@ class LLMEngine:
         if mesh is None:
             self._mesh = None
             self._repl_sharding = None
-            self._pool_sharding = None
             return
         tp = int(mesh.shape.get("tp", 1))
         if tp > 1 and self._num_heads % tp:
@@ -577,10 +464,6 @@ class LLMEngine:
                 f"extent {tp} to shard KV pools along the head axis")
         self._mesh = mesh
         self._repl_sharding = NamedSharding(mesh, PartitionSpec())
-        # pool layout [num_pages, heads, page_size, head_dim]: axis 1
-        # IS the head axis
-        self._pool_sharding = NamedSharding(
-            mesh, PartitionSpec(None, "tp"))
 
     def _param_sharding(self, arr):
         """Head-axis weight sharding heuristic: shard the LAST axis
@@ -619,19 +502,8 @@ class LLMEngine:
     def attention_path(self):
         """What the decode program's attention was built from — the
         AOT fingerprint's term for it: the Pallas kernel at its
-        revision, or the XLA composition."""
-        if self._latent:
-            path = self._latent_decode_path()
-            return "latent/" + (path if path == "xla" else
-                                f"{path}/{MLA_PAGED_DECODE_REVISION}")
-        if not self._kv_rows:
-            return "xla"
-        return f"paged_decode/{PAGED_DECODE_REVISION}"
-
-    def _latent_decode_path(self):
-        return latent_decode_path(
-            self.config.dtype, self._latent_rank,
-            latent_pool_width(self._latent_row), self.config.page_size)
+        revision, or the XLA composition (the pool kind's to say)."""
+        return self._pool.attention_path
 
     @property
     def program_fingerprint(self):
@@ -806,43 +678,23 @@ class LLMEngine:
         counterpart of token-only adoption, for when re-running prefill
         on the target is the cost being disaggregated away.
 
-        The payload carries, per layer, the request's owned pages
-        gathered from the (possibly quantized ``(codes, scales)``)
-        pools, plus prompt/generated tokens, sampling params, stream
+        The payload carries, per layer, the request's owned pages as
+        the pool kind encodes them (``self._pool.export``: named blocks
+        a layer), plus prompt/generated tokens, sampling params, stream
         watermark, deadline AGE (``metrics.clock`` is per-process — the
         absolute ``arrive_t`` never crosses a process boundary), and
         the pool geometry the importer validates against.  With
         `release` (default) the request leaves this engine entirely —
         slot, pages and live-table entry — so prefill workers stay
         empty-handed between handoffs."""
-        self._refuse_latent("export_page_state")
         req = self._requests.get(request_id)
         if req is None or req.slot is None:
             raise ValueError(
                 f"request {request_id!r} is not running here — only a "
                 f"RUNNING (slot-owning) request has pages to export")
         slot = req.slot
-        cfg = self.config
         L = int(self._lens[slot])
         pages = list(self._alloc.owned_pages(slot))
-        layers = []
-
-        def owned(pool):
-            # the handoff format is head-major [n, heads, page, d]
-            # whatever the local pool layout is
-            blk = np.asarray(pool)[pages]
-            return (from_row_pages(blk, self._num_heads)
-                    if self._kv_rows else blk)
-
-        for k_pool, v_pool in zip(self._k_pools, self._v_pools):
-            if self._kv_quant is None:
-                layers.append({"k": owned(k_pool), "v": owned(v_pool)})
-            else:
-                layers.append({
-                    "k_codes": np.asarray(k_pool[0])[pages],
-                    "k_scales": np.asarray(k_pool[1])[pages],
-                    "v_codes": np.asarray(v_pool[0])[pages],
-                    "v_scales": np.asarray(v_pool[1])[pages]})
         sp = req.sampling_params
         state = {
             "prompt_token_ids": list(req.prompt_token_ids),
@@ -858,15 +710,9 @@ class LLMEngine:
                 "eos_token_id": sp.eos_token_id,
                 "deadline_s": sp.deadline_s,
             },
-            "geometry": {
-                "page_size": cfg.page_size,
-                "num_layers": self._num_layers,
-                "num_heads": self._num_heads,
-                "head_dim": self._head_dim,
-                "kv_cache_dtype": cfg.kv_cache_dtype,
-                "dtype": str(np.dtype(cfg.dtype)),
-            },
-            "layers": layers,
+            "geometry": dict(self._pool.geometry),
+            "layers": self._pool.export((self._k_pools, self._v_pools),
+                                        pages),
         }
         if req.trace is not None:
             # trace identity rides the handoff blob so the decode
@@ -895,16 +741,8 @@ class LLMEngine:
         :class:`AdmissionRejected` when no slot/pages are free or this
         engine is DRAINING (the exporter still holds the state dict and
         can retry elsewhere)."""
-        self._refuse_latent("import_page_state")
-        cfg = self.config
         geo = state["geometry"]
-        mine = {"page_size": cfg.page_size,
-                "num_layers": self._num_layers,
-                "num_heads": self._num_heads,
-                "head_dim": self._head_dim,
-                "kv_cache_dtype": cfg.kv_cache_dtype,
-                "dtype": str(np.dtype(cfg.dtype))}
-        for k, want in mine.items():
+        for k, want in self._pool.geometry.items():
             if geo.get(k) != want:
                 raise ValueError(
                     f"page-state geometry mismatch on {k!r}: exporter "
@@ -926,8 +764,7 @@ class LLMEngine:
                 "draining",
                 f"engine {self._metrics_name} page-pool pressure "
                 f"{self.health.last_pressure:.2f}")
-        n_pages = len(state["layers"][0][
-            "k" if self._kv_quant is None else "k_codes"])
+        n_pages = len(next(iter(state["layers"][0].values())))
         try:
             slot = self._slots.index(None)
         except ValueError:
@@ -964,30 +801,9 @@ class LLMEngine:
                                                              n_pages)]
         for pos, page in enumerate(pages):
             self._tables[slot, pos] = page
-        idx = np.asarray(pages)
-
-        def local(blk):
-            # head-major handoff block -> this engine's pool layout
-            blk = np.asarray(blk)
-            return jnp.asarray(to_row_pages(blk) if self._kv_rows
-                               else blk)
-
-        for li in range(self._num_layers):
-            blk = state["layers"][li]
-            if self._kv_quant is None:
-                self._k_pools[li] = self._k_pools[li].at[idx].set(
-                    local(blk["k"]))
-                self._v_pools[li] = self._v_pools[li].at[idx].set(
-                    local(blk["v"]))
-            else:
-                kc, ks = self._k_pools[li]
-                vc, vs = self._v_pools[li]
-                self._k_pools[li] = (
-                    kc.at[idx].set(jnp.asarray(blk["k_codes"])),
-                    ks.at[idx].set(jnp.asarray(blk["k_scales"])))
-                self._v_pools[li] = (
-                    vc.at[idx].set(jnp.asarray(blk["v_codes"])),
-                    vs.at[idx].set(jnp.asarray(blk["v_scales"])))
+        self._k_pools, self._v_pools = self._pool.import_(
+            (self._k_pools, self._v_pools), np.asarray(pages),
+            state["layers"])
         self._lens[slot] = L
         req.transition(RequestState.PREFILL)
         req.transition(RequestState.DECODE)
@@ -997,13 +813,6 @@ class LLMEngine:
                   pages=n_pages, tokens=L):
             pass
         return rid
-
-    def _refuse_latent(self, what):
-        if self._latent:
-            raise NotImplementedError(
-                f"{what}: the page hand-off format is K and V blocks by "
-                f"head; a 'latent' pool has none (adopt_request replays "
-                f"the tokens instead)")
 
     def has_unfinished(self):
         return (self.scheduler.has_waiting()
@@ -1228,7 +1037,7 @@ class LLMEngine:
         self.metrics.pages_live = pages_live
         with span("serving.decode", live=self.num_running,
                   pages_live=pages_live,
-                  kernel=self._decode_kernel) as span_:
+                  kernel=self._pool.decode_kernel) as span_:
             self._decode_step_inner(events, span_)
 
     def _decode_step_inner(self, events, span_):
@@ -1544,21 +1353,13 @@ class LLMEngine:
 
     def _step_out_shardings(self):
         """out_shardings for the prefill/decode step programs in mesh
-        mode (None otherwise): logits replicated, pools keeping their
-        head-axis sharding — pinning the output layout to the input
-        layout is what keeps the pool arrays reusable call-over-call
-        without a resharding copy (or a surprise cache miss)."""
+        mode (None otherwise): logits replicated, pools as their kind
+        shards them — pinning the output layout to the input layout is
+        what keeps the pool arrays reusable call-over-call without a
+        resharding copy (or a surprise cache miss)."""
         if self._mesh is None:
             return None
-        # quantized pool entries are (codes, scales) pairs; the same
-        # P(None, "tp") spec shards codes on the head axis (axis 1 of
-        # [pages, heads, page, dim]) and scales on theirs (axis 1 of
-        # [pages, heads])
-        pool_sh = (self._pool_sharding if self._kv_quant is None
-                   else (self._pool_sharding, self._pool_sharding))
-        return (self._repl_sharding,
-                [pool_sh] * self._num_layers,
-                [pool_sh] * self._num_layers)
+        return (self._repl_sharding, *self._pool.out_shardings())
 
     def _prefill_program(self, bucket):
         """(fn, example_args, donate, out_shardings) for one prefill
@@ -1568,10 +1369,9 @@ class LLMEngine:
 
         def prefill(params, k_pools, v_pools, row_table, ids, pos_ids,
                     length):
-            ctx = PagedKVContext(k_pools, v_pools, row_table, length,
-                                 cfg.page_size, "prefill",
-                                 quant=self._kv_quant)
-            if self._latent:
+            ctx = PagedKVContext(self._pool, k_pools, v_pools, row_table,
+                                 length, "prefill")
+            if self._head_on_last:
                 # the model's head runs on the last REAL token alone
                 last = self._run_model(
                     params, ids, pos_ids, ctx,
@@ -1601,29 +1401,13 @@ class LLMEngine:
     def _guard_flags(self, logits, k_pools, v_pools, tables, lens):
         """Traced per-slot anomaly flags ``[B, 2]`` f32: column 0 is
         the logit finite-check (any non-finite value in the row's
-        logits), column 1 the quantized-KV scale-overflow check (a
-        non-finite — or above ``guard_scale_limit`` — page scale on
-        any page the row actually uses, any layer).  Gathers touch
-        only the tiny ``[N, h]`` scale planes, so the guard's decode
-        bytes are noise next to the attention reads."""
-        cfg = self.config
+        logits), column 1 the pool kind's scale-overflow check (a
+        quantized pool: a non-finite — or above ``guard_scale_limit`` —
+        page scale on any page the row actually uses, any layer)."""
         bad_logits = jnp.any(~jnp.isfinite(logits), axis=-1)     # [B]
-        if self._kv_quant is None:
-            bad_scale = jnp.zeros_like(bad_logits)
-        else:
-            P_ = tables.shape[1]
-            used = ((jnp.arange(P_, dtype=jnp.int32) * cfg.page_size)
-                    [None, :] < (lens + 1)[:, None])             # [B, P]
-            limit = cfg.guard_scale_limit
-            bad_scale = jnp.zeros(logits.shape[0], jnp.bool_)
-            for kq, vq in zip(k_pools, v_pools):
-                for _codes, scales in (kq, vq):
-                    s = scales[tables]                           # [B,P,h]
-                    bad = ~jnp.isfinite(s)
-                    if limit is not None:
-                        bad = bad | (s > limit)
-                    bad_scale = bad_scale | jnp.any(
-                        bad & used[:, :, None], axis=(1, 2))
+        bad_scale = self._pool.scale_overflow(
+            (k_pools, v_pools), tables, lens,
+            self.config.guard_scale_limit)
         return jnp.stack([bad_logits, bad_scale],
                          axis=-1).astype(jnp.float32)
 
@@ -1638,9 +1422,8 @@ class LLMEngine:
             # output.  Still ONE decode program for the engine's life.
             def decode(params, k_pools, v_pools, tables, lens, tokens,
                        poison):
-                ctx = PagedKVContext(k_pools, v_pools, tables, lens,
-                                     cfg.page_size, "decode",
-                                     quant=self._kv_quant)
+                ctx = PagedKVContext(self._pool, k_pools, v_pools,
+                                     tables, lens, "decode")
                 logits = self._run_model(params, tokens, lens[:, None],
                                          ctx)
                 logits = logits[:, 0].astype(jnp.float32) + poison
@@ -1659,9 +1442,8 @@ class LLMEngine:
                 self._guarded_out_shardings()
 
         def decode(params, k_pools, v_pools, tables, lens, tokens):
-            ctx = PagedKVContext(k_pools, v_pools, tables, lens,
-                                 cfg.page_size, "decode",
-                                 quant=self._kv_quant)
+            ctx = PagedKVContext(self._pool, k_pools, v_pools, tables,
+                                 lens, "decode")
             logits = self._run_model(params, tokens, lens[:, None], ctx)
             return (logits[:, 0].astype(jnp.float32),
                     ctx.k_pools, ctx.v_pools) + self._expert_stats(ctx)
@@ -1755,10 +1537,7 @@ class LLMEngine:
         page budget, in bytes).  Quantized pools count codes AND their
         per-page scales — the honest narrow-storage number the
         hbm_budget/perfgate gates see."""
-        return sum(int(leaf.nbytes) for leaf in
-                   jax.tree_util.tree_leaves(self._k_pools)) + \
-            sum(int(leaf.nbytes) for leaf in
-                jax.tree_util.tree_leaves(self._v_pools))
+        return self._pool.nbytes
 
     @property
     def kv_bytes_per_token(self):

@@ -221,7 +221,7 @@ def test_engine_prefill_then_decode_matches_reference(model, weights):
     results = engine.generate(prompts, sps)
     assert engine.metrics.requests_evicted >= 1          # a replay ran
     assert engine.metrics.moe_tokens_routed > 0
-    assert engine._v_pools == [] and engine._kv_kind == "latent"
+    assert engine._v_pools == [] and engine._pool.kind == "latent"
     checked = 0
     for k, (prompt, res) in enumerate(zip(prompts, results)):
         seq = prompt + list(res.output_token_ids)
@@ -258,23 +258,45 @@ def test_engine_spans_carry_expert_counters(model):
     engine.shutdown()
 
 
-@pytest.mark.parametrize("what", ["kv_cache_dtype", "mesh", "export",
-                                  "import"])
+@pytest.mark.parametrize("what", [{"kv_cache_dtype": "int8"},
+                                  {"mesh": {"tp": 2}}],
+                         ids=["kv_cache_dtype", "mesh"])
 def test_latent_pool_refuses_by_name(model, what):
-    if what == "kv_cache_dtype":
-        with pytest.raises(ValueError, match="latent"):
-            _engine(model, kv_cache_dtype="int8")
-    elif what == "mesh":
-        with pytest.raises(ValueError, match="latent"):
-            _engine(model, mesh={"tp": 2})
-    else:
-        engine = _engine(model)
-        with pytest.raises(NotImplementedError, match="latent"):
-            if what == "export":
-                engine.export_page_state("req-0")
-            else:
-                engine.import_page_state({})
-        engine.shutdown()
+    with pytest.raises(ValueError, match="latent"):
+        _engine(model, **what)
+
+
+def test_latent_pages_hand_off_mid_request(model):
+    """A request exported after five steps — its latent rows through the
+    fleet's wire format — and imported into a second engine, on other
+    page ids beside a request already running there, finishes with the
+    tokens of the uninterrupted run."""
+    from paddle_tpu.serving.fleet import wire
+    prompt = list(range(3, 20))
+    sp = serving.SamplingParams(max_new_tokens=12, temperature=0.7,
+                                top_p=0.9, seed=5)
+    whole = _engine(model)
+    want = whole.generate([prompt], sp)[0].output_token_ids
+    whole.shutdown()
+    first, second = _engine(model), _engine(model)
+    rid = first.add_request(prompt, sp)
+    for _ in range(5):
+        first.step()
+    assert 1 < len(first._requests[rid].output_token_ids) < 12
+    state = wire.unpack_state(wire.pack_state(first.export_page_state(rid)))
+    assert not first.has_unfinished()
+    assert [sorted(blocks) for blocks in state["layers"]] \
+        == [["rows"]] * TINY["num_hidden_layers"]
+    second.add_request([7, 8, 9], serving.SamplingParams(
+        max_new_tokens=30, temperature=0.0))
+    second.step()
+    moved = second.import_page_state(state)
+    assert second._alloc.owned_pages(second._requests[moved].slot)[0] != 1
+    while second.has_unfinished():
+        second.step()
+    assert second.finished_requests[moved].output_token_ids == want
+    first.shutdown()
+    second.shutdown()
 
 
 def test_pool_accounting_follows_the_declaration(model):

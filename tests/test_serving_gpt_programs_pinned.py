@@ -55,6 +55,6 @@ def test_gpt_program_unchanged(digests, program):
 def test_gpt_fingerprint_and_pools_unchanged(engine):
     assert engine.program_fingerprint == FINGERPRINT
     assert engine.attention_path == "xla"
-    assert engine._kv_kind == "kv"
+    assert engine._pool.kind == "kv"
     assert len(engine._k_pools) == len(engine._v_pools) == 2
     assert "moe" not in engine.metrics.snapshot()
