@@ -12,8 +12,10 @@ import threading
 
 import jax
 
+from paddle_tpu.amp.auto_cast import amp_state, auto_cast
 from paddle_tpu.core.dispatch import apply
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.observability import metrics as _obs_metrics
 
 _rc_tls = threading.local()
 
@@ -59,6 +61,25 @@ def recompute(function, *args, **kwargs):
 
     meta = {"n_user": 1, "is_seq": False}
 
+    # a region re-runs the way it ran: the autocast state is thread-local
+    # and ``backward()`` is usually called after the ``auto_cast`` block
+    # has exited, so the state current at this call is snapshotted here
+    # and re-entered round every run of the region — the forward (where
+    # it is already current) and ckpt_bwd's re-run alike.  Reference:
+    # RecomputeFunction saves is_fw_autocast / amp_level / amp_dtype and
+    # both lists at forward and re-enters auto_cast in backward.
+    st = amp_state()
+    amp = dict(enable=st.enabled, level=st.level, dtype=st.dtype,
+               custom_white_list=set(st.custom_white),
+               custom_black_list=set(st.custom_black))
+    state_name = (f"{st.level}/{jax.numpy.dtype(st.dtype).name}"
+                  if st.enabled else "off")
+    _obs_metrics.registry().counter(
+        "recompute_regions_total",
+        help="recompute() calls (trace time), by the autocast state "
+             "the region runs and re-runs under",
+        labels={"autocast": state_name}).inc()
+
     # VJP-only rematerialization (NOT jax.checkpoint): the eager tape
     # pre-lowers every op's custom_vjp into raw fwd/bwd calls, so by the
     # time jax.checkpoint would linearize this region via JVP the flash
@@ -84,7 +105,8 @@ def recompute(function, *args, **kwargs):
                     call_args.append(nt)
                 else:
                     call_args.append(args[i])
-            out = function(*call_args, **kwargs)
+            with auto_cast(**amp):
+                out = function(*call_args, **kwargs)
             if isinstance(out, (tuple, list)):
                 meta["is_seq"] = True
                 outs = tuple(o._value if isinstance(o, Tensor) else o

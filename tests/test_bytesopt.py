@@ -337,35 +337,48 @@ class TestBf16ActivationPolicy:
         diffs = [abs(a - b) for a, b in zip(plain, remat)]
         assert max(diffs) <= 0.05, (plain, remat)
 
+    @staticmethod
+    def _enable_recompute_losses(mode, autocast=False):
+        P.seed(0)
+        m = nn.Sequential(nn.Linear(16, 32), nn.GELU(),
+                          nn.Linear(32, 8))
+        if mode is not None:
+            m[0].enable_recompute(mode)
+        opt = P.optimizer.AdamW(learning_rate=0.01,
+                                parameters=m.parameters())
+        xs = P.to_tensor(np.random.default_rng(0)
+                         .standard_normal((4, 16)).astype(np.float32))
+        losses = []
+        for _ in range(4):
+            opt.clear_grad()
+            with P.amp.auto_cast(enable=autocast, level="O1"):
+                loss = (m(xs).astype("float32") ** 2).mean()
+            loss.backward()
+            opt.step()
+            losses.append(float(loss.numpy()))
+        return losses
+
     def test_per_layer_enable_recompute(self):
         """Per-Layer remat selection: a layer wrapped via
         enable_recompute(True) trains to the same losses as the plain
         layer (the recompute region is numerics-neutral in f32), and
         "auto" mode only engages under an ambient remat policy."""
-        def run(mode):
-            P.seed(0)
-            m = nn.Sequential(nn.Linear(16, 32), nn.GELU(),
-                              nn.Linear(32, 8))
-            if mode is not None:
-                m[0].enable_recompute(mode)
-            opt = P.optimizer.AdamW(learning_rate=0.01,
-                                    parameters=m.parameters())
-            xs = P.to_tensor(np.random.default_rng(0)
-                             .standard_normal((4, 16)).astype(np.float32))
-            losses = []
-            for _ in range(4):
-                opt.clear_grad()
-                loss = (m(xs) ** 2).mean()
-                loss.backward()
-                opt.step()
-                losses.append(float(loss.numpy()))
-            return losses
-
+        run = self._enable_recompute_losses
         plain = run(None)
         remat = run(True)
         np.testing.assert_allclose(plain, remat, rtol=1e-5, atol=1e-6)
         auto_off = run("auto")      # no ambient policy: behaves plain
         np.testing.assert_allclose(plain, auto_off, rtol=1e-5, atol=1e-6)
+
+    def test_per_layer_enable_recompute_under_autocast(self):
+        """The property users rely on: under auto_cast(O1) the region
+        re-runs in the forward's bf16 (backward() is called after the
+        block has exited), so the recomputed layer trains to the plain
+        layer's losses at bf16's level — and does learn."""
+        plain = self._enable_recompute_losses(None, autocast=True)
+        remat = self._enable_recompute_losses(True, autocast=True)
+        assert remat[-1] < remat[0]
+        np.testing.assert_allclose(plain, remat, rtol=2 ** -7)
 
     @pytest.mark.shardlint
     def test_optimized_program_has_zero_sl303(self):
