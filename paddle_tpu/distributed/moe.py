@@ -39,6 +39,7 @@ __all__ = [
     "BaseGate", "NaiveGate", "GShardGate", "SwitchGate",
     "MoELayer", "StackedExpertFFN", "dispatch_combine",
     "DroplessMoELayer", "dropless_experts", "sigmoid_topk_route",
+    "softmax_topk_route",
 ]
 
 
@@ -344,7 +345,20 @@ def sigmoid_topk_route(h, gate_w, gate_bias, top_k, scale,
     return top * scale, idx.astype(jnp.int32)
 
 
-def dropless_experts(h, weights, idx, w13, w2, first=0):
+def softmax_topk_route(h, gate_w, top_k):
+    """Granite's router (``granitemoehybrid``, as Mixtral's) on tokens ``h
+    [n, d]``: the logits in float32 whatever ``h`` is stored in, the top
+    ``top_k`` of the LOGITS, the weights a softmax over the chosen alone.
+    Returns (weights ``[n, k]`` f32, experts ``[n, k]`` int32)."""
+    import jax
+    import jax.numpy as jnp
+    g = jnp.matmul(h.astype(jnp.float32), gate_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(g, top_k)
+    return jax.nn.softmax(top, axis=-1), idx.astype(jnp.int32)
+
+
+def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
     """Routed experts without a capacity: ``sum_i weights[:, i] *
     E_idx[:, i](h)`` over the experts HELD, ``E(h) = (silu(h W1) * (h
     W3)) W2``.
@@ -356,7 +370,11 @@ def dropless_experts(h, weights, idx, w13, w2, first=0):
     the result is another holder's).  The ``n * k`` pairs are sorted by
     expert, so each expert's rows are contiguous and the two grouped
     products do ``n * k`` rows of work; rows come back to their tokens by
-    the inverse permutation (a gather).  Returns (out ``[n, d]`` in ``h``'s dtype,
+    the inverse permutation (a gather).  ``share``: the experts held are
+    a share of the router's, so some pairs' rows lie past the last group,
+    where the grouped product writes NOTHING (on a TPU they hold whatever
+    the memory held, NaN included, and a weight of 0 would not clear
+    that): those rows are set to 0.  Returns (out ``[n, d]`` in ``h``'s dtype,
     tokens per held expert ``[E_held]`` int32)."""
     import jax
     import jax.numpy as jnp
@@ -376,6 +394,8 @@ def dropless_experts(h, weights, idx, w13, w2, first=0):
                            preferred_element_type=jnp.float32)
     # rows past the held experts' are not this holder's: weight 0
     wts = jnp.where(mine, weights.reshape(-1), 0.0)[order]
+    if share:
+        y = jnp.where(mine[order][:, None], y, 0.0)
     y = y * wts[:, None]
     back = jnp.zeros((n * k,), jnp.int32).at[order].set(
         jnp.arange(n * k, dtype=jnp.int32))
@@ -383,9 +403,12 @@ def dropless_experts(h, weights, idx, w13, w2, first=0):
 
 
 class DroplessMoELayer(nn.Layer):
-    """Sigmoid-routed experts with shared experts, nothing dropped
-    (DeepSeek-V3 / Kanana-2; equations in docs/serving.md "Latent pool
-    and dropless experts").
+    """Routed experts with shared experts, nothing dropped.  ``route``
+    is the model's declaration of its router: ``"sigmoid"`` (DeepSeek-V3 /
+    Kanana-2, :func:`sigmoid_topk_route`, with the selection bias
+    ``gate_bias``; equations in docs/serving.md "Latent pool and dropless
+    experts") or ``"softmax"`` (Granite, :func:`softmax_topk_route`: no
+    bias, no scaling).
 
     The layer is told which contiguous range of experts it holds
     (``held = (first, count)``, default all): it routes over all
@@ -401,11 +424,16 @@ class DroplessMoELayer(nn.Layer):
 
     def __init__(self, d_model, d_expert, num_experts, top_k, n_shared=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True, held=None,
-                 shared=True, initializer_range=0.02, make_parameter=None):
+                 shared=True, initializer_range=0.02, make_parameter=None,
+                 route="sigmoid"):
         super().__init__()
+        if route not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router {route!r}")
         self.d_model, self.num_experts, self.top_k = d_model, num_experts, top_k
         self.scale, self.norm_topk_prob = routed_scaling_factor, norm_topk_prob
+        self.route = route
         self.first, count = held if held is not None else (0, num_experts)
+        self.share = count != num_experts
         self.last_counts = None
         make = make_parameter or (
             lambda shape, std: self.create_parameter(
@@ -415,7 +443,8 @@ class DroplessMoELayer(nn.Layer):
         self.gate_weight = make([d_model, num_experts], std)
         # e_score_correction_bias: fitted while training, loaded with the
         # weights; it moves the choice and never enters a weight
-        self.gate_bias = make([num_experts], 0.0)
+        if route == "sigmoid":
+            self.gate_bias = make([num_experts], 0.0)
         self.w13 = make([count, d_model, 2 * d_expert], std)
         self.w2 = make([count, d_expert, d_model], std)
         self.has_shared = bool(shared and n_shared)
@@ -427,12 +456,24 @@ class DroplessMoELayer(nn.Layer):
         import jax
         import jax.numpy as jnp
 
-        def fn(v, gw, gb, w13, w2, *shared):
+        if self.route == "sigmoid":
+            router = (self.gate_weight, self.gate_bias)
+
+            def route(h, gw, gb):
+                return sigmoid_topk_route(h, gw, gb, self.top_k, self.scale,
+                                          self.norm_topk_prob)
+        else:
+            router = (self.gate_weight,)
+
+            def route(h, gw):
+                return softmax_topk_route(h, gw, self.top_k)
+
+        def fn(v, *leaves):
             h = v.reshape(-1, v.shape[-1])
-            weights, idx = sigmoid_topk_route(
-                h, gw, gb, self.top_k, self.scale, self.norm_topk_prob)
+            weights, idx = route(h, *leaves[:len(router)])
+            w13, w2, *shared = leaves[len(router):]
             out, counts = dropless_experts(h, weights, idx, w13, w2,
-                                           self.first)
+                                           self.first, self.share)
             if shared:
                 a = jnp.matmul(h, shared[0],
                                preferred_element_type=jnp.float32)
@@ -444,7 +485,6 @@ class DroplessMoELayer(nn.Layer):
 
         shared = ((self.shared_w13, self.shared_w2) if self.has_shared
                   else ())
-        out, counts = apply(fn, x, self.gate_weight, self.gate_bias,
-                            self.w13, self.w2, *shared)
+        out, counts = apply(fn, x, *router, self.w13, self.w2, *shared)
         self.last_counts = counts
         return out

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "PageAllocator",
+    "grouped_causal_attention",
     "latent_attend",
     "latent_decode_path",
     "latent_decode_step",
@@ -288,6 +289,23 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     k_pages = k_pages.at[flat_pages, :, flat_offs].set(kt)
     v_pages = v_pages.at[flat_pages, :, flat_offs].set(vt)
     return k_pages, v_pages
+
+
+def grouped_causal_attention(q, k, v, scale):
+    """``q [b, s, H, d]`` over ``k`` / ``v [b, s, H_kv, d]``, query head
+    ``i`` reading K/V head ``i // (H / H_kv)``; ``scale`` multiplies the
+    scores; causal, float32 softmax, both contractions accumulated wide."""
+    b, s, H, d = q.shape
+    g = H // k.shape[2]
+    qg = q.reshape(b, s, k.shape[2], g, d)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), jnp.bool_)), scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, H, d).astype(q.dtype)
 
 
 # --------------------------------------------------------- latent pools

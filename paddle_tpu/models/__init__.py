@@ -42,3 +42,7 @@ from paddle_tpu.models.vit import (  # noqa: F401
     vit_tiny,
 )
 from paddle_tpu.models.deepfm import DeepFM, DeepFMCriterion, SparseEmbeddingBag  # noqa: F401
+from paddle_tpu.models.granitemoehybrid import (  # noqa: F401
+    GraniteMoeHybridConfig,
+    GraniteMoeHybridForCausalLM,
+)

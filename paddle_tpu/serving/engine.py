@@ -185,18 +185,22 @@ class PagedKVContext:
     What is written and how it is read back is `pool`'s (serving/
     kv_pool.py: the engine's cache kind).  A model that declares a
     LATENT cache calls :meth:`latent_prefill` / :meth:`latent_decode` in
-    :meth:`attend`'s place (its pools are ``k_pools`` alone); a model
-    with experts reports each expert layer's load through
+    :meth:`attend`'s place (its pools are ``k_pools`` alone); a layer
+    that declares a per-slot STATE calls :meth:`recur` (`slot`: the
+    admitted slot, a prefill program's extra operand for such a pool);
+    a model with experts reports each expert layer's load through
     :meth:`note_expert_counts`.
     """
 
-    def __init__(self, pool, k_pools, v_pools, tables, lens, mode):
+    def __init__(self, pool, k_pools, v_pools, tables, lens, mode,
+                 slot=None):
         self.pool = pool
         self.k_pools = list(k_pools)
         self.v_pools = list(v_pools)
         self.tables = tables
         self.lens = lens
         self.mode = mode
+        self.slot = slot
         self._layer = 0
         self.expert_counts = []      # one traced [experts] int32 a layer
 
@@ -237,6 +241,22 @@ class PagedKVContext:
             return out
 
         return apply(fn, q, rows)
+
+    def recur(self, fn, *inputs):
+        """A state layer's one hook: ``fn(conv, ssm, lens, *values) ->
+        (out, conv', ssm')`` runs on this layer's per-slot state (every
+        slot's in decode; a fresh zero state in prefill) and what it
+        hands back is stored (in place; at the admitted slot).  Returns
+        ``out`` as a Tensor."""
+        li = self._next_layer()
+
+        def run(*values):
+            out, self.k_pools[li], self.v_pools[li] = self.pool.recur(
+                fn, self.k_pools[li], self.v_pools[li], self.lens,
+                self.slot, values)
+            return out
+
+        return apply(run, *inputs)
 
     def note_expert_counts(self, counts):
         """An expert layer's tokens per expert in this forward (traced
@@ -290,6 +310,13 @@ class LLMEngine:
       their load through ``kv_ctx.note_expert_counts``.  The latent pool
       is plain and on one device: ``kv_cache_dtype`` and a multi-device
       ``mesh`` refuse it by name.
+      ``{"kind": "layers", "layers": [...]}``
+      (``models/granitemoehybrid.py``): a declaration PER LAYER — ``kv``
+      with its own ``num_heads`` / ``head_dim`` (and ``query_heads``,
+      ``scale`` for grouped queries), read through ``kv_ctx.attend``, or
+      ``state`` (``conv``, ``ssm`` shapes), a per-SLOT recurrent state
+      reached through ``kv_ctx.recur``; a prefill overwrites its slot's
+      state, decode advances it in place, the hand-off carries it.
 
     The declaration and ``EngineConfig(kv_cache_dtype=, dtype=, mesh=)``
     pick ONE pool object (serving/kv_pool.py) that owns the cache's
@@ -367,6 +394,7 @@ class LLMEngine:
                         else EngineMetrics(name=self._metrics_name))
         self.metrics.compile_bound = cfg.compile_bound
         self.metrics.pages_total = cfg.num_pages - 1   # page 0 reserved
+        self.metrics.state_pool_bytes = self._pool.state_nbytes
         # health state machine over live page-pool occupancy; the gauge
         # is EngineMetrics-owned so its registry lifecycle matches
         self.health = HealthMonitor(
@@ -712,7 +740,7 @@ class LLMEngine:
             },
             "geometry": dict(self._pool.geometry),
             "layers": self._pool.export((self._k_pools, self._v_pools),
-                                        pages),
+                                        pages, slot),
         }
         if req.trace is not None:
             # trace identity rides the handoff blob so the decode
@@ -764,7 +792,7 @@ class LLMEngine:
                 "draining",
                 f"engine {self._metrics_name} page-pool pressure "
                 f"{self.health.last_pressure:.2f}")
-        n_pages = len(next(iter(state["layers"][0].values())))
+        n_pages = self._pool.exported_pages(state["layers"])
         try:
             slot = self._slots.index(None)
         except ValueError:
@@ -803,7 +831,7 @@ class LLMEngine:
             self._tables[slot, pos] = page
         self._k_pools, self._v_pools = self._pool.import_(
             (self._k_pools, self._v_pools), np.asarray(pages),
-            state["layers"])
+            state["layers"], slot)
         self._lens[slot] = L
         req.transition(RequestState.PREFILL)
         req.transition(RequestState.DECODE)
@@ -961,7 +989,7 @@ class LLMEngine:
         bucket = self.scheduler.bucket_for_len(L)
         with span("serving.prefill", ctx=req.trace,
                   request=req.request_id, bucket=bucket,
-                  tokens=L) as span_:
+                  tokens=L, **self._pool.prefill_attrs(L, bucket)) as span_:
             self._prefill_inner(req, events, cfg, t0, tokens, L, bucket,
                                 span_)
 
@@ -1003,8 +1031,12 @@ class LLMEngine:
         last_logits, self._k_pools, self._v_pools, *stats = fn(
             self._params, self._k_pools, self._v_pools,
             self._place(self._tables[slot:slot + 1]), self._place(ids),
-            self._place(pos_ids), self._place(length))
+            self._place(pos_ids), self._place(length),
+            *(self._place(x) for x in self._pool.slot_operands(slot)))
         self._lens[slot] = L
+        # a slot's per-slot state, if the pool keeps one, is now this
+        # request's: whatever ran there before is overwritten
+        self.metrics.state_admits_total += bool(self._pool.state_layers)
 
         tok = self._sample(last_logits, [req], width=1, carry=stats)[0]
         self._note_experts(span_, bucket)
@@ -1037,7 +1069,8 @@ class LLMEngine:
         self.metrics.pages_live = pages_live
         with span("serving.decode", live=self.num_running,
                   pages_live=pages_live,
-                  kernel=self._pool.decode_kernel) as span_:
+                  kernel=self._pool.decode_kernel,
+                  **self._pool.decode_attrs(self.num_running)) as span_:
             self._decode_step_inner(events, span_)
 
     def _decode_step_inner(self, events, span_):
@@ -1368,9 +1401,9 @@ class LLMEngine:
         cfg = self.config
 
         def prefill(params, k_pools, v_pools, row_table, ids, pos_ids,
-                    length):
+                    length, *slot):
             ctx = PagedKVContext(self._pool, k_pools, v_pools, row_table,
-                                 length, "prefill")
+                                 length, "prefill", *slot)
             if self._head_on_last:
                 # the model's head runs on the last REAL token alone
                 last = self._run_model(
@@ -1390,8 +1423,9 @@ class LLMEngine:
             jnp.zeros((1, cfg.max_pages_per_seq), jnp.int32),
             jnp.zeros((1, bucket), jnp.int32),
             jnp.zeros((1, bucket), jnp.int32),
-            jnp.zeros((1,), jnp.int32)), (1, 2), \
-            self._step_out_shardings()
+            jnp.zeros((1,), jnp.int32),
+            *(jnp.asarray(x) for x in self._pool.slot_operands(0))), \
+            (1, 2), self._step_out_shardings()
 
     def _expert_stats(self, ctx):
         """The extra output of a program of a model with expert layers
@@ -1536,7 +1570,7 @@ class LLMEngine:
         """Total bytes of the paged K+V pools across all layers (the
         page budget, in bytes).  Quantized pools count codes AND their
         per-page scales — the honest narrow-storage number the
-        hbm_budget/perfgate gates see."""
+        hbm_budget/perfgate gates see; a per-slot state counts too."""
         return self._pool.nbytes
 
     @property

@@ -18,6 +18,15 @@ programs, and asks the pool object for everything about the format.
 - :class:`LatentPool` — ONE pool a layer of rows ``[c | k_r | 0]``;
   the Pallas kernel ``mla_paged_decode`` on a TPU, ``latent_attend``
   elsewhere; wire block ``rows``.  Refuses a mesh and a narrow dtype.
+- :class:`LayeredPool` — a kind PER LAYER: a :class:`GroupedKV` for the
+  layers that declare ``kv`` (row pages at their own K/V head count,
+  grouped queries reading through the XLA composition) and a
+  :class:`SlotState` for those that declare ``state`` — a recurrent
+  layer's state, indexed by SLOT and not by page (``[max_num_seqs,
+  ...]``: the convolution's window at the engine's dtype, the
+  state-space matrix in float32), overwritten by the prefill that
+  admits a request and advanced in place by decode; wire blocks
+  ``conv`` / ``ssm``.  Refuses a mesh and a narrow dtype.
 
 docs/serving.md "The page pool" has the table.  The step functions (the
 mathematics) stay in :mod:`paddle_tpu.incubate.nn.paged_attention` and
@@ -34,7 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from paddle_tpu.incubate.nn.paged_attention import (latent_decode_path,
+from paddle_tpu.incubate.nn.paged_attention import (grouped_causal_attention,
+                                                    latent_decode_path,
                                                     latent_decode_step,
                                                     latent_pool_width,
                                                     latent_prefill_append,
@@ -50,8 +60,8 @@ from paddle_tpu.quantization.kv_cache import (quantized_decode_step,
                                               quantized_prefill_append,
                                               resolve_kv_cache_dtype)
 
-__all__ = ["LatentPool", "PagePool", "PlainKV", "QuantizedKV",
-           "make_page_pool"]
+__all__ = ["GroupedKV", "LatentPool", "LayeredPool", "PagePool",
+           "PlainKV", "QuantizedKV", "SlotState", "make_page_pool"]
 
 
 def _dense_causal_attention(q, k, v):
@@ -91,6 +101,8 @@ class PagePool:
 
     attention_path = "xla"
     decode_kernel = False
+    state_layers = 0          # layers cached by slot (SlotState)
+    state_nbytes = 0
 
     def __init__(self, cfg, num_layers, mesh=None, spec=None):
         self.page_size = cfg.page_size
@@ -139,15 +151,35 @@ class PagePool:
         page a slot uses.  Only a quantized kind has scales."""
         return jnp.zeros(tables.shape[0], jnp.bool_)
 
+    # ------------------------------------------- what a slot adds
+    def slot_operands(self, slot):
+        """Operands a prefill program takes beyond the slot's page table
+        (host values; none for a kind that caches by page alone)."""
+        return ()
+
+    def prefill_attrs(self, tokens, bucket):
+        """Attributes this kind adds to a ``serving.prefill`` span."""
+        return {}
+
+    def decode_attrs(self, live):
+        """Attributes this kind adds to a ``serving.decode`` span of
+        `live` running slots."""
+        return {}
+
     # ------------------------------------------------------- hand-off
     def _to_wire(self, block):
         return block
 
     _from_wire = _to_wire
 
-    def export(self, pools, pages):
+    def exported_pages(self, layers):
+        """How many pages the exported `layers` hold."""
+        return len(next(iter(layers[0].values())))
+
+    def export(self, pools, pages, slot=None):
         """The blocks of `pages` (page ids), one ``{name: ndarray}`` a
-        layer — what ``serving.fleet.wire`` packs."""
+        layer — what ``serving.fleet.wire`` packs.  `slot` is for a kind
+        that caches by slot."""
         leaves = jax.tree_util.tree_leaves
         layers = [{} for _ in range(self.num_layers)]
         for half, names in zip(pools, self._wire):
@@ -156,7 +188,7 @@ class PagePool:
                     blocks[name] = self._to_wire(np.asarray(arr)[pages])
         return layers
 
-    def import_(self, pools, idx, layers):
+    def import_(self, pools, idx, layers, slot=None):
         """`pools` with the exported `layers` written at page ids `idx`
         (an eager scatter: no compiled program)."""
         filled = [
@@ -261,6 +293,56 @@ class QuantizedKV(PlainKV):
         return bad_scale
 
 
+class GroupedKV(PlainKV):
+    """K and V of ``num_heads x head_dim`` read by ``query_heads`` query
+    heads (head ``i`` reads K/V head ``i // groups``) at the model's own
+    score ``scale``: ROW pages on every platform (a head-major pool is
+    re-laid whole by every program that appends to it), read through the
+    XLA composition — ``paged_decode`` reads one K/V head a query head
+    at ``1/sqrt(d)``.  On one device."""
+
+    def __init__(self, cfg, num_layers, num_heads, head_dim, query_heads,
+                 scale):
+        super().__init__(cfg, num_layers, num_heads, head_dim, rows=True)
+        self.groups = query_heads // num_heads
+        self.scale = float(scale)
+        self.decode_kernel = False
+        self.attention_path = "xla/row_pages"
+
+    def prefill(self, q, k, v, kp, vp, tables, lens):
+        out = grouped_causal_attention(q, k, v, self.scale)
+        kp, vp = self._append(jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                              kp, vp, tables, lens, self.page_size)
+        return out, kp, vp
+
+    def decode(self, q, k, v, kp, vp, tables, lens):
+        """Each slot's new K/V row scattered at its length (in place),
+        the slot's table gathered as rows ``[b, positions, H_kv, d]``, a
+        K/V head's group of queries attending together; float32 softmax,
+        both contractions accumulated wide."""
+        b, _, H, d = q.shape
+        page, hk = self.page_size, self.num_heads
+        lens = lens.astype(jnp.int32)
+        page_ids = jnp.take_along_axis(tables, (lens // page)[:, None],
+                                       axis=1)[:, 0]
+        kp = kp.at[page_ids, lens % page].set(
+            k.reshape(b, hk * d).astype(kp.dtype))
+        vp = vp.at[page_ids, lens % page].set(
+            v.reshape(b, hk * d).astype(vp.dtype))
+        keys = kp[tables].reshape(b, -1, hk, d)
+        vals = vp[tables].reshape(b, -1, hk, d)
+        scores = jnp.einsum(
+            "bhgd,bkhd->bhgk", q.reshape(b, hk, self.groups, d), keys,
+            preferred_element_type=jnp.float32) * self.scale
+        live = jnp.arange(keys.shape[1])[None, :] < (lens + 1)[:, None]
+        scores = jnp.where(live[:, None, None, :], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bhgk,bkhd->bhgd", probs, vals,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, 1, H, d).astype(q.dtype), kp, vp
+
+
 class LatentPool(PagePool):
     """ONE pool a layer of row pages ``[pages, page, W]``: a token's row
     is ``[c | k_r]`` padded with zeros to whole lane tiles; keys and
@@ -312,9 +394,177 @@ class LatentPool(PagePool):
         return u[:, None], pages
 
 
+class SlotState:
+    """A recurrent layer's cache: indexed by SLOT, not by page.  An entry
+    is the pair (``conv [slots, *conv]`` at the engine's dtype — the
+    convolution's last inputs —, ``ssm [slots, *ssm]`` float32 — the
+    state-space matrix).  A prefill starts from nothing and overwrites
+    its slot's entry, so a finished request's state never reaches the
+    next; decode advances every slot's entry in place."""
+
+    wire = ("conv", "ssm")
+
+    def __init__(self, cfg, spec):
+        slots = cfg.max_num_seqs
+        self.struct = (
+            jax.ShapeDtypeStruct((slots, *spec["conv"]), cfg.dtype),
+            jax.ShapeDtypeStruct((slots, *spec["ssm"]), jnp.float32))
+        # lists: what a hand-off's JSON header brings back
+        self.geometry = {"conv": list(spec["conv"]),
+                         "ssm": list(spec["ssm"])}
+
+    def recur(self, fn, conv, ssm, lens, slot, values):
+        """``fn(conv, ssm, lens, *values) -> (out, conv', ssm')`` on the
+        rows the program runs: every slot's entry (decode, `slot` None),
+        or a fresh zero entry whose result lands at `slot` (prefill)."""
+        if slot is None:
+            return fn(conv, ssm, lens, *values)
+        out, conv_new, ssm_new = fn(
+            *(jnp.zeros((1, *s.shape[1:]), s.dtype) for s in self.struct),
+            lens, *values)
+        at = slot.astype(jnp.int32)[0]
+        return (out,
+                jax.lax.dynamic_update_slice_in_dim(
+                    conv, conv_new.astype(conv.dtype), at, axis=0),
+                jax.lax.dynamic_update_slice_in_dim(
+                    ssm, ssm_new.astype(ssm.dtype), at, axis=0))
+
+
+class LayeredPool(PagePool):
+    """A cache kind PER LAYER (``{"kind": "layers", "layers": [...]}``):
+    one :class:`GroupedKV` for the layers declaring ``kv`` (one geometry:
+    ``num_heads x head_dim``, optionally ``query_heads`` and ``scale``)
+    and one :class:`SlotState` for those declaring ``state``.  A layer's
+    entry is a pair in the engine's two lists either way: (K pages, V
+    pages) or (conv, ssm).  The pool is plain and on one device."""
+
+    kind = "layers"
+
+    def __init__(self, cfg, layers, mesh=None):
+        self.kinds = [layer["kind"] for layer in layers]
+        unknown = set(self.kinds) - {"kv", "state"}
+        if unknown:
+            raise ValueError(f"unknown kv cache kind {sorted(unknown)[0]!r} "
+                             f"in a per-layer declaration")
+        kv = [layer for layer in layers if layer["kind"] == "kv"]
+        state = [layer for layer in layers if layer["kind"] == "state"]
+        if any(layer != group[0] for group in (kv, state)
+               for layer in group):
+            raise ValueError("the layers of one cache kind must share "
+                             "one geometry")
+        if cfg.kv_cache_dtype is not None:
+            raise ValueError(
+                f"kv_cache_dtype={cfg.kv_cache_dtype!r}: a per-layer pool "
+                f"is stored plain (a 'state' layer keeps its state in "
+                f"float32, a 'kv' layer its K and V at the engine's dtype)")
+        if mesh is not None:
+            raise ValueError(
+                "mesh: a per-layer pool is not sharded (a 'state' layer's "
+                "per-slot state lives on one device)")
+        super().__init__(cfg, len(layers))
+        self.kv = self.state = None
+        if kv:
+            heads, dim = int(kv[0]["num_heads"]), int(kv[0]["head_dim"])
+            self.kv = GroupedKV(cfg, len(kv), heads, dim,
+                                int(kv[0].get("query_heads", heads)),
+                                kv[0].get("scale", dim ** -0.5))
+            self.geometry.update(self.kv.geometry, num_layers=len(layers))
+        if state:
+            self.state = SlotState(cfg, state[0])
+            self.state_layers = len(state)
+            self.geometry.update(self.state.geometry)
+        self.geometry["kinds"] = list(self.kinds)
+        self.attention_path = "+".join(
+            [f"kv:{self.kv.attention_path}"] * bool(kv)
+            + ["state:xla/float32"] * bool(state))
+
+    def _struct_of(self, li):
+        return ((self.kv._struct,) * 2 if self.kinds[li] == "kv"
+                else self.state.struct)
+
+    def _per_layer(self, make):
+        """``(firsts, seconds)``: ``make(struct leaf)`` over each layer's
+        pair of entries."""
+        halves = ([], [])
+        for li in range(self.num_layers):
+            for half, struct in zip(halves, self._struct_of(li)):
+                half.append(jax.tree_util.tree_map(make, struct))
+        return halves
+
+    def allocate(self, device=None):
+        return self._per_layer(
+            lambda s: jnp.zeros(s.shape, s.dtype, device=device))
+
+    @property
+    def state_nbytes(self):
+        if self.state is None:
+            return 0
+        return self.state_layers * sum(
+            math.prod(s.shape) * np.dtype(s.dtype).itemsize
+            for s in self.state.struct)
+
+    @property
+    def nbytes(self):
+        return (self.kv.nbytes if self.kv else 0) + self.state_nbytes
+
+    # a page layer's read and write are its PlainKV's
+    def prefill(self, *args):
+        return self.kv.prefill(*args)
+
+    def decode(self, *args):
+        return self.kv.decode(*args)
+
+    def recur(self, fn, conv, ssm, lens, slot, values):
+        return self.state.recur(fn, conv, ssm, lens, slot, values)
+
+    def slot_operands(self, slot):
+        return (np.array([slot], np.int32),) if self.state else ()
+
+    def prefill_attrs(self, tokens, bucket):
+        if self.state is None:
+            return {}
+        return {"scan_tokens": tokens}
+
+    def decode_attrs(self, live):
+        return ({"state_rows": live * self.state_layers} if self.state
+                else {})
+
+    # ------------------------------------------------------- hand-off
+    def exported_pages(self, layers):
+        if self.kv is None:
+            return 0
+        return self.kv.exported_pages([layers[self.kinds.index("kv")]])
+
+    def export(self, pools, pages, slot=None):
+        layers = []
+        for li, kind in enumerate(self.kinds):
+            first, second = pools[0][li], pools[1][li]
+            if kind == "state":
+                layers.append({"conv": np.asarray(first[slot]),
+                               "ssm": np.asarray(second[slot])})
+            else:
+                layers.append(self.kv.export(([first], [second]), pages)[0])
+        return layers
+
+    def import_(self, pools, idx, layers, slot=None):
+        halves = ([], [])
+        for li, kind in enumerate(self.kinds):
+            first, second = pools[0][li], pools[1][li]
+            if kind == "state":
+                first = first.at[slot].set(jnp.asarray(layers[li]["conv"]))
+                second = second.at[slot].set(jnp.asarray(layers[li]["ssm"]))
+            else:
+                (first,), (second,) = self.kv.import_(
+                    ([first], [second]), idx, [layers[li]])
+            halves[0].append(first)
+            halves[1].append(second)
+        return halves
+
+
 def make_page_pool(model, cfg, mesh=None):
     """The pool of the kind `model` declares (``kv_cache_spec()``; no
-    declaration is GPT's K and V of ``num_heads x head_dim``), at
+    declaration is GPT's K and V of ``num_heads x head_dim``; ``"layers"``
+    is a declaration per layer), at
     `cfg`'s page geometry, dtype and ``kv_cache_dtype``, for the
     RESOLVED `mesh` (None off-mesh).  A kind that cannot shard or cannot
     narrow raises ``ValueError`` by name."""
@@ -322,6 +572,8 @@ def make_page_pool(model, cfg, mesh=None):
             else {"kind": "kv"})
     if spec["kind"] == "latent":
         return LatentPool(cfg, spec, mesh)
+    if spec["kind"] == "layers":
+        return LayeredPool(cfg, spec["layers"], mesh)
     if spec["kind"] != "kv":
         raise ValueError(f"unknown kv cache kind {spec['kind']!r}")
     mc = model.config

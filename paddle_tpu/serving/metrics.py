@@ -157,6 +157,9 @@ class EngineMetrics:
         # the first note_experts, so an engine without experts registers
         # nothing and snapshots as before
         self.moe_tokens_routed = 0   # token-expert pairs, all layers
+        # per-slot recurrent state (pools with state layers only)
+        self.state_pool_bytes = 0
+        self.state_admits_total = 0  # slots whose state a prefill overwrote
         self.moe_imbalance = None    # histogram of heaviest / mean load
 
     def note_experts(self, pairs, tokens_max, mean_load):
@@ -215,8 +218,12 @@ class EngineMetrics:
         moe = ({} if self.moe_imbalance is None else {"moe": {
             "tokens_routed": self.moe_tokens_routed,
             "expert_imbalance": self.moe_imbalance.summary()}})
+        state = ({} if not self.state_pool_bytes else {"state": {
+            "pool_bytes": self.state_pool_bytes,
+            "admits_total": self.state_admits_total}})
         return {
             **moe,
+            **state,
             "uptime_s": round(elapsed, 3),
             "requests": {
                 "received": self.requests_received,
