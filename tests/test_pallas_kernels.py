@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.nn.functional.transformer import _sdpa_ref
+from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
 from paddle_tpu.ops.pallas.norm import fused_layer_norm, fused_rms_norm
 
@@ -54,6 +55,217 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             rtol=0.05, atol=0.05)
+
+
+def _bhsd(b, h, sq, sk, d, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda s: jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
+    return mk(sq), mk(sk), mk(sk)
+
+
+def _ref_bhsd(q, k, v, causal):
+    """Plain attention on [b, h, s, d] in f32: (o, lse)."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * q.shape[-1] ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
+                   v.astype(jnp.float32))
+    return o, jax.nn.logsumexp(s, axis=-1)
+
+
+def _flash_and_grads(q, k, v, causal, blocks=(None, None)):
+    """(o, lse) and the gradients of a loss that reads both."""
+    def run(fn):
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o.astype(jnp.float32) ** 2).sum() + lse.sum()
+        return fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: fa._flash_block(
+        q, k, v, causal, q.shape[-1] ** -0.5, *blocks, True))
+    want = run(lambda q, k, v: _ref_bhsd(q, k, v, causal))
+    return got, want
+
+
+def _assert_close(got, want, tol):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * (np.abs(b).max() + 1e-6)
+
+
+# (rows of batch*heads as (b, h)), seq_q, seq_k, head size, causal, explicit
+# blocks: every schedule `_pick_blocks` can return — the resident walk (the
+# GPT cell's shape, 4096, dense, a length that ends inside a tile), the lone
+# tile (the BERT cell's shape, 1024 causal, ragged), seq_q != seq_k with and
+# without named blocks (as ring attention calls), the query-major forward
+# at a head size of 128, streamed tiles under a resident walk's size
+SCHEDULE_CASES = {
+    "gpt_s2048_causal": ((1, 1), 2048, 2048, 64, True, (None, None)),
+    "walk_s2048_dense": ((1, 1), 2048, 2048, 64, False, (None, None)),
+    "walk_s4096_causal": ((1, 1), 4096, 4096, 64, True, (None, None)),
+    "walk_s4096_dense": ((1, 1), 4096, 4096, 64, False, (None, None)),
+    "walk_ragged_1300_causal": ((1, 1), 1300, 1300, 64, True, (None, None)),
+    "walk_sq512_sk2048": ((1, 1), 512, 2048, 64, False, (None, None)),
+    "walk_sq2048_sk1100_causal": ((1, 1), 2048, 1100, 64, True,
+                                  (None, None)),
+    "bert_s512_dense": ((1, 2), 512, 512, 64, False, (None, None)),
+    "lone_s1024_causal": ((1, 1), 1024, 1024, 64, True, (None, None)),
+    "ragged_300_causal": ((1, 2), 300, 300, 64, True, (None, None)),
+    "ragged_1000_dense": ((1, 1), 1000, 1000, 64, False, (None, None)),
+    "ragged_1000_causal": ((1, 1), 1000, 1000, 64, True, (None, None)),
+    "lone_sq256_sk700": ((1, 2), 256, 700, 64, False, (None, None)),
+    "ring_sq256_sk512": ((1, 2), 256, 512, 64, False, (128, 128)),
+    "ring_sq512_sk256_causal": ((1, 2), 512, 256, 64, True, (128, 256)),
+    "named_ragged_600_causal": ((1, 1), 600, 600, 64, True, (256, 128)),
+    "d128_s2048_causal": ((1, 1), 2048, 2048, 128, True, (None, None)),
+    "d128_ragged_700_dense": ((1, 1), 700, 700, 128, False, (None, None)),
+    "d32_s1536_causal": ((1, 1), 1536, 1536, 32, True, (None, None)),
+}
+
+
+@pytest.fixture
+def force(monkeypatch):
+    """force(name, value): replace a module-level piece of the schedule for
+    one test.  The kernels' functions are traced once a shape (`jax.jit`),
+    so what they traced before is dropped at each replacement, and again
+    when the test ends."""
+    def clear():
+        fa._flash_fwd.clear_cache()
+        fa._flash_bwd_impl.clear_cache()
+
+    def force(name, value):
+        monkeypatch.setattr(fa, name, value)
+        clear()
+
+    yield force
+    monkeypatch.undo()
+    clear()
+
+
+class TestFlashSchedule:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", sorted(SCHEDULE_CASES))
+    def test_forward_and_gradients_match(self, case, dtype):
+        (b, h), sq, sk, d, causal, blocks = SCHEDULE_CASES[case]
+        q, k, v = _bhsd(b, h, sq, sk, d, dtype)
+        got, want = _flash_and_grads(q, k, v, causal, blocks)
+        _assert_close(got, want, 3e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+    @pytest.mark.parametrize("causal,sq,sk,sched", [
+        (True, 512, 512, (128, 128, 4, True)),
+        (True, 512, 512, (128, 128, 1, True)),
+        (True, 512, 512, (128, 256, 2, False)),
+        (True, 384, 512, (256, 128, 2, True)),
+        (False, 300, 300, (128, 128, 3, True)),
+    ])
+    def test_unmasked_body_equals_masked_body(self, force, causal, sq, sk,
+                                              sched):
+        """A tile wholly under the diagonal (or wholly inside the length)
+        gives the same numbers through the unmasked body as through the
+        masked one: with every tile that runs sent through the masked
+        body, all five results are what the schedule gives (to an ulp:
+        the interpreter fuses the two bodies differently)."""
+        force("_pick_blocks", lambda *a, **kw: fa.Schedule(*sched))
+        q, k, v = _bhsd(1, 2, sq, sk, 64, jnp.float32)
+        scheduled, _ = _flash_and_grads(q, k, v, causal)
+        key_tiles, query_tiles = fa._key_tiles, fa._query_tiles
+
+        traced = []
+
+        def all_masked_keys(*a):
+            free, run = key_tiles(*a)
+            traced.append("keys")
+            return free * 0, run
+
+        def all_masked_queries(*a):
+            lo, free, end = query_tiles(*a)
+            traced.append("queries")
+            return lo, end, end
+
+        force("_key_tiles", all_masked_keys)
+        force("_query_tiles", all_masked_queries)
+        masked, _ = _flash_and_grads(q, k, v, causal)
+        # the kernels were traced anew, through the all-masked bounds
+        assert {"keys", "queries"} <= set(traced)
+        for a, b in zip(jax.tree_util.tree_leaves(scheduled),
+                        jax.tree_util.tree_leaves(masked)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("sched", [
+        (128, 128, 4, True), (128, 128, 1, True), (256, 128, 2, True),
+        (128, 256, 2, True), (512, 512, 1, True), (128, 128, 4, False),
+        (128, 128, 1, False), (512, 512, 1, False)])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_any_schedule_gives_the_same_attention(self, force, sched,
+                                                   causal):
+        force("_pick_blocks", lambda *a, **kw: fa.Schedule(*sched))
+        q, k, v = _bhsd(1, 2, 512, 512, 64, jnp.float32)
+        _assert_close(*_flash_and_grads(q, k, v, causal), 2e-5)
+
+    @pytest.mark.parametrize("shape,want", [
+        # gpt355m_train: K/V of a head resident, 512 x 512 tiles walked
+        # in-kernel up to the diagonal
+        ((2048, 2048, 64, True), dict(
+            schedule=(512, 512, 4, True), grid_fwd_dq=(4, 1),
+            grid_dkv=(4, 1), tiles_run=10, tiles_masked=4, tiles_skipped=6,
+            scores_run=2621440, scores_masked=1048576)),
+        # bert_base_train: one tile is the whole head
+        ((512, 512, 64, False), dict(
+            schedule=(512, 512, 1, True), grid_fwd_dq=(1, 1),
+            grid_dkv=(1, 1), tiles_run=1, tiles_masked=0, tiles_skipped=0,
+            scores_run=262144, scores_masked=0)),
+        # 16k / d128: streams 1024 x 1024 tiles, query-major forward
+        ((16384, 16384, 128, True), dict(
+            schedule=(1024, 1024, 1, False), grid_fwd_dq=(16, 16),
+            grid_dkv=(16, 16), tiles_run=136, tiles_masked=16,
+            tiles_skipped=120, scores_run=136 * 1024 * 1024,
+            scores_masked=16 * 1024 * 1024)),
+        # a ragged dense length under 1024: one tile, masked at its edge
+        ((1000, 1000, 64, False), dict(
+            schedule=(1008, 1008, 1, True), grid_fwd_dq=(1, 1),
+            grid_dkv=(1, 1), tiles_run=1, tiles_masked=1, tiles_skipped=0,
+            scores_run=1008 * 1008, scores_masked=1008 * 1008)),
+        # s1024 / d64 causal: the single tile, not a walk of three (the
+        # forward is 0.36 ms against 0.46, `_pick_blocks`)
+        ((1024, 1024, 64, True), dict(
+            schedule=(1024, 1024, 1, True), grid_fwd_dq=(1, 1),
+            grid_dkv=(1, 1), tiles_run=1, tiles_masked=1, tiles_skipped=0,
+            scores_run=1024 * 1024, scores_masked=1024 * 1024)),
+        # s4096 / d64 causal: 512 KiB of K (and of V) a head, the largest
+        # that stays resident
+        ((4096, 4096, 64, True), dict(
+            schedule=(512, 512, 8, True), grid_fwd_dq=(8, 1),
+            grid_dkv=(8, 1), tiles_run=36, tiles_masked=8, tiles_skipped=28,
+            scores_run=36 * 512 * 512, scores_masked=8 * 512 * 512)),
+        # s8192 / d64: too long to stay resident, streamed key-major
+        ((8192, 8192, 64, True), dict(
+            schedule=(1024, 1024, 1, True), grid_fwd_dq=(8, 8),
+            grid_dkv=(8, 8), tiles_run=36, tiles_masked=8, tiles_skipped=28,
+            scores_run=36 * 1024 * 1024, scores_masked=8 * 1024 * 1024)),
+        # a resident walk whose last tile the length ends in
+        ((1300, 1300, 64, False), dict(
+            schedule=(512, 512, 3, True), grid_fwd_dq=(3, 1),
+            grid_dkv=(3, 1), tiles_run=9, tiles_masked=3, tiles_skipped=0,
+            scores_run=9 * 512 * 512, scores_masked=3 * 512 * 512)),
+    ], ids=["gpt355m_train", "bert_base_train", "16k_d128", "ragged_1000",
+            "s1024_d64", "s4096_d64", "s8192_d64", "ragged_1300"])
+    def test_schedule_counts_pinned(self, shape, want):
+        sq, sk, d, causal = shape
+        assert fa.schedule_counts(sq, sk, d, causal, jnp.bfloat16) == want
+
+    def test_named_blocks_are_obeyed(self):
+        """A caller that names a block gets its tile, one a grid step."""
+        kw = dict(head_dim=64, dtype=jnp.bfloat16)
+        assert fa._pick_blocks(2048, 2048, 512, 256, **kw) == (
+            512, 256, 1, True)
+        assert fa._pick_blocks(2048, 2048, 256, None, **kw) == (
+            256, fa.DEFAULT_BLOCK_K, 1, True)
+        assert fa._pick_blocks(200, 333, 1024, 1024, **kw) == (
+            208, 336, 1, True)
 
 
 class TestFusedNorms:

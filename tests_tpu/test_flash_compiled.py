@@ -78,6 +78,55 @@ def test_flash_grads_compiled(shape, dtype, causal):
         assert err < (5e-2 if dtype == jnp.bfloat16 else 2e-2), err
 
 
+# the train cells' attention shapes and one shape for each other schedule
+# `_pick_blocks` can return, no block named
+CELL_CASES = [
+    ((4, 16, 2048, 2048, 64), jnp.bfloat16, True),     # gpt355m_train
+    ((48, 12, 512, 512, 64), jnp.bfloat16, False),     # bert_base_train
+    ((2, 4, 1024, 1024, 64), jnp.bfloat16, True),      # the lone 1024 tile
+    ((2, 2, 1000, 1000, 64), jnp.bfloat16, False),     # ... ragged
+    ((2, 2, 1300, 1300, 64), jnp.bfloat16, True),      # a walk that ends
+    #                                                    inside a tile
+    ((1, 4, 4096, 4096, 64), jnp.bfloat16, True),      # the longest walk
+    ((2, 2, 512, 2048, 64), jnp.bfloat16, False),      # seq_q != seq_k
+    ((1, 2, 8192, 8192, 64), jnp.bfloat16, True),      # streamed key-major
+    ((2, 2, 2048, 2048, 128), jnp.bfloat16, True),     # query-major forward
+    ((2, 2, 2048, 2048, 64), jnp.float32, True),       # f32: streamed
+]
+
+
+@pytest.mark.parametrize("shape,dtype,causal", CELL_CASES)
+def test_flash_cell_shapes_compiled(shape, dtype, causal):
+    """Forward and all three gradients, no block named: `_pick_blocks`
+    chooses from the shape."""
+    b, h, sq, sk, d = shape
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(b, h, sq, d), dtype)
+    k = jnp.asarray(rng.randn(b, h, sk, d), dtype)
+    v = jnp.asarray(rng.randn(b, h, sk, d), dtype)
+    scale = 1.0 / np.sqrt(d)
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def f(q, k, v):
+        o = _flash_bhsd(q, k, v, causal, scale, None, None, False)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def g(q, k, v):
+        o = ref_attn(q, k, v, causal, scale)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    got_g, got_o = jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(
+        q, k, v)
+    want_g, want_o = jax.jit(jax.grad(g, argnums=(0, 1, 2), has_aux=True))(
+        q, k, v)
+    for a, b_, tol in [(got_o, want_o, 2e-2)] + [
+            (a, b_, 5e-2) for a, b_ in zip(got_g, want_g)]:
+        denom = float(jnp.max(jnp.abs(b_.astype(jnp.float32)))) + 1e-6
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                    - b_.astype(jnp.float32)))) / denom
+        assert err < tol, err
+
+
 def test_flash_long_sequence_16k():
     """16k-token causal attention: K/V must stream through VMEM (the r2
     kernel pinned the whole K/V per (batch,head) and could not even hold
