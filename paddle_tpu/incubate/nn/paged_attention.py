@@ -291,17 +291,24 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     return k_pages, v_pages
 
 
-def grouped_causal_attention(q, k, v, scale):
+def grouped_causal_attention(q, k, v, scale, block=1):
     """``q [b, s, H, d]`` over ``k`` / ``v [b, s, H_kv, d]``, query head
     ``i`` reading K/V head ``i // (H / H_kv)``; ``scale`` multiplies the
-    scores; causal, float32 softmax, both contractions accumulated wide."""
+    scores; causal, float32 softmax, both contractions accumulated wide.
+    ``block`` > 1 makes the mask causal over BLOCKS of that many positions:
+    ``i`` sees ``j`` iff ``j // block <= i // block`` (its whole block and
+    every earlier one)."""
     b, s, H, d = q.shape
     g = H // k.shape[2]
     qg = q.reshape(b, s, k.shape[2], g, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(jnp.tril(jnp.ones((s, s), jnp.bool_)), scores,
-                       jnp.finfo(jnp.float32).min)
+    if block == 1:
+        seen = jnp.tril(jnp.ones((s, s), jnp.bool_))
+    else:
+        at = jnp.arange(s) // block
+        seen = at[None, :] <= at[:, None]
+    scores = jnp.where(seen, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
                      preferred_element_type=jnp.float32)

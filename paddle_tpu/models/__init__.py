@@ -46,3 +46,7 @@ from paddle_tpu.models.granitemoehybrid import (  # noqa: F401
     GraniteMoeHybridConfig,
     GraniteMoeHybridForCausalLM,
 )
+from paddle_tpu.models.sdar_moe import (  # noqa: F401
+    SdarMoeConfig,
+    SdarMoeForCausalLM,
+)

@@ -55,11 +55,12 @@ from paddle_tpu.quantization.kv_cache import resolve_kv_cache_dtype
 from paddle_tpu.resilience.faultinject import fire as _fire
 from paddle_tpu.resilience.faultinject import note_recovery
 from paddle_tpu.resilience.health import HealthMonitor
+from paddle_tpu.serving.generation import make_generation
 from paddle_tpu.serving.kv_pool import make_page_pool
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.request import (GenerationResult, Request,
                                         RequestState, SamplingParams)
-from paddle_tpu.serving.sampler import sample_tokens, sampler_path
+from paddle_tpu.serving.sampler import sampler_path
 from paddle_tpu.serving.scheduler import (AdmissionRejected, Scheduler,
                                           default_buckets)
 
@@ -357,6 +358,8 @@ class LLMEngine:
         # the cache kind, chosen once; the arrays are engine state
         self._pool = make_page_pool(model, cfg, self._mesh)
         self._k_pools, self._v_pools = self._pool.allocate(self._device)
+        # the kind of generation, chosen once (serving/generation.py)
+        self._gen = make_generation(model, cfg)
         self._moe_layers = int(getattr(model, "num_expert_layers", 0))
         self._moe_experts = int(getattr(mc, "n_routed_experts", 0))
         self._moe_top_k = int(getattr(mc, "num_experts_per_tok", 0))
@@ -395,6 +398,7 @@ class LLMEngine:
         self.metrics.compile_bound = cfg.compile_bound
         self.metrics.pages_total = cfg.num_pages - 1   # page 0 reserved
         self.metrics.state_pool_bytes = self._pool.state_nbytes
+        self.metrics.block_length = self._gen.block_length
         # health state machine over live page-pool occupancy; the gauge
         # is EngineMetrics-owned so its registry lifecycle matches
         self.health = HealthMonitor(
@@ -531,7 +535,7 @@ class LLMEngine:
         """What the decode program's attention was built from — the
         AOT fingerprint's term for it: the Pallas kernel at its
         revision, or the XLA composition (the pool kind's to say)."""
-        return self._pool.attention_path
+        return self._pool.attention_path + self._gen.path
 
     @property
     def program_fingerprint(self):
@@ -550,7 +554,9 @@ class LLMEngine:
                 max_new_tokens=sp.max_new_tokens,
                 temperature=sp.temperature, top_k=sp.top_k,
                 top_p=sp.top_p, seed=sp.seed,
-                eos_token_id=self.config.eos_token_id)
+                eos_token_id=self.config.eos_token_id,
+                denoising_steps=sp.denoising_steps, remasking=sp.remasking,
+                confidence_threshold=sp.confidence_threshold)
         return sp
 
     def _validate_request(self, prompt, sp):
@@ -559,7 +565,9 @@ class LLMEngine:
         strand earlier ones in the queue."""
         if not prompt:
             raise ValueError("prompt must contain at least one token")
-        total_max = len(prompt) + sp.max_new_tokens
+        self._gen.check_params(sp)
+        total_max = self._gen.positions_needed(len(prompt),
+                                               sp.max_new_tokens)
         if total_max > self.config.max_model_len:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens "
@@ -661,6 +669,7 @@ class LLMEngine:
                                      is None else int(arrival_index)),
                       stream=stream)
         req.output_token_ids = generated
+        req.fixed_at = [None] * len(generated)
         req._streamed = len(generated) if streamed is None \
             else min(int(streamed), len(generated))
         # adopted == evicted-elsewhere: requests_admitted/ttft are the
@@ -715,6 +724,7 @@ class LLMEngine:
         `release` (default) the request leaves this engine entirely —
         slot, pages and live-table entry — so prefill workers stay
         empty-handed between handoffs."""
+        self._gen.check_handoff()
         req = self._requests.get(request_id)
         if req is None or req.slot is None:
             raise ValueError(
@@ -769,6 +779,7 @@ class LLMEngine:
         :class:`AdmissionRejected` when no slot/pages are free or this
         engine is DRAINING (the exporter still holds the state dict and
         can retry elsewhere)."""
+        self._gen.check_handoff()
         geo = state["geometry"]
         for k, want in self._pool.geometry.items():
             if geo.get(k) != want:
@@ -812,6 +823,7 @@ class LLMEngine:
                           "arrival_index", self._next_id)),
                       stream=stream)
         req.output_token_ids = generated
+        req.fixed_at = [None] * len(generated)
         req._streamed = min(int(state.get("streamed", len(generated))),
                             len(generated))
         req.num_evictions = 1     # admitted/ttft were the exporter's
@@ -986,12 +998,16 @@ class LLMEngine:
         req.transition(RequestState.PREFILL)
         tokens = req.replay_token_ids
         L = len(tokens)
-        bucket = self.scheduler.bucket_for_len(L)
+        # the positions this prefill stores (a kind may leave the tail of
+        # the prompt to its first pass; none stored = no program runs)
+        covered = self._gen.prefill_len(L)
+        bucket = self.scheduler.bucket_for_len(covered) if covered else 0
         with span("serving.prefill", ctx=req.trace,
                   request=req.request_id, bucket=bucket,
-                  tokens=L, **self._pool.prefill_attrs(L, bucket)) as span_:
-            self._prefill_inner(req, events, cfg, t0, tokens, L, bucket,
-                                span_)
+                  tokens=L, **self._pool.prefill_attrs(covered, bucket),
+                  **self._gen.prefill_attrs(covered)) as span_:
+            self._prefill_inner(req, events, cfg, t0, tokens, covered,
+                                bucket, span_)
 
     def _note_experts(self, span_, rows):
         """After a sample fetch that carried a program's expert stats:
@@ -1012,65 +1028,85 @@ class LLMEngine:
             rows * self._moe_top_k * self._moe_layers, tokens_max,
             rows * self._moe_top_k / self._moe_experts)
 
-    def _prefill_inner(self, req, events, cfg, t0, tokens, L, bucket,
+    def _prefill_inner(self, req, events, cfg, t0, tokens, covered, bucket,
                        span_):
         slot = self._slots.index(None)
         self._slots[slot] = req
         req.slot = slot
 
-        need = self._alloc.pages_needed(L, cfg.page_size)
+        need = self._alloc.pages_needed(covered, cfg.page_size)
         for pos, page in self._alloc.allocate(slot, need):
             self._tables[slot, pos] = page
 
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :L] = tokens
-        pos_ids = np.arange(bucket, dtype=np.int32)[None, :]
-        length = np.array([L], np.int32)
+        head = stats = ()
+        if covered:
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :covered] = tokens[:covered]
+            pos_ids = np.arange(bucket, dtype=np.int32)[None, :]
+            length = np.array([covered], np.int32)
 
-        fn = self._get_prefill(bucket)
-        last_logits, self._k_pools, self._v_pools, *stats = fn(
-            self._params, self._k_pools, self._v_pools,
-            self._place(self._tables[slot:slot + 1]), self._place(ids),
-            self._place(pos_ids), self._place(length),
-            *(self._place(x) for x in self._pool.slot_operands(slot)))
-        self._lens[slot] = L
+            fn = self._get_prefill(bucket)
+            out = fn(
+                self._params, self._k_pools, self._v_pools,
+                self._place(self._tables[slot:slot + 1]), self._place(ids),
+                self._place(pos_ids), self._place(length),
+                *(self._place(x) for x in self._pool.slot_operands(slot)))
+            # what the kind's program puts first, the pools, the stats
+            n = self._gen.prefill_heads
+            head, stats = out[:n], out[n + 2:]
+            self._k_pools, self._v_pools = out[n:n + 2]
+        self._lens[slot] = covered
         # a slot's per-slot state, if the pool keeps one, is now this
         # request's: whatever ran there before is overwritten
         self.metrics.state_admits_total += bool(self._pool.state_layers)
+        self._gen.admitted(self, req, slot, tokens, head, stats, span_,
+                           bucket, t0, events)
 
-        tok = self._sample(last_logits, [req], width=1, carry=stats)[0]
-        self._note_experts(span_, bucket)
-        now = self.metrics.clock()
-        self.metrics.prefill_steps += 1
-        self.metrics.prefill_step_s.observe(now - t0)
-        self.metrics.prompt_tokens += L
+    def _note_prefill(self, req, tokens, t0, now, ran=True):
+        """An admission's counters (`ran`: a prefill program ran for it);
+        for a fresh request the queue and prefill stages of its time to
+        first token."""
+        if ran:
+            self.metrics.prefill_steps += 1
+            self.metrics.prefill_step_s.observe(now - t0)
+        self.metrics.prompt_tokens += tokens
         if req.num_evictions == 0:
             self.metrics.requests_admitted += 1
-            self.metrics.ttft.observe(now - req.arrive_t)
-            # stage decomposition: for a fresh request TTFT is exactly
-            # queue-wait (arrival -> prefill start) + prefill
+            # stage decomposition: queue-wait (arrival -> prefill start)
+            # + prefill
             self.metrics.ttft_queue.observe(max(0.0, t0 - req.arrive_t))
             self.metrics.ttft_prefill.observe(max(0.0, now - t0))
-        req.append_token(tok, now=now)
-        self._observe_resume(req, now)
-        self.metrics.generated_tokens += 1
-        self._post_token(req, events, now)
-        if not req.is_finished:
-            req.transition(RequestState.DECODE)
+
+    def _deliver(self, req, toks, fixed_at, now, events, gap=True):
+        """`toks` reach `req` together, in order, at `now` (one stamp, ONE
+        inter-token gap); delivery stops at the token that finishes the
+        request."""
+        if req.first_token_t is None and req.num_evictions == 0:
+            self.metrics.ttft.observe(now - req.arrive_t)
+        if gap and req.last_token_t is not None:
+            self.metrics.inter_token.observe(now - req.last_token_t)
+        for tok, at in zip(toks, fixed_at):
+            req.append_token(tok, now=now, fixed_at=at)
+            self._observe_resume(req, now)
+            self.metrics.generated_tokens += 1
+            self._post_token(req, events, now)
+            if req.is_finished:
+                break
 
     # -------------------------------------------------------- decode
     def _decode_step(self, events):
         # pages_live: the pages this step's attention has to read, from
         # the host-side lengths (before the capacity pass evicts anyone)
         page = self.config.page_size
-        pages_live = sum(-(-(int(self._lens[s]) + 1) // page)
+        pages_live = sum(-(-(int(self._lens[s]) + self._gen.rows) // page)
                          for s, r in enumerate(self._slots)
                          if r is not None)
         self.metrics.pages_live = pages_live
         with span("serving.decode", live=self.num_running,
                   pages_live=pages_live,
                   kernel=self._pool.decode_kernel,
-                  **self._pool.decode_attrs(self.num_running)) as span_:
+                  **self._pool.decode_attrs(self.num_running),
+                  **self._gen.decode_attrs(self)) as span_:
             self._decode_step_inner(events, span_)
 
     def _decode_step_inner(self, events, span_):
@@ -1089,14 +1125,15 @@ class LLMEngine:
                 self._evict(victim, events)
                 note_recovery("serving.pool", "pool_exhaust",
                               victim=victim.request_id)
-        # capacity pass: every live row must fit one more token; the
-        # pool running dry preempts the latest-arrived running request
+        # capacity pass: every live row must fit what this pass writes
+        # (one more token, or its in-flight block); the pool running dry
+        # preempts the latest-arrived running request
         for slot in range(cfg.max_num_seqs):
             req = self._slots[slot]
             if req is None:
                 continue
             need = self._alloc.pages_needed(
-                int(self._lens[slot]) + 1, cfg.page_size)
+                int(self._lens[slot]) + self._gen.rows, cfg.page_size)
             while not self._alloc.can_allocate(slot, need):
                 victim = self.scheduler.select_victim(
                     [r for r in self._slots if r is not None])
@@ -1115,9 +1152,7 @@ class LLMEngine:
         live = [(s, r) for s, r in enumerate(self._slots) if r is not None]
         if not live:
             return
-        tokens = np.zeros((cfg.max_num_seqs, 1), np.int32)
-        for s, r in live:
-            tokens[s, 0] = r.output_token_ids[-1]
+        tokens = self._gen.decode_operands(self, live)
 
         fn = self._get_decode()
         guard_args = ()
@@ -1147,22 +1182,14 @@ class LLMEngine:
             logits, self._k_pools, self._v_pools = out
         self._decode_fault_streak = 0
 
-        reqs = [self._slots[s] for s in range(cfg.max_num_seqs)]
-        toks = self._sample(logits, reqs, width=cfg.max_num_seqs,
-                            carry=stats)
-        self._note_experts(span_, cfg.max_num_seqs)
-        for s, r in live:
-            self._lens[s] += 1
+        self._gen.decoded(self, live, logits, stats, span_, t0, events)
+
+    def _note_decode(self, t0):
+        """A decode pass's counters; returns the stamp its tokens get."""
         now = self.metrics.clock()
         self.metrics.decode_steps += 1
         self.metrics.decode_step_s.observe(now - t0)
-        for s, r in live:
-            if r.last_token_t is not None:
-                self.metrics.inter_token.observe(now - r.last_token_t)
-            r.append_token(toks[s], now=now)
-            self._observe_resume(r, now)
-            self.metrics.generated_tokens += 1
-            self._post_token(r, events, now)
+        return now
 
     def _poison_vector(self, live):
         """The guarded decode's injection operand: zeros in production;
@@ -1260,26 +1287,19 @@ class LLMEngine:
 
     # ------------------------------------------------------ sampling
     def _sample(self, logits, reqs, width, carry=()):
-        """reqs: per-row Request or None (padding rows).  Position is
-        the ABSOLUTE index of the token being sampled = the row's cache
-        length AFTER its input token was appended — which is exactly
-        `total_len` host-side.  `carry`: the expert stats of the program
-        that made `logits` (a model with expert layers), which ride this
-        step's one blocking fetch into ``_moe_stats``."""
-        seeds = np.zeros((width,), np.int32)
-        pos = np.zeros((width,), np.int32)
-        temps = np.zeros((width,), np.float32)
-        top_ks = np.zeros((width,), np.int32)
-        top_ps = np.ones((width,), np.float32)
-        for i, r in enumerate(reqs):
-            if r is None:
-                continue
-            sp = r.sampling_params
-            seeds[i] = sp.seed
-            pos[i] = r.total_len
-            temps[i] = sp.temperature
-            top_ks[i] = sp.top_k
-            top_ps[i] = sp.top_p
+        """The sampler over `logits`, one Request (or None: a padding
+        row, a dead slot) a row or slot; the generation kind says what a
+        row's key is and what comes back."""
+        return self._gen.sample(self, logits, reqs, width, carry)
+
+    def _run_sampler(self, width, logits, keys, temps, top_ks, top_ps,
+                     carry=()):
+        """One call of the ``sample/<width>`` program and its ONE blocking
+        fetch.  `keys`: the per-row operands a draw's key is made of, as
+        the generation kind orders them (seeds, positions, ...).  `carry`:
+        the expert stats of the program that made `logits` (a model with
+        expert layers), which ride the fetch into ``_moe_stats``.  Returns
+        the fetched array less the stats."""
         # the searches this call's program will run: it branches on the
         # same three facts of the same operands
         draws, any_k, any_p = sampler_path(temps, top_ks, top_ps)
@@ -1289,12 +1309,13 @@ class LLMEngine:
         with span("serving.sample", width=width, path=path):
             fn = self._get_sampler(width)
             out = np.asarray(fn(
-                self._place(logits), self._place(seeds), self._place(pos),
+                self._place(logits), *(self._place(k) for k in keys),
                 self._place(temps), self._place(top_ks),
                 self._place(top_ps), *carry))
         if carry:
-            self._moe_stats = out[width:]
-        return [int(t) for t in out[:width]]
+            self._moe_stats = out[-len(carry[0]):]
+            out = out[:-len(carry[0])]
+        return out
 
     # ------------------------------------------------- finish / evict
     def _observe_resume(self, req, now):
@@ -1394,38 +1415,31 @@ class LLMEngine:
             return None
         return (self._repl_sharding, *self._pool.out_shardings())
 
-    def _prefill_program(self, bucket):
-        """(fn, example_args, donate, out_shardings) for one prefill
-        bucket — shared by the compile path and the shardlint self-audit
-        (which traces the SAME program, never a lookalike)."""
+    def _kv_context(self, k_pools, v_pools, tables, lens, mode, *slot):
+        """Traced: the cache-aware attention hook of one program."""
+        return PagedKVContext(self._pool, k_pools, v_pools, tables, lens,
+                              mode, *slot)
+
+    def _prefill_example(self, bucket):
+        """Example operands of a prefill program at `bucket`."""
         cfg = self.config
-
-        def prefill(params, k_pools, v_pools, row_table, ids, pos_ids,
-                    length, *slot):
-            ctx = PagedKVContext(self._pool, k_pools, v_pools, row_table,
-                                 length, "prefill", *slot)
-            if self._head_on_last:
-                # the model's head runs on the last REAL token alone
-                last = self._run_model(
-                    params, ids, pos_ids, ctx,
-                    logits_positions=Tensor(length - 1))[:, 0]
-                return (last.astype(jnp.float32), ctx.k_pools,
-                        ctx.v_pools) + self._expert_stats(ctx)
-            logits = self._run_model(params, ids, pos_ids, ctx)
-            # logits [1, bucket, V] -> the last REAL token's row
-            last = jnp.take_along_axis(
-                logits, (length - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-            return (last.astype(jnp.float32), ctx.k_pools, ctx.v_pools)
-
-        return prefill, (
+        return (
             self._params, self._k_pools, self._v_pools,
             jnp.zeros((1, cfg.max_pages_per_seq), jnp.int32),
             jnp.zeros((1, bucket), jnp.int32),
             jnp.zeros((1, bucket), jnp.int32),
             jnp.zeros((1,), jnp.int32),
-            *(jnp.asarray(x) for x in self._pool.slot_operands(0))), \
-            (1, 2), self._step_out_shardings()
+            *(jnp.asarray(x) for x in self._pool.slot_operands(0)))
+
+    def _decode_example(self, rows):
+        """Example operands of the decode program at `rows` ids a slot."""
+        cfg = self.config
+        return (
+            self._params, self._k_pools, self._v_pools,
+            jnp.zeros((cfg.max_num_seqs, cfg.max_pages_per_seq),
+                      jnp.int32),
+            jnp.zeros((cfg.max_num_seqs,), jnp.int32),
+            jnp.zeros((cfg.max_num_seqs, rows), jnp.int32))
 
     def _expert_stats(self, ctx):
         """The extra output of a program of a model with expert layers
@@ -1445,51 +1459,6 @@ class LLMEngine:
         return jnp.stack([bad_logits, bad_scale],
                          axis=-1).astype(jnp.float32)
 
-    def _decode_program(self):
-        cfg = self.config
-
-        if cfg.guard:
-            # sentinel-guarded decode: one extra [B, 1] poison operand
-            # (all zeros in production — the fault-injection hook adds
-            # NaN/inf to a victim row, so injection never changes the
-            # compiled program) and one extra [B, 2] anomaly-flag
-            # output.  Still ONE decode program for the engine's life.
-            def decode(params, k_pools, v_pools, tables, lens, tokens,
-                       poison):
-                ctx = PagedKVContext(self._pool, k_pools, v_pools,
-                                     tables, lens, "decode")
-                logits = self._run_model(params, tokens, lens[:, None],
-                                         ctx)
-                logits = logits[:, 0].astype(jnp.float32) + poison
-                flags = self._guard_flags(logits, ctx.k_pools,
-                                          ctx.v_pools, tables, lens)
-                return (logits, ctx.k_pools, ctx.v_pools,
-                        flags) + self._expert_stats(ctx)
-
-            return decode, (
-                self._params, self._k_pools, self._v_pools,
-                jnp.zeros((cfg.max_num_seqs, cfg.max_pages_per_seq),
-                          jnp.int32),
-                jnp.zeros((cfg.max_num_seqs,), jnp.int32),
-                jnp.zeros((cfg.max_num_seqs, 1), jnp.int32),
-                jnp.zeros((cfg.max_num_seqs, 1), jnp.float32)), (1, 2), \
-                self._guarded_out_shardings()
-
-        def decode(params, k_pools, v_pools, tables, lens, tokens):
-            ctx = PagedKVContext(self._pool, k_pools, v_pools, tables,
-                                 lens, "decode")
-            logits = self._run_model(params, tokens, lens[:, None], ctx)
-            return (logits[:, 0].astype(jnp.float32),
-                    ctx.k_pools, ctx.v_pools) + self._expert_stats(ctx)
-
-        return decode, (
-            self._params, self._k_pools, self._v_pools,
-            jnp.zeros((cfg.max_num_seqs, cfg.max_pages_per_seq),
-                      jnp.int32),
-            jnp.zeros((cfg.max_num_seqs,), jnp.int32),
-            jnp.zeros((cfg.max_num_seqs, 1), jnp.int32)), (1, 2), \
-            self._step_out_shardings()
-
     def _guarded_out_shardings(self):
         """Decode out_shardings with the guard-flag output appended
         (replicated, like the logits)."""
@@ -1498,30 +1467,11 @@ class LLMEngine:
             return None
         return (*base, self._repl_sharding)
 
-    def _sampler_program(self, width):
-        V = int(self._model.config.vocab_size)
-        fn, carry = sample_tokens, ()
-        if self._moe_layers:
-            # the expert stats of the program that made the logits ride
-            # the token fetch: one array comes back, not two
-            def fn(logits, seeds, pos, temps, top_ks, top_ps, stats):
-                return jnp.concatenate([sample_tokens(
-                    logits, seeds, pos, temps, top_ks, top_ps), stats])
-            carry = (jnp.zeros((2,), jnp.int32),)
-        return fn, (
-            jnp.zeros((width, V), jnp.float32),
-            jnp.zeros((width,), jnp.int32),
-            jnp.zeros((width,), jnp.int32),
-            jnp.zeros((width,), jnp.float32),
-            jnp.zeros((width,), jnp.int32),
-            jnp.ones((width,), jnp.float32)) + carry, (), \
-            (self._repl_sharding if self._mesh is not None else None)
-
     def _get_prefill(self, bucket):
         key = ("prefill", bucket)
         if key in self._compiled:
             return self._compiled[key]
-        fn, example, donate, out_sh = self._prefill_program(bucket)
+        fn, example, donate, out_sh = self._gen.prefill_program(self, bucket)
         return self._compile(key, fn, example, donate=donate,
                              out_shardings=out_sh)
 
@@ -1529,7 +1479,7 @@ class LLMEngine:
         key = ("decode",)
         if key in self._compiled:
             return self._compiled[key]
-        fn, example, donate, out_sh = self._decode_program()
+        fn, example, donate, out_sh = self._gen.decode_program(self)
         return self._compile(key, fn, example, donate=donate,
                              out_shardings=out_sh)
 
@@ -1537,7 +1487,7 @@ class LLMEngine:
         key = ("sample", width)
         if key in self._compiled:
             return self._compiled[key]
-        fn, example, donate, out_sh = self._sampler_program(width)
+        fn, example, donate, out_sh = self._gen.sampler_program(self, width)
         return self._compile(key, fn, example, donate=donate,
                              out_shardings=out_sh)
 
@@ -1551,8 +1501,8 @@ class LLMEngine:
         for b in self.config.prefill_buckets:
             self._get_prefill(b)
         self._get_decode()
-        self._get_sampler(1)
-        self._get_sampler(self.config.max_num_seqs)
+        for width in self._gen.sampler_widths():
+            self._get_sampler(width)
         return {
             "programs": len(self._compiled),
             "compiled": self.metrics.compile_count,
@@ -1598,12 +1548,12 @@ class LLMEngine:
         import jax
         progs = {}
         for b in self.config.prefill_buckets:
-            fn, example, *_ = self._prefill_program(b)
+            fn, example, *_ = self._gen.prefill_program(self, b)
             progs[f"prefill_{b}"] = jax.jit(fn).trace(*example).jaxpr
-        fn, example, *_ = self._decode_program()
+        fn, example, *_ = self._gen.decode_program(self)
         progs["decode"] = jax.jit(fn).trace(*example).jaxpr
-        for width in (1, self.config.max_num_seqs):
-            fn, example, *_ = self._sampler_program(width)
+        for width in self._gen.sampler_widths():
+            fn, example, *_ = self._gen.sampler_program(self, width)
             progs[f"sample_{width}"] = jax.jit(fn).trace(*example).jaxpr
         return progs
 

@@ -302,15 +302,19 @@ class GroupedKV(PlainKV):
     at ``1/sqrt(d)``.  On one device."""
 
     def __init__(self, cfg, num_layers, num_heads, head_dim, query_heads,
-                 scale):
+                 scale, causal_block=1):
         super().__init__(cfg, num_layers, num_heads, head_dim, rows=True)
         self.groups = query_heads // num_heads
         self.scale = float(scale)
+        self.causal_block = int(causal_block)
         self.decode_kernel = False
         self.attention_path = "xla/row_pages"
+        if self.causal_block > 1:
+            self.geometry["causal_block"] = self.causal_block
 
     def prefill(self, q, k, v, kp, vp, tables, lens):
-        out = grouped_causal_attention(q, k, v, self.scale)
+        out = grouped_causal_attention(q, k, v, self.scale,
+                                       block=self.causal_block)
         kp, vp = self._append(jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
                               kp, vp, tables, lens, self.page_size)
         return out, kp, vp
@@ -319,7 +323,10 @@ class GroupedKV(PlainKV):
         """Each slot's new K/V row scattered at its length (in place),
         the slot's table gathered as rows ``[b, positions, H_kv, d]``, a
         K/V head's group of queries attending together; float32 softmax,
-        both contractions accumulated wide."""
+        both contractions accumulated wide.  ``q`` / ``k`` / ``v`` of
+        ``rows`` > 1 positions a slot are one BLOCK (:meth:`decode_block`)."""
+        if q.shape[1] > 1:
+            return self.decode_block(q, k, v, kp, vp, tables, lens)
         b, _, H, d = q.shape
         page, hk = self.page_size, self.num_heads
         lens = lens.astype(jnp.int32)
@@ -341,6 +348,37 @@ class GroupedKV(PlainKV):
         out = jnp.einsum("bhgk,bkhd->bhgd", probs, vals,
                          preferred_element_type=jnp.float32)
         return out.reshape(b, 1, H, d).astype(q.dtype), kp, vp
+
+    def decode_block(self, q, k, v, kp, vp, tables, lens):
+        """A pass over each slot's in-flight block of ``rows`` positions
+        (``q [b, rows, H, d]``): the block's K/V rows are written FIRST, at
+        ``len .. len + rows - 1`` (in place, in pages the slot owns;
+        overwritten by the block's next pass), then each of its ``rows x
+        H`` query rows attends over the slot's ``len + rows`` positions —
+        the stored prefix and the whole block, so no mask is needed inside
+        it.  ``len`` is not advanced here: the caller advances it when the
+        pass was the block's last."""
+        b, rows, H, d = q.shape
+        page, hk = self.page_size, self.num_heads
+        lens = lens.astype(jnp.int32)
+        at = lens[:, None] + jnp.arange(rows, dtype=jnp.int32)   # [b, rows]
+        page_ids = jnp.take_along_axis(tables, at // page, axis=1)
+        kp = kp.at[page_ids, at % page].set(
+            k.reshape(b, rows, hk * d).astype(kp.dtype))
+        vp = vp.at[page_ids, at % page].set(
+            v.reshape(b, rows, hk * d).astype(vp.dtype))
+        keys = kp[tables].reshape(b, -1, hk, d)
+        vals = vp[tables].reshape(b, -1, hk, d)
+        scores = jnp.einsum(
+            "bqhgd,bkhd->bhgqk", q.reshape(b, rows, hk, self.groups, d),
+            keys, preferred_element_type=jnp.float32) * self.scale
+        live = jnp.arange(keys.shape[1])[None, :] < (lens + rows)[:, None]
+        scores = jnp.where(live[:, None, None, None, :], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vals,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, rows, H, d).astype(q.dtype), kp, vp
 
 
 class LatentPool(PagePool):
@@ -464,10 +502,7 @@ class LayeredPool(PagePool):
         super().__init__(cfg, len(layers))
         self.kv = self.state = None
         if kv:
-            heads, dim = int(kv[0]["num_heads"]), int(kv[0]["head_dim"])
-            self.kv = GroupedKV(cfg, len(kv), heads, dim,
-                                int(kv[0].get("query_heads", heads)),
-                                kv[0].get("scale", dim ** -0.5))
+            self.kv = _grouped_kv(cfg, len(kv), kv[0])
             self.geometry.update(self.kv.geometry, num_layers=len(layers))
         if state:
             self.state = SlotState(cfg, state[0])
@@ -561,10 +596,23 @@ class LayeredPool(PagePool):
         return halves
 
 
+def _grouped_kv(cfg, num_layers, spec):
+    """The :class:`GroupedKV` a ``kv`` declaration with its own geometry
+    asks for: ``num_heads x head_dim``, optionally ``query_heads``,
+    ``scale`` and ``causal_block``."""
+    heads, dim = int(spec["num_heads"]), int(spec["head_dim"])
+    return GroupedKV(cfg, num_layers, heads, dim,
+                     int(spec.get("query_heads", heads)),
+                     spec.get("scale", dim ** -0.5),
+                     spec.get("causal_block", 1))
+
+
 def make_page_pool(model, cfg, mesh=None):
     """The pool of the kind `model` declares (``kv_cache_spec()``; no
-    declaration is GPT's K and V of ``num_heads x head_dim``; ``"layers"``
-    is a declaration per layer), at
+    declaration is GPT's K and V of ``num_heads x head_dim``; a ``"kv"``
+    declaration with a geometry of its own — ``num_heads``, ``head_dim``,
+    ``query_heads`` — is grouped-query K/V for the whole model;
+    ``"layers"`` is a declaration per layer), at
     `cfg`'s page geometry, dtype and ``kv_cache_dtype``, for the
     RESOLVED `mesh` (None off-mesh).  A kind that cannot shard or cannot
     narrow raises ``ValueError`` by name."""
@@ -577,6 +625,16 @@ def make_page_pool(model, cfg, mesh=None):
     if spec["kind"] != "kv":
         raise ValueError(f"unknown kv cache kind {spec['kind']!r}")
     mc = model.config
+    if "num_heads" in spec:
+        if cfg.kv_cache_dtype is not None:
+            raise ValueError(
+                f"kv_cache_dtype={cfg.kv_cache_dtype!r}: grouped-query "
+                f"K/V pages are stored plain at the engine's dtype")
+        if mesh is not None:
+            raise ValueError(
+                "mesh: grouped-query K/V pages are row pages on one "
+                "device (no head axis to shard)")
+        return _grouped_kv(cfg, mc.num_layers, spec)
     heads = int(mc.num_heads)
     args = (cfg, mc.num_layers, heads, int(mc.hidden_size) // heads, mesh)
     # kv_cache_dtype narrows the pool STORAGE only

@@ -161,6 +161,12 @@ class EngineMetrics:
         self.state_pool_bytes = 0
         self.state_admits_total = 0  # slots whose state a prefill overwrote
         self.moe_imbalance = None    # histogram of heaviest / mean load
+        # generation by diffusion over blocks (0 = a next-token engine,
+        # which snapshots as before)
+        self.block_length = 0
+        self.decode_forwards_total = 0   # live slots summed over passes
+        self.commit_passes_total = 0     # passes that stored a final block
+        self.tokens_fixed_total = 0      # positions fixed by denoising
 
     def note_experts(self, pairs, tokens_max, mean_load):
         """One program's routing: `pairs` token-expert pairs over all
@@ -221,9 +227,18 @@ class EngineMetrics:
         state = ({} if not self.state_pool_bytes else {"state": {
             "pool_bytes": self.state_pool_bytes,
             "admits_total": self.state_admits_total}})
+        blocks = ({} if not self.block_length else {"blocks": {
+            "block_length": self.block_length,
+            "decode_forwards_total": self.decode_forwards_total,
+            "commit_passes_total": self.commit_passes_total,
+            "tokens_fixed_total": self.tokens_fixed_total,
+            "tokens_per_forward": round(
+                self.tokens_fixed_total / self.decode_forwards_total, 4)
+            if self.decode_forwards_total else 0.0}})
         return {
             **moe,
             **state,
+            **blocks,
             "uptime_s": round(elapsed, 3),
             "requests": {
                 "received": self.requests_received,

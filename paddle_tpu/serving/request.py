@@ -52,8 +52,12 @@ class SamplingParams:
     deadline semantics rather than unbounded queueing.
     """
 
+    REMASKING = ("low_confidence_static", "low_confidence_dynamic")
+
     def __init__(self, max_new_tokens=16, temperature=0.0, top_k=0,
-                 top_p=1.0, seed=0, eos_token_id=None, deadline_s=None):
+                 top_p=1.0, seed=0, eos_token_id=None, deadline_s=None,
+                 denoising_steps=None, remasking=None,
+                 confidence_threshold=None):
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         if temperature < 0.0:
@@ -62,6 +66,13 @@ class SamplingParams:
             raise ValueError("top_p must be in (0, 1]")
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError("deadline_s must be positive (or None)")
+        if denoising_steps is not None and denoising_steps < 1:
+            raise ValueError("denoising_steps must be >= 1")
+        if remasking is not None and remasking not in self.REMASKING:
+            raise ValueError(f"remasking must be one of {self.REMASKING}")
+        if confidence_threshold is not None \
+                and not 0.0 <= confidence_threshold < 1.0:
+            raise ValueError("confidence_threshold must be in [0, 1)")
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -70,13 +81,25 @@ class SamplingParams:
         self.eos_token_id = eos_token_id
         self.deadline_s = float(deadline_s) if deadline_s is not None \
             else None
+        # generation by diffusion over blocks only (None = the engine's
+        # default; a next-token model refuses them by name when set)
+        self.denoising_steps = (int(denoising_steps)
+                                if denoising_steps is not None else None)
+        self.remasking = remasking
+        self.confidence_threshold = (float(confidence_threshold)
+                                     if confidence_threshold is not None
+                                     else None)
 
     def __repr__(self):
+        blocks = "".join(
+            f", {k}={getattr(self, k)}" for k in (
+                "denoising_steps", "remasking", "confidence_threshold")
+            if getattr(self, k) is not None)
         return (f"SamplingParams(max_new_tokens={self.max_new_tokens}, "
                 f"temperature={self.temperature}, top_k={self.top_k}, "
                 f"top_p={self.top_p}, seed={self.seed}, "
                 f"eos_token_id={self.eos_token_id}, "
-                f"deadline_s={self.deadline_s})")
+                f"deadline_s={self.deadline_s}{blocks})")
 
 
 class Request:
@@ -98,6 +121,10 @@ class Request:
         self.stream = stream
         self.state = RequestState.WAITING
         self.output_token_ids = []
+        # per output token, the denoising pass within its block that fixed
+        # it (generation by diffusion over blocks; None for a next-token
+        # model and for tokens generated before an adoption)
+        self.fixed_at = []
         self._streamed = 0          # tokens already delivered to `stream`
         self._stream_done = False   # final last=True signal sent
         self.slot = None            # decode batch slot while running
@@ -139,11 +166,12 @@ class Request:
     def is_finished(self):
         return self.state == RequestState.FINISHED
 
-    def append_token(self, token_id, now=None):
+    def append_token(self, token_id, now=None, fixed_at=None):
         """Record a newly sampled token; returns True if it was NEW
         (not a replay duplicate — replays never reach here because the
         engine re-prefills rather than re-samples)."""
         self.output_token_ids.append(int(token_id))
+        self.fixed_at.append(fixed_at)
         if self.first_token_t is None and now is not None:
             self.first_token_t = now
         self.last_token_t = now

@@ -82,11 +82,19 @@ def sampler_path(temperatures, top_ks, top_ps):
             (draws & (top_ps < 1.0)).any())
 
 
-def sample_tokens(logits, seeds, positions, temperatures, top_ks, top_ps):
+def sample_tokens(logits, seeds, positions, temperatures, top_ks, top_ps,
+                  passes=None):
     """Batched sampling (pure, trace-safe).
 
     logits [B, V] f32; seeds/positions/top_ks [B] int32;
     temperatures/top_ps [B] f32 -> token ids [B] int32.
+
+    With ``passes [B]`` int32 (generation by diffusion over blocks: a
+    position is sampled once a denoising pass) the key of a draw also
+    folds in the row's pass, and the result is ``(token ids, confidence)``:
+    ``confidence [B]`` f32 is the probability of the chosen token under the
+    distribution it was chosen from — the softmax of the logits for a
+    greedy row, the filtered and renormalised one for a drawn row.
     """
     B, V = logits.shape
     logits = logits.astype(jnp.float32)
@@ -94,6 +102,9 @@ def sample_tokens(logits, seeds, positions, temperatures, top_ks, top_ps):
     top_ks, top_ps = top_ks.astype(jnp.int32), top_ps.astype(jnp.float32)
     greedy = jnp.argmax(logits, -1).astype(jnp.int32)
     any_draw, any_k, any_p = sampler_path(temperatures, top_ks, top_ps)
+    if passes is not None:
+        greedy = (greedy, jnp.exp(
+            jnp.max(logits, -1) - jax.nn.logsumexp(logits, -1)))
 
     def draw():
         # temperature; <=0 means greedy (selected at the end)
@@ -125,6 +136,18 @@ def sample_tokens(logits, seeds, positions, temperatures, top_ks, top_ps):
                               _NEG_INF)
 
         # Gumbel-max draw from the filtered distribution
+        if passes is not None:
+            gumbel = jax.vmap(lambda seed, position, pass_: jax.random.gumbel(
+                jax.random.fold_in(jax.random.fold_in(
+                    jax.random.PRNGKey(seed), position), pass_),
+                (V,), jnp.float32))(seeds.astype(jnp.int32),
+                                    positions.astype(jnp.int32),
+                                    passes.astype(jnp.int32))
+            sampled = jnp.argmax(log_probs + gumbel, -1).astype(jnp.int32)
+            kept = jnp.sum(jnp.where(probs >= pmin[:, None], probs, 0.0), -1)
+            conf = jnp.take_along_axis(probs, sampled[:, None], -1)[:, 0] / kept
+            return (jnp.where(temperatures <= 0.0, greedy[0], sampled),
+                    jnp.where(temperatures <= 0.0, greedy[1], conf))
         gumbel = jax.vmap(lambda seed, position: jax.random.gumbel(
             jax.random.fold_in(jax.random.PRNGKey(seed), position),
             (V,), jnp.float32))(seeds.astype(jnp.int32),
