@@ -20,12 +20,16 @@ same all-to-all the reference issues by hand through NCCL.
 :class:`DroplessMoELayer` is the other formulation, for routers whose
 load may not be capped (DeepSeek-V3's sigmoid router): token-expert pairs
 are sorted by expert and every expert held runs over its own contiguous
-rows in ONE grouped product (``jax.lax.ragged_dot``; work proportional to
-``tokens x top_k``), so no token is dropped at any load and no ``[n, E,
-C]`` tensor exists.
+rows in ONE grouped product (work proportional to ``tokens x top_k``), so
+no token is dropped at any load and no ``[n, E, C]`` tensor exists.  The
+product is ``ops/pallas/grouped_matmul.py``'s: the Pallas kernel where
+``pick_tiles`` names it (on a TPU, over the rows an expert its sweep
+measured), ``jax.lax.ragged_dot`` elsewhere.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import math
 
 import paddle_tpu
@@ -39,7 +43,7 @@ __all__ = [
     "BaseGate", "NaiveGate", "GShardGate", "SwitchGate",
     "MoELayer", "StackedExpertFFN", "dispatch_combine",
     "DroplessMoELayer", "dropless_experts", "sigmoid_topk_route",
-    "softmax_topk_route",
+    "softmax_topk_route", "grouped_tally", "experts_path",
 ]
 
 
@@ -358,6 +362,45 @@ def softmax_topk_route(h, gate_w, top_k):
     return jax.nn.softmax(top, axis=-1), idx.astype(jnp.int32)
 
 
+_GROUPED_TALLY = contextvars.ContextVar("grouped_tally", default=None)
+
+
+@contextlib.contextmanager
+def grouped_tally():
+    """Collects, while a program is traced, one entry a grouped product of
+    :func:`dropless_experts`: True where it took the Pallas kernel, False
+    where ``ragged_dot``.  Yields the list."""
+    took = []
+    token = _GROUPED_TALLY.set(took)
+    try:
+        yield took
+    finally:
+        _GROUPED_TALLY.reset(token)
+
+
+def experts_path():
+    """What the grouped expert products are built from, for the serving
+    AOT fingerprint: the kernel at its revision where a kernel may run
+    (which products take it is ``pick_tiles``' rule of the shapes, which
+    the fingerprint covers), else ``ragged_dot``."""
+    from paddle_tpu.ops.pallas import kernel_default
+    from paddle_tpu.ops.pallas.grouped_matmul import GROUPED_MATMUL_REVISION
+    return (f"grouped_matmul/{GROUPED_MATMUL_REVISION}" if kernel_default()
+            else "ragged_dot")
+
+
+def _grouped(rows, w, counts):
+    """One grouped product ``rows [m, K]`` x ``w [G, K, N]`` -> f32, on the
+    path ``pick_tiles`` names for its shape, noted in the tally."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    tiles = gm.pick_tiles(rows.shape[0], w.shape[0], w.shape[1], w.shape[2],
+                          rows.dtype)
+    took = _GROUPED_TALLY.get()
+    if took is not None:
+        took.append(tiles is not None)
+    return gm.grouped_matmul(rows, w, counts, tiles)
+
+
 def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
     """Routed experts without a capacity: ``sum_i weights[:, i] *
     E_idx[:, i](h)`` over the experts HELD, ``E(h) = (silu(h W1) * (h
@@ -369,13 +412,13 @@ def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
     A pair whose expert is held elsewhere adds nothing here (its share of
     the result is another holder's).  The ``n * k`` pairs are sorted by
     expert, so each expert's rows are contiguous and the two grouped
-    products do ``n * k`` rows of work; rows come back to their tokens by
-    the inverse permutation (a gather).  ``share``: the experts held are
-    a share of the router's, so some pairs' rows lie past the last group,
-    where the grouped product writes NOTHING (on a TPU they hold whatever
-    the memory held, NaN included, and a weight of 0 would not clear
-    that): those rows are set to 0.  Returns (out ``[n, d]`` in ``h``'s dtype,
-    tokens per held expert ``[E_held]`` int32)."""
+    products (:func:`_grouped`) do ``n * k`` rows of work; rows come back
+    to their tokens by the inverse permutation (a gather).  ``share``: the
+    experts held are a share of the router's, so some pairs' rows lie past
+    the last group, where the grouped product writes NOTHING (on a TPU
+    they hold whatever the memory held, NaN included, and a weight of 0
+    would not clear that): those rows are set to 0.  Returns (out ``[n,
+    d]`` in ``h``'s dtype, tokens per held expert ``[E_held]`` int32)."""
     import jax
     import jax.numpy as jnp
     n, k = idx.shape
@@ -386,12 +429,10 @@ def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
     order = jnp.argsort(local, stable=True)
     counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
     rows = h[order // k]                                     # [n*k, d]
-    a = jax.lax.ragged_dot(rows, w13, counts,
-                           preferred_element_type=jnp.float32)
+    a = _grouped(rows, w13, counts)
     gate, up = jnp.split(a, 2, -1)
     act = (jax.nn.silu(gate) * up).astype(h.dtype)
-    y = jax.lax.ragged_dot(act, w2, counts,
-                           preferred_element_type=jnp.float32)
+    y = _grouped(act, w2, counts)
     # rows past the held experts' are not this holder's: weight 0
     wts = jnp.where(mine, weights.reshape(-1), 0.0)[order]
     if share:

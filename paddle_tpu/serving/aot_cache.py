@@ -80,7 +80,7 @@ def program_devices(params, mesh=None):
 
 
 def engine_fingerprint(model_config, engine_config, params, mesh=None,
-                       attention="xla"):
+                       attention="xla", experts=None):
     """Hex digest naming the compiled-program family of one engine.
 
     `params` contributes structure (sorted name/shape/dtype) and
@@ -91,7 +91,10 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None,
     — serving/kv_pool.py: ``"xla"`` or the Pallas kernel with its
     revision); with the sampler's revision it is the part of the
     CODE the digest covers, so two trees that differ in either never
-    share an executable.
+    share an executable.  `experts` (a model with expert layers alone:
+    ``distributed.moe.experts_path()``, the grouped product's kernel at
+    its revision or ``ragged_dot``) enters the digest only when given, so
+    the fingerprint of a model without experts is what it was.
     """
     import jaxlib
 
@@ -121,6 +124,8 @@ def engine_fingerprint(model_config, engine_config, params, mesh=None,
         "devices": [(d.platform, d.device_kind)
                     for d in program_devices(params, mesh)],
     }
+    if experts is not None:
+        material["experts"] = str(experts)
     return hashlib.sha256(repr(material).encode()).hexdigest()[:24]
 
 

@@ -204,6 +204,8 @@ class PagedKVContext:
         self.slot = slot
         self._layer = 0
         self.expert_counts = []      # one traced [experts] int32 a layer
+        # one bool a grouped expert product, at trace time: the kernel?
+        self.grouped_products = []
 
     def _next_layer(self):
         li = self._layer
@@ -265,13 +267,16 @@ class PagedKVContext:
         self.expert_counts.append(counts)
 
     def expert_stats(self):
-        """``[experts_hit, expert_tokens_max]`` int32: experts that got
-        at least one token, summed over layers, and the heaviest
-        expert's tokens in the worst layer — padding rows of the batch
-        or bucket included, as the program routed them."""
+        """``[experts_hit, expert_tokens_max, kernel_products, products]``
+        int32: experts that got at least one token, summed over layers,
+        and the heaviest expert's tokens in the worst layer — padding
+        rows of the batch or bucket included, as the program routed them
+        —, then how many of the program's grouped expert products took
+        the Pallas kernel, of how many (constants of the trace)."""
         counts = jnp.stack(self.expert_counts)               # [L, E]
-        return jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(
-            jnp.int32)
+        took = self.grouped_products
+        return jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                          sum(took), len(took)]).astype(jnp.int32)
 
     def attend(self, q, k, v):
         """q/k/v: Tensor [b, s, n_head, head_dim] -> Tensor same shape
@@ -421,7 +426,7 @@ class LLMEngine:
             from paddle_tpu.serving.aot_cache import engine_fingerprint
             self._program_fp = engine_fingerprint(
                 mc, cfg, self._params, self._mesh,
-                attention=self.attention_path)
+                attention=self.attention_path, experts=self.experts_path)
 
         self._compiled = {}
         self._requests = {}          # live (queued or running) only
@@ -536,6 +541,16 @@ class LLMEngine:
         AOT fingerprint's term for it: the Pallas kernel at its
         revision, or the XLA composition (the pool kind's to say)."""
         return self._pool.attention_path + self._gen.path
+
+    @property
+    def experts_path(self):
+        """What a model with expert layers builds its grouped expert
+        products from (``distributed.moe.experts_path()``), the AOT
+        fingerprint's term for them; None for a model without."""
+        if not self._moe_layers:
+            return None
+        from paddle_tpu.distributed.moe import experts_path
+        return experts_path()
 
     @property
     def program_fingerprint(self):
@@ -1015,14 +1030,17 @@ class LLMEngine:
         the tokens the program routed in every expert layer."""
         if not self._moe_layers or self._moe_stats is None:
             return
-        hit, tokens_max = (int(x) for x in self._moe_stats)
+        hit, tokens_max, took, products = (int(x) for x in self._moe_stats)
         self._moe_stats = None
         span_.set(experts_hit=hit, expert_tokens_max=tokens_max)
         # a capture keeps a span's attributes as they were at entry, so
-        # the same numbers also go down as a marker the capture can read
+        # the same numbers also go down as a marker the capture can read;
+        # `grouped_kernel`: the share of the program's grouped products
+        # that took the Pallas kernel
         with span("serving.experts", experts_hit=hit,
                   expert_tokens_max=tokens_max, rows=rows,
-                  layers=self._moe_layers):
+                  layers=self._moe_layers,
+                  grouped_kernel=took / products if products else 0.0):
             pass
         self.metrics.note_experts(
             rows * self._moe_top_k * self._moe_layers, tokens_max,
@@ -1397,9 +1415,11 @@ class LLMEngine:
         try:
             for k, t in sd.items():
                 t._value = params[k]
-            with no_grad():
+            from paddle_tpu.distributed.moe import grouped_tally
+            with no_grad(), grouped_tally() as took:
                 out = self._model(Tensor(ids), position_ids=Tensor(pos_ids),
                                   kv_ctx=ctx, **kw)
+            ctx.grouped_products.extend(took)
             return out._value
         finally:
             for t, v in saved:

@@ -50,6 +50,9 @@ __all__ = ["BlockDiffusion", "NextToken", "make_generation"]
 # part of a block-diffusion engine's AOT fingerprint: bump with any change
 # to what its programs compute round the model
 BLOCK_DIFFUSION_REVISION = 1
+# the expert stats a program of a model with expert layers hands the
+# sampler to carry (``PagedKVContext.expert_stats``)
+EXPERT_STATS = 4
 
 _BLOCK_KNOBS = ("denoising_steps", "remasking", "confidence_threshold")
 
@@ -166,7 +169,7 @@ class NextToken:
             def fn(logits, seeds, pos, temps, top_ks, top_ps, stats):
                 return jnp.concatenate([sample_tokens(
                     logits, seeds, pos, temps, top_ks, top_ps), stats])
-            carry = (jnp.zeros((2,), jnp.int32),)
+            carry = (jnp.zeros((EXPERT_STATS,), jnp.int32),)
         return fn, (
             jnp.zeros((width, V), jnp.float32),
             jnp.zeros((width,), jnp.int32),
@@ -346,7 +349,8 @@ class BlockDiffusion(NextToken):
             return jnp.concatenate([toks, jax.lax.bitcast_convert_type(
                 conf, jnp.int32), *stats])
 
-        carry = (jnp.zeros((2,), jnp.int32),) if eng._moe_layers else ()
+        carry = ((jnp.zeros((EXPERT_STATS,), jnp.int32),)
+                 if eng._moe_layers else ())
         return fn, (
             jnp.zeros((width, V), jnp.float32),
             jnp.zeros((width,), jnp.int32),

@@ -258,6 +258,34 @@ def test_engine_spans_carry_expert_counters(model):
     engine.shutdown()
 
 
+@pytest.mark.parametrize("kernel", [False, True])
+def test_marker_says_which_grouped_products_took_the_kernel(
+        model, monkeypatch, kernel):
+    """``grouped_kernel`` on the marker: the share of the program's grouped
+    expert products that took the Pallas kernel — none on the CPU's
+    path, all where the rule names the kernel (interpreted here); the
+    tokens are the same either way."""
+    from paddle_tpu.observability import spans
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    prompt, sp = [[3, 4, 5, 6]], serving.SamplingParams(max_new_tokens=4,
+                                                        temperature=0.0)
+    plain = _engine(model)
+    want = plain.generate(prompt, sp)[0].output_token_ids
+    plain.shutdown()
+    if kernel:
+        monkeypatch.setattr(gm, "pick_tiles",
+                            lambda rows, groups, k, n, dtype: (16, n))
+    engine = _engine(model)
+    rec = spans.recorder()
+    rec.clear()
+    got = engine.generate(prompt, sp)[0].output_token_ids
+    marks = [r.attrs["grouped_kernel"] for r in rec.spans()
+             if r.name == "serving.experts"]
+    assert marks and set(marks) == {1.0 if kernel else 0.0}
+    assert got == want
+    engine.shutdown()
+
+
 @pytest.mark.parametrize("what", [{"kv_cache_dtype": "int8"},
                                   {"mesh": {"tp": 2}}],
                          ids=["kv_cache_dtype", "mesh"])

@@ -151,3 +151,34 @@ def test_mla_paged_decode_compiles_at_the_cell_size(one_chip):
     assert not [ln for ln in text.splitlines()
                 if "[8193,16,640]" in ln.split(" = ")[-1].split("(")[0]
                 and (" copy(" in ln or " convert(" in ln)]
+
+
+def test_grouped_matmul_compiles_at_the_block_pass_size(one_chip):
+    """The grouped expert product through Mosaic at ``sdar30b_serve_chat``'s
+    block pass (1,024 rows over 128 experts, both products) under the
+    tiles the path rule gives it: each keeps its name, so
+    ``breakdown.device_ops`` and ``tools/idle_gaps.py`` find it, and
+    VMEM holds its tiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                      pick_tiles)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for k, n in ((2048, 1536), (768, 2048)):
+            tiles = pick_tiles(1024, 128, k, n, jnp.bfloat16, kernel=True)
+            text = jax.jit(lambda x, w, c: grouped_matmul(
+                x, w, c, tiles, interpret=False)).lower(
+                    S((1024, k), jnp.bfloat16), S((128, k, n), jnp.bfloat16),
+                    S((128,), jnp.int32)).compile().as_text()
+            calls = _kernel_calls(text)
+            assert [c.split("%")[-1].split(".")[0] for c in calls] == [
+                "grouped_matmul"]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

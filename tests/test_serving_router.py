@@ -163,6 +163,45 @@ class TestAOTProgramCache:
         finally:
             shutil.rmtree(d, ignore_errors=True)
 
+    def test_grouped_products_are_part_of_an_expert_engines_fingerprint(
+            self, tiny_model, monkeypatch, tmp_path):
+        """An engine whose model has expert layers names what its grouped
+        products are built from (``ragged_dot`` here; the kernel at its
+        revision where a kernel may run): two trees that differ in the
+        kernel's revision, or in the path, never share an executable.  A
+        model without experts adds no term: its fingerprint is what it
+        was."""
+        from paddle_tpu import ops
+        from paddle_tpu.distributed import moe
+        from paddle_tpu.ops.pallas import grouped_matmul as gm
+        from tests.test_sdar_moe_model import build, tiny_weights
+        cfg = _cfg()
+        cache = AOTProgramCache(str(tmp_path))
+        sdar = serving.LLMEngine(build(tiny_weights()), cfg,
+                                 program_cache=cache)
+        gpt = serving.LLMEngine(tiny_model, cfg, program_cache=cache)
+        assert sdar.experts_path == "ragged_dot" and gpt.experts_path is None
+
+        def fp(engine, experts):
+            return engine_fingerprint(
+                engine._model.config, cfg, engine._params, None,
+                attention=engine.attention_path, experts=experts)
+
+        assert fp(gpt, None) == gpt.program_fingerprint == engine_fingerprint(
+            tiny_model.config, cfg, gpt._params, None)
+        assert fp(sdar, "ragged_dot") == sdar.program_fingerprint
+        assert fp(sdar, None) != sdar.program_fingerprint
+        monkeypatch.setattr(ops.pallas, "kernel_default", lambda: True)
+        kernel = moe.experts_path()
+        assert kernel == f"grouped_matmul/{gm.GROUPED_MATMUL_REVISION}"
+        monkeypatch.setattr(gm, "GROUPED_MATMUL_REVISION",
+                            gm.GROUPED_MATMUL_REVISION + 1)
+        bumped = moe.experts_path()
+        assert len({fp(sdar, "ragged_dot"), fp(sdar, kernel),
+                    fp(sdar, bumped)}) == 3
+        sdar.shutdown()
+        gpt.shutdown()
+
     def test_corrupt_entry_degrades_to_compile(self, tiny_model):
         """A torn cache entry is a miss, not a crash: the engine
         recompiles and REPLACES the bad file."""

@@ -267,3 +267,24 @@ class TestBlockSparse:
                 q, k, v, bm, block_q=256, block_k=256, interpret=False),
             _sd((b, h, s, d), jnp.float32), _sd((b, h, s, d), jnp.float32),
             _sd((b, h, s, d), jnp.float32))
+
+
+class TestGroupedMatmul:
+    @pytest.mark.parametrize("rows,experts", [
+        ((1024, 2048), (128, 2048, 1536)), ((1024, 768), (128, 768, 2048)),
+        ((192, 2048), (128, 2048, 1536)), ((640, 4096), (36, 4096, 1536))],
+        ids=["sdar_w13", "sdar_w2", "kanana_w13", "granite_w13"])
+    def test_cell_decode_shape_lowers(self, rows, experts):
+        """The expert cells' decode products (SDAR's block pass, Kanana's
+        decode, Granite's decode over 36 held experts) under the tiles
+        the path rule gives them on a TPU."""
+        from paddle_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                          pick_tiles)
+
+        (m, k), (g, _, n) = rows, experts
+        tiles = pick_tiles(m, g, k, n, jnp.bfloat16, kernel=True)
+        assert tiles is not None
+        txt = _lower_tpu(
+            lambda x, w, c: grouped_matmul(x, w, c, tiles, interpret=False),
+            _sd((m, k)), _sd((g, k, n)), _sd((g,), jnp.int32))
+        assert "grouped_matmul" in txt
