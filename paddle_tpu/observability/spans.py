@@ -12,8 +12,9 @@ uses.  It does two things:
   through ``jax.profiler.start_server`` (jax's own
   ``TraceAnnotation.is_enabled()``) — ALSO opens a
   ``jax.profiler.TraceAnnotation`` carrying the span's attributes as the
-  event's stats, so the span lies on the capture's clock next to the XLA
-  device activity.
+  event's stats — those given at entry and those ``set`` before the
+  exit — so the span lies on the capture's clock next to the XLA device
+  activity.
 
 Spans inside a ``to_static``-traced function fire at TRACE time (host
 side), which is exactly when the interesting wall-clock cost (retrace +
@@ -278,9 +279,12 @@ class span:
 
     def set(self, **attrs):
         """Attributes known only at the end (``admitted``, ``hit``):
-        set before the exit, they reach the ring-buffer record; the
-        capture's event keeps what was known at entry."""
+        set before the exit, they reach the ring-buffer record and,
+        under a capture, the open annotation's event as its stats."""
         self.attrs = {**self.attrs, **attrs} if self.attrs else attrs
+        ann = getattr(self, "_ann", None)
+        if ann is not None:
+            ann.set_metadata(**attrs)
 
     def __enter__(self):
         if not _state[0]:
@@ -314,6 +318,7 @@ class span:
         dur = time.perf_counter_ns() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         _tls.depth = self._depth
         trace = ()
         if self._sid is not None:

@@ -906,7 +906,8 @@ class LLMEngine:
         return events
 
     def _step_inner(self, events):
-        self._expire_deadlines(events)
+        with span("serving.gauges"):
+            self._expire_deadlines(events)
         with span("serving.admit"):
             admitted = self._admit(events)
         running = [r for r in self._slots if r is not None]
@@ -921,7 +922,8 @@ class LLMEngine:
                 f"{head.request_id} (prompt {len(head.replay_token_ids)} "
                 f"tokens) cannot be admitted — the page pool "
                 f"({self._alloc.num_free_pages} free) is too small")
-        self._refresh_gauges()
+        with span("serving.gauges"):
+            self._refresh_gauges()
         return admitted
 
     def generate(self, prompts, sampling_params=None):
@@ -1033,10 +1035,10 @@ class LLMEngine:
         hit, tokens_max, took, products = (int(x) for x in self._moe_stats)
         self._moe_stats = None
         span_.set(experts_hit=hit, expert_tokens_max=tokens_max)
-        # a capture keeps a span's attributes as they were at entry, so
-        # the same numbers also go down as a marker the capture can read;
-        # `grouped_kernel`: the share of the program's grouped products
-        # that took the Pallas kernel
+        # the same numbers also go down as a marker, which the benchmark's
+        # readers read (a capture now keeps the span's exit attributes
+        # too); `grouped_kernel`: the share of the program's grouped
+        # products that took the Pallas kernel
         with span("serving.experts", experts_hit=hit,
                   expert_tokens_max=tokens_max, rows=rows,
                   layers=self._moe_layers,
@@ -1058,17 +1060,19 @@ class LLMEngine:
 
         head = stats = ()
         if covered:
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :covered] = tokens[:covered]
-            pos_ids = np.arange(bucket, dtype=np.int32)[None, :]
-            length = np.array([covered], np.int32)
-
             fn = self._get_prefill(bucket)
-            out = fn(
-                self._params, self._k_pools, self._v_pools,
-                self._place(self._tables[slot:slot + 1]), self._place(ids),
-                self._place(pos_ids), self._place(length),
-                *(self._place(x) for x in self._pool.slot_operands(slot)))
+            with span("serving.launch", program="prefill", bucket=bucket):
+                ids = np.zeros((1, bucket), np.int32)
+                ids[0, :covered] = tokens[:covered]
+                pos_ids = np.arange(bucket, dtype=np.int32)[None, :]
+                length = np.array([covered], np.int32)
+                operands = (
+                    self._place(self._tables[slot:slot + 1]),
+                    self._place(ids), self._place(pos_ids),
+                    self._place(length),
+                    *(self._place(x) for x in self._pool.slot_operands(slot)))
+                out = fn(self._params, self._k_pools, self._v_pools,
+                         *operands)
             # what the kind's program puts first, the pools, the stats
             n = self._gen.prefill_heads
             head, stats = out[:n], out[n + 2:]
@@ -1130,6 +1134,54 @@ class LLMEngine:
     def _decode_step_inner(self, events, span_):
         cfg = self.config
         t0 = self.metrics.clock()
+        with span("serving.capacity") as capacity:
+            capacity.set(grown=self._make_room(events))
+
+        live = [(s, r) for s, r in enumerate(self._slots) if r is not None]
+        if not live:
+            return
+        fn = self._get_decode()
+        fault = None
+        with span("serving.launch", program="decode",
+                  width=cfg.max_num_seqs * self._gen.rows):
+            tokens = self._gen.decode_operands(self, live)
+            guard_args = ()
+            if cfg.guard:
+                guard_args = (self._place(self._poison_vector(live)),)
+            try:
+                # chaos hook: `exception` faults here simulate a crashed
+                # decode (payload `request_id` names the offender)
+                _fire("serving.decode", step=self.metrics.decode_steps)
+                operands = (self._place(self._tables),
+                            self._place(self._lens), self._place(tokens),
+                            *guard_args)
+                out = fn(self._params, self._k_pools, self._v_pools,
+                         *operands)
+            except Exception as e:
+                if not cfg.crash_safe_decode:
+                    raise
+                fault = e
+        if fault is not None:
+            self._recover_decode_fault(fault, events)
+            return
+        stats = ()
+        if self._moe_layers:
+            *out, last = out
+            stats = (last,)
+        if cfg.guard:
+            logits, self._k_pools, self._v_pools, flags = out
+            live = self._quarantine_flagged(live, flags, events)
+        else:
+            logits, self._k_pools, self._v_pools = out
+        self._decode_fault_streak = 0
+
+        self._gen.decoded(self, live, logits, stats, span_, t0, events)
+
+    def _make_room(self, events):
+        """The capacity pass before a decode pass; returns the pages it
+        allocated."""
+        cfg = self.config
+        grown = 0
         # chaos hook: injected pool exhaustion drives ONE deterministic
         # preemption round through the REAL victim-selection path (the
         # same code a genuinely dry pool exercises below)
@@ -1166,41 +1218,8 @@ class LLMEngine:
                 continue                       # row preempted itself
             for pos, page in self._alloc.allocate(slot, need):
                 self._tables[slot, pos] = page
-
-        live = [(s, r) for s, r in enumerate(self._slots) if r is not None]
-        if not live:
-            return
-        tokens = self._gen.decode_operands(self, live)
-
-        fn = self._get_decode()
-        guard_args = ()
-        if cfg.guard:
-            guard_args = (self._place(self._poison_vector(live)),)
-        try:
-            # chaos hook: `exception` faults here simulate a crashed
-            # decode (payload `request_id` names the offender)
-            _fire("serving.decode", step=self.metrics.decode_steps)
-            out = fn(
-                self._params, self._k_pools, self._v_pools,
-                self._place(self._tables), self._place(self._lens),
-                self._place(tokens), *guard_args)
-        except Exception as e:
-            if not cfg.crash_safe_decode:
-                raise
-            self._recover_decode_fault(e, events)
-            return
-        stats = ()
-        if self._moe_layers:
-            *out, last = out
-            stats = (last,)
-        if cfg.guard:
-            logits, self._k_pools, self._v_pools, flags = out
-            live = self._quarantine_flagged(live, flags, events)
-        else:
-            logits, self._k_pools, self._v_pools = out
-        self._decode_fault_streak = 0
-
-        self._gen.decoded(self, live, logits, stats, span_, t0, events)
+                grown += 1
+        return grown
 
     def _note_decode(self, t0):
         """A decode pass's counters; returns the stamp its tokens get."""
@@ -1326,10 +1345,14 @@ class LLMEngine:
         self.metrics.sampler_paths[path] += 1
         with span("serving.sample", width=width, path=path):
             fn = self._get_sampler(width)
-            out = np.asarray(fn(
-                self._place(logits), *(self._place(k) for k in keys),
-                self._place(temps), self._place(top_ks),
-                self._place(top_ps), *carry))
+            with span("serving.launch", program="sample", width=width):
+                operands = (self._place(logits),
+                            *(self._place(k) for k in keys),
+                            self._place(temps), self._place(top_ks),
+                            self._place(top_ps))
+                res = fn(*operands, *carry)
+            with span("serving.fetch"):
+                out = np.asarray(res)
         if carry:
             self._moe_stats = out[-len(carry[0]):]
             out = out[:-len(carry[0])]

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.observability import span
 from paddle_tpu.serving.request import RequestState
 from paddle_tpu.serving.sampler import sample_tokens
 
@@ -211,12 +212,13 @@ class NextToken:
         """After a prefill program ran for `req` at `slot`: the first
         token, sampled from the prompt's last logits."""
         tok = eng._sample(head[0], [req], width=1, carry=stats)[0]
-        eng._note_experts(span_, bucket)
-        now = eng.metrics.clock()
-        eng._note_prefill(req, len(tokens), t0, now)
-        eng._deliver(req, [tok], [None], now, events, gap=False)
-        if not req.is_finished:
-            req.transition(RequestState.DECODE)
+        with span("serving.deliver", tokens=1):
+            eng._note_experts(span_, bucket)
+            now = eng.metrics.clock()
+            eng._note_prefill(req, len(tokens), t0, now)
+            eng._deliver(req, [tok], [None], now, events, gap=False)
+            if not req.is_finished:
+                req.transition(RequestState.DECODE)
 
     def decode_operands(self, eng, live):
         """The decode program's per-pass ids ``[slots, rows]``."""
@@ -230,12 +232,13 @@ class NextToken:
         one token a sequence, every length advanced by one."""
         reqs = [eng._slots[s] for s in range(self.slots)]
         toks = eng._sample(logits, reqs, width=self.slots, carry=stats)
-        eng._note_experts(span_, self.slots)
-        for s, r in live:
-            eng._lens[s] += 1
-        now = eng._note_decode(t0)
-        for s, r in live:
-            eng._deliver(r, [toks[s]], [None], now, events)
+        with span("serving.deliver", tokens=len(live)):
+            eng._note_experts(span_, self.slots)
+            for s, r in live:
+                eng._lens[s] += 1
+            now = eng._note_decode(t0)
+            for s, r in live:
+                eng._deliver(r, [toks[s]], [None], now, events)
 
 
 class BlockDiffusion(NextToken):
@@ -425,13 +428,17 @@ class BlockDiffusion(NextToken):
         prompt shorter than a block): no token; the first block opens on
         the prompt's leftover."""
         if stats:
-            eng._moe_stats = np.asarray(stats[0])
-            eng._note_experts(span_, bucket)
-        stored = self.prefill_len(len(tokens))
-        eng._note_prefill(req, len(tokens), t0, eng.metrics.clock(),
-                          ran=stored > 0)
-        self.open_block(slot, tokens[stored:])
-        req.transition(RequestState.DECODE)
+            # the prefill's one blocking fetch: it samples nothing
+            with span("serving.fetch"):
+                eng._moe_stats = np.asarray(stats[0])
+        with span("serving.deliver", tokens=0):
+            if stats:
+                eng._note_experts(span_, bucket)
+            stored = self.prefill_len(len(tokens))
+            eng._note_prefill(req, len(tokens), t0, eng.metrics.clock(),
+                              ran=stored > 0)
+            self.open_block(slot, tokens[stored:])
+            req.transition(RequestState.DECODE)
 
     def decode_operands(self, eng, live):
         return self.ids.copy()
@@ -444,26 +451,29 @@ class BlockDiffusion(NextToken):
         B, m = self.rows, eng.metrics
         toks, conf = eng._sample(logits, list(eng._slots),
                                  width=self.slots * B, carry=stats)
-        eng._note_experts(span_, self.slots * B)
-        now = eng._note_decode(t0)
-        m.decode_forwards_total += len(live)
-        for s, r in live:
-            if not self.masked[s].any():
-                eng._lens[s] += B
-                m.commit_passes_total += 1
-                self.open_block(s)
-                continue
-            fix = self.choose(r.sampling_params, self.masked[s], conf[s],
-                              int(self.passes[s]))
-            self.ids[s, fix] = toks[s, fix]
-            self.masked[s, fix] = False
-            self.fixed_at[s, fix] = self.passes[s]
-            self.passes[s] += 1
-            m.tokens_fixed_total += len(fix)
-            if not self.masked[s].any():
-                g = int(self.given[s])
-                eng._deliver(r, self.ids[s, g:].tolist(),
-                             self.fixed_at[s, g:].tolist(), now, events)
+        with span("serving.deliver") as deliver:
+            before = m.generated_tokens
+            eng._note_experts(span_, self.slots * B)
+            now = eng._note_decode(t0)
+            m.decode_forwards_total += len(live)
+            for s, r in live:
+                if not self.masked[s].any():
+                    eng._lens[s] += B
+                    m.commit_passes_total += 1
+                    self.open_block(s)
+                    continue
+                fix = self.choose(r.sampling_params, self.masked[s],
+                                  conf[s], int(self.passes[s]))
+                self.ids[s, fix] = toks[s, fix]
+                self.masked[s, fix] = False
+                self.fixed_at[s, fix] = self.passes[s]
+                self.passes[s] += 1
+                m.tokens_fixed_total += len(fix)
+                if not self.masked[s].any():
+                    g = int(self.given[s])
+                    eng._deliver(r, self.ids[s, g:].tolist(),
+                                 self.fixed_at[s, g:].tolist(), now, events)
+            deliver.set(tokens=m.generated_tokens - before)
 
 
 def make_generation(model, cfg):

@@ -744,7 +744,7 @@ class TestSpansReachAnyCapture:
         def body():
             with obs.span("capture.probe", request="req-9", bucket=128,
                           tokens=77) as s:
-                s.set(hit=True)          # the record's, not the event's
+                s.set(hit=True)
 
         events = _capture_events(tmp_path, body)
         assert len(events["capture.probe"]) == 1
@@ -753,6 +753,40 @@ class TestSpansReachAnyCapture:
         assert int(stats["bucket"]) == 128 and int(stats["tokens"]) == 77
         assert obs.recorder().spans()[-1].attrs == {
             "request": "req-9", "bucket": 128, "tokens": 77, "hit": True}
+
+    def test_attributes_set_at_exit_reach_the_capture(self, tmp_path):
+        def body():
+            with obs.span("capture.exit-probe", bucket=8) as s:
+                s.set(admitted=2)
+                s.set(tokens=5, admitted=3)      # the last set wins
+            with obs.span("capture.after-exit") as s:
+                pass
+            s.set(late=1)                        # closed: the record's
+
+        events = _capture_events(tmp_path, body)
+        (stats,) = events["capture.exit-probe"]
+        assert int(stats["bucket"]) == 8 and int(stats["tokens"]) == 5
+        assert int(stats["admitted"]) == 3
+        (after,) = events["capture.after-exit"]
+        assert "late" not in after
+
+    def test_an_engines_exit_attributes_reach_the_capture(self, tmp_path):
+        """serving.step's admitted / tokens, serving.decode's experts_hit
+        and serving.deliver's tokens, all known only at exit."""
+        from paddle_tpu import serving
+        engine = _tiny_block_engine()
+        try:
+            events = _capture_events(tmp_path, lambda: engine.generate(
+                [list(range(1, 15))],
+                serving.SamplingParams(max_new_tokens=6, temperature=0.0)))
+        finally:
+            engine.shutdown()
+        steps = events["serving.step"]
+        assert sum(int(s["admitted"]) for s in steps) == 1
+        assert sum(int(s["tokens"]) for s in steps) == 6
+        assert sum(int(d["tokens"]) for d in events["serving.deliver"]) == 6
+        assert all(int(d["experts_hit"]) > 0
+                   for d in events["serving.decode"])
 
     def test_disabled_spans_write_no_event(self, tmp_path):
         def body():
@@ -933,6 +967,113 @@ class TestEngineStepSpans:
                 assert r.parent_id == "router.1"
                 assert r.attrs["request"] == rid
             assert r.parent_id not in step_ids
+
+
+# ============================================ the host's phases of a step
+PHASES = ("serving.capacity", "serving.launch", "serving.fetch",
+          "serving.deliver", "serving.gauges")
+
+
+def _tiny_block_engine():
+    """The tiny block-diffusion engine of test_serving_block_generation."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import serving
+    from tests.test_sdar_moe_model import build, tiny_weights
+    return serving.LLMEngine(
+        build(tiny_weights()),
+        serving.EngineConfig(max_num_seqs=3, page_size=8, max_model_len=64,
+                             dtype=jnp.float32))
+
+
+@pytest.fixture(scope="module", params=["next_token", "block_diffusion"])
+def phase_records(request):
+    """One tiny CPU engine of each generation kind serving three prompts:
+    its ring-buffer records, its own counters and the tokens it
+    delivered."""
+    from paddle_tpu import serving
+    if request.param == "next_token":
+        engine, prompts = _tiny_engine(), [[1, 2, 3], [4, 5, 6, 7], [8, 9]]
+        new = 4
+    else:
+        engine = _tiny_block_engine()
+        prompts = [list(range(1, 15)), list(range(20, 29)), [5, 6, 7]]
+        new = 6
+    rec = obs.recorder()
+    prev_cap = rec.capacity
+    rec.set_capacity(1 << 16)
+    before = rec.total_recorded
+    try:
+        results = engine.generate(
+            prompts, serving.SamplingParams(max_new_tokens=new,
+                                            temperature=0.0))
+        records = rec.spans()[-(rec.total_recorded - before):]
+        m = engine.metrics
+        counts = {"prefill": m.prefill_steps, "decode": m.decode_steps,
+                  "sample": sum(m.sampler_paths.values())}
+        delivered = sum(len(r.output_token_ids) for r in results)
+    finally:
+        rec.set_capacity(prev_cap)
+        engine.shutdown()
+    return records, counts, delivered
+
+
+def _children(records, parent):
+    """Direct children by start; a first call's compile is set-up, not a
+    phase."""
+    return sorted((r for r in records if _inside(r, parent)
+                   and r.depth == parent.depth + 1
+                   and r.name != "serving.compile"),
+                  key=lambda r: r.start_ns)
+
+
+class TestEngineStepPhases:
+    @pytest.mark.parametrize("name", PHASES)
+    def test_every_phase_lies_inside_one_step(self, phase_records, name):
+        records = phase_records[0]
+        steps = [r for r in records if r.name == "serving.step"]
+        found = [r for r in records if r.name == name]
+        assert found
+        for r in found:
+            assert sum(_inside(r, s) for s in steps) == 1, r
+
+    def test_a_decode_steps_phases_come_in_order(self, phase_records):
+        """capacity < launch(decode) < sample{launch(sample) < fetch} <
+        deliver, each a direct child of the pass (launch and fetch of the
+        sample span)."""
+        records = phase_records[0]
+        passes = [r for r in records if r.name == "serving.decode"]
+        assert passes
+        for p in passes:
+            kids = _children(records, p)
+            assert [k.name for k in kids] == [
+                "serving.capacity", "serving.launch", "serving.sample",
+                "serving.deliver"], p
+            assert kids[1].attrs["program"] == "decode"
+            inner = _children(records, kids[2])
+            assert [(k.name, (k.attrs or {}).get("program")) for k in
+                    inner] == [("serving.launch", "sample"),
+                               ("serving.fetch", None)]
+            assert kids[0].attrs["grown"] >= 0
+
+    def test_launches_count_what_the_engine_counts(self, phase_records):
+        records, counts, _ = phase_records
+        launched = {}
+        for r in records:
+            if r.name == "serving.launch":
+                launched[r.attrs["program"]] = launched.get(
+                    r.attrs["program"], 0) + 1
+        assert counts["prefill"] > 0 and counts["decode"] > 0
+        assert launched == counts
+
+    def test_delivery_and_gauges_cover_every_step(self, phase_records):
+        records, _, delivered = phase_records
+        steps = [r for r in records if r.name == "serving.step"]
+        # expiry before admission and the gauges after: two a step
+        assert sum(r.name == "serving.gauges" for r in records) == \
+            2 * len(steps)
+        deliveries = [r for r in records if r.name == "serving.deliver"]
+        assert sum(r.attrs["tokens"] for r in deliveries) == delivered
 
 
 class _TenRows(P.io.Dataset):
