@@ -79,17 +79,18 @@ def program_devices(params, mesh=None):
                   key=lambda d: d.id)
 
 
-def engine_fingerprint(model_config, engine_config, params, mesh=None,
-                       attention="xla", experts=None):
+def engine_fingerprint(model_config, engine_config, params, mesh=None, *,
+                       attention, experts=None):
     """Hex digest naming the compiled-program family of one engine.
 
     `params` contributes structure (sorted name/shape/dtype) and
     placement, never values — weights can be hot-swapped under a
     fingerprint because XLA compiled against their avals.  `attention`
-    names what the decode attention was built from
-    (``LLMEngine.attention_path``, which the engine's page pool states
-    — serving/kv_pool.py: ``"xla"`` or the Pallas kernel with its
-    revision); with the sampler's revision it is the part of the
+    names what the decode program was built from
+    (``LLMEngine.attention_path``: what the engine's page pool states —
+    serving/kv_pool.py: ``"xla"`` or the Pallas kernel with its
+    revision — and what its kind of generation adds, serving/
+    generation.py); with the sampler's revision it is the part of the
     CODE the digest covers, so two trees that differ in either never
     share an executable.  `experts` (a model with expert layers alone:
     ``distributed.moe.experts_path()``, the grouped product's kernel at

@@ -27,6 +27,16 @@ bit-exact on CPU; on TPU a replayed position is computed by the prefill
 program instead of the decode program, so a near-tie in bf16 logits
 could in principle resolve differently across an eviction).
 
+Run-ahead: where the kind of generation allows it (next-token, no
+guard, no state by slot in the pool), a step launches the NEXT decode
+pass — its rows' ids taken on the device from the pass in flight's
+sampler output — before it fetches the tokens of the pass in flight, so
+the host's work of a step (capacity, launches, delivery, admission) runs
+while the device decodes.  Draws are keyed by (seed, position), so the
+tokens are the synchronous loop's; an admission, a fault, an eviction or
+a hand-off first ends the pass in flight (docs/serving.md, "The step
+loop").
+
 Everything host-side here is orchestration over device arrays; the only
 jax entry points are the compiled step programs, so the engine runs
 bit-deterministically on the CPU mesh (``JAX_PLATFORMS=cpu``) and
@@ -55,7 +65,7 @@ from paddle_tpu.quantization.kv_cache import resolve_kv_cache_dtype
 from paddle_tpu.resilience.faultinject import fire as _fire
 from paddle_tpu.resilience.faultinject import note_recovery
 from paddle_tpu.resilience.health import HealthMonitor
-from paddle_tpu.serving.generation import make_generation
+from paddle_tpu.serving.generation import EXPERT_STATS, make_generation
 from paddle_tpu.serving.kv_pool import make_page_pool
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.request import (GenerationResult, Request,
@@ -365,6 +375,15 @@ class LLMEngine:
         self._k_pools, self._v_pools = self._pool.allocate(self._device)
         # the kind of generation, chosen once (serving/generation.py)
         self._gen = make_generation(model, cfg)
+        # a pass launched and not fetched (`generation.Pass`): the kind's
+        # passes run ahead of the host unless the guard reads the host
+        # between them, or the pool keeps a state by slot — a pass writes
+        # it in place, so a hand-off could not take back a row of the pass
+        # in flight; tests reach the synchronous loop by clearing
+        # `_run_ahead`
+        self._run_ahead = (self._gen.runs_ahead and not cfg.guard
+                           and not self._pool.state_layers)
+        self._ahead = None
         self._moe_layers = int(getattr(model, "num_expert_layers", 0))
         self._moe_experts = int(getattr(mc, "n_routed_experts", 0))
         self._moe_top_k = int(getattr(mc, "num_experts_per_tok", 0))
@@ -738,8 +757,10 @@ class LLMEngine:
         the pool geometry the importer validates against.  With
         `release` (default) the request leaves this engine entirely —
         slot, pages and live-table entry — so prefill workers stay
-        empty-handed between handoffs."""
+        empty-handed between handoffs.  A pass in flight is discarded
+        first: what is exported is the state the last step left."""
         self._gen.check_handoff()
+        self._drain("handoff")
         req = self._requests.get(request_id)
         if req is None or req.slot is None:
             raise ValueError(
@@ -908,8 +929,18 @@ class LLMEngine:
     def _step_inner(self, events):
         with span("serving.gauges"):
             self._expire_deadlines(events)
+        self._ahead = self._in_flight()
         with span("serving.admit"):
             admitted = self._admit(events)
+        # the prefills' first-token fetch waited out the pass in flight.
+        # Where slots are short (none left free, or requests still
+        # waiting) its tokens come now and the step launches afresh, so
+        # the admitted rows' second token comes this step, as in the
+        # synchronous loop, and their slots free no later; else they join
+        # the pass launched ahead, a step later, and the step stays short
+        if admitted and (self.scheduler.has_waiting()
+                         or not self._free_slot_count()):
+            self._drain("prefill", events)
         running = [r for r in self._slots if r is not None]
         if running:
             self._decode_step(events)
@@ -967,7 +998,9 @@ class LLMEngine:
     def shutdown(self):
         """Unregister from the profiler metrics registry and release
         this engine's claim on its registry-owned instruments (shared
-        instruments survive until the last same-named engine goes)."""
+        instruments survive until the last same-named engine goes).  A
+        pass in flight is discarded."""
+        self._drain("idle")
         from paddle_tpu.observability.metrics import registry
         registry().unregister_source(self._metrics_name,
                                      expected=self._snapshot_fn)
@@ -1117,53 +1150,106 @@ class LLMEngine:
 
     # -------------------------------------------------------- decode
     def _decode_step(self, events):
-        # pages_live: the pages this step's attention has to read, from
-        # the host-side lengths (before the capacity pass evicts anyone)
-        page = self.config.page_size
-        pages_live = sum(-(-(int(self._lens[s]) + self._gen.rows) // page)
-                         for s, r in enumerate(self._slots)
-                         if r is not None)
-        self.metrics.pages_live = pages_live
         with span("serving.decode", live=self.num_running,
-                  pages_live=pages_live,
                   kernel=self._pool.decode_kernel,
                   **self._pool.decode_attrs(self.num_running),
                   **self._gen.decode_attrs(self)) as span_:
             self._decode_step_inner(events, span_)
 
     def _decode_step_inner(self, events, span_):
-        cfg = self.config
+        """One pass's handling: the pass in flight — or, with none, one
+        launched now — gets a successor launched behind it (its rows' ids
+        taken on the device from this pass's sampler output) before its
+        own tokens are fetched and delivered.  A kind that cannot run
+        ahead, or a pass whose successor cannot be launched yet, is
+        fetched with none behind it: the next step launches afresh."""
         t0 = self.metrics.clock()
-        with span("serving.capacity") as capacity:
-            capacity.set(grown=self._make_room(events))
+        done, self._ahead = self._ahead, None
+        launched = []
+        if done is None:
+            done = self._launch(events)
+            if done is None:
+                return
+            launched.append(done)
+        if not self._run_ahead:
+            self.metrics.drains["kind"] += 1
+        else:
+            self._ahead = self._launch(events, after=done)
+            if self._ahead is not None:
+                launched.append(self._ahead)
+                self.metrics.passes_ahead += 1
+        # `pages_live`: the pages the passes launched here read
+        self.metrics.pages_live = sum(p.pages for p in launched)
+        span_.set(pages_live=self.metrics.pages_live,
+                  ahead=int(self._ahead is not None))
+        self._gen.decoded(self, done, span_, t0, events)
 
-        live = [(s, r) for s, r in enumerate(self._slots) if r is not None]
+    def _launch(self, events, after=None):
+        """The capacity pass and the launch of one decode pass over the
+        live slots (and, for a kind that samples at launch, its sampler);
+        returns the `generation.Pass`, or None when none was launched.
+
+        `after`: the pass in flight.  Each row it still serves is one
+        position longer than the host knows and takes its id from
+        `after`'s sampler output; a row whose token in `after` is its last
+        is left out.  Such a pass is not launched — `after` is then
+        fetched with none behind it, and the cause counted — where it
+        would run over no row, where the next step admits a request (its
+        prefill would wait out the pass, and its first pass would come a
+        step late), where the pool would have to evict, or where a fault
+        fires."""
+        cfg = self.config
+        B = cfg.max_num_seqs
+        ahead = np.zeros((B,), np.int32)
+        if after is not None:
+            for s, r, ev in after.rows:
+                ahead[s] = self._serving(s, r, ev)
+        last = [s for s, r in enumerate(self._slots) if ahead[s] and len(
+            r.output_token_ids) + 1 >= r.sampling_params.max_new_tokens]
+        live = [(s, r) for s, r in enumerate(self._slots)
+                if r is not None and s not in last]
+        if after is not None and (not live or self._admission_due(len(last))):
+            self.metrics.drains["prefill" if live else "idle"] += 1
+            return None
         if not live:
-            return
+            return None
+        with span("serving.capacity") as capacity:
+            grown = self._make_room(events, live, ahead, after is not None)
+            capacity.set(grown=grown or 0)
+        if grown is None:
+            self.metrics.drains["evict"] += 1
+            return None
+        live = [(s, r) for s, r in live if self._slots[s] is r]
+        if not live:
+            return None
+        rows = self._gen.rows
+        in_pass = np.zeros((B,), np.bool_)
+        in_pass[[s for s, _r in live]] = True
+        lens = np.where(in_pass, self._lens + ahead, 0).astype(np.int32)
+        pages = int(np.sum(-(-(lens[in_pass] + rows) // cfg.page_size)))
         fn = self._get_decode()
         fault = None
-        with span("serving.launch", program="decode",
-                  width=cfg.max_num_seqs * self._gen.rows):
-            tokens = self._gen.decode_operands(self, live)
-            guard_args = ()
+        with span("serving.launch", program="decode", width=B * rows):
+            operands = self._gen.decode_operands(self, live, ahead, after)
             if cfg.guard:
-                guard_args = (self._place(self._poison_vector(live)),)
+                operands += (self._place(self._poison_vector(live)),)
             try:
                 # chaos hook: `exception` faults here simulate a crashed
                 # decode (payload `request_id` names the offender)
                 _fire("serving.decode", step=self.metrics.decode_steps)
-                operands = (self._place(self._tables),
-                            self._place(self._lens), self._place(tokens),
-                            *guard_args)
                 out = fn(self._params, self._k_pools, self._v_pools,
-                         *operands)
+                         self._place(np.where(in_pass[:, None],
+                                              self._tables, 0)),
+                         self._place(lens), *operands)
             except Exception as e:
                 if not cfg.crash_safe_decode:
                     raise
                 fault = e
         if fault is not None:
             self._recover_decode_fault(fault, events)
-            return
+            if after is not None:
+                self.metrics.drains["fault"] += 1
+            return None
         stats = ()
         if self._moe_layers:
             *out, last = out
@@ -1174,12 +1260,55 @@ class LLMEngine:
         else:
             logits, self._k_pools, self._v_pools = out
         self._decode_fault_streak = 0
+        return self._gen.launched(self, live, ahead, logits, stats, pages)
 
-        self._gen.decoded(self, live, logits, stats, span_, t0, events)
+    def _admission_due(self, ending):
+        """Whether the next step admits the queue's head, into a slot free
+        now or one of `ending` slots whose request ends with the pass in
+        flight."""
+        head = self.scheduler.peek()
+        return head is not None and self.scheduler.admissible(
+            head, self._free_slot_count() + ending,
+            self._alloc.num_free_pages)
 
-    def _make_room(self, events):
-        """The capacity pass before a decode pass; returns the pages it
-        allocated."""
+    def _serving(self, slot, req, evictions):
+        """Whether `slot` still serves `req` as it did when a pass was
+        launched over it (not finished, expired or evicted since)."""
+        return self._slots[slot] is req and req.num_evictions == evictions
+
+    def _in_flight(self):
+        """The pass in flight, or None: one none of whose rows is served
+        any more (each finished or expired) is dropped unfetched."""
+        done = self._ahead
+        if done is not None and not any(self._serving(s, r, ev)
+                                        for s, r, ev in done.rows):
+            self.metrics.drains["idle"] += 1
+            done = None
+        return done
+
+    def _drain(self, cause, events=None):
+        """End the pass in flight, so that the next is launched afresh:
+        fetch it and deliver its tokens to `events` (an admission), or,
+        outside a step (a hand-off, shutdown), discard it unfetched — the
+        engine's state is then the one the last step left, and the next
+        pass computes those tokens again (draws keyed by (seed, position),
+        over the same K/V; a pool with a state by slot does not run
+        ahead)."""
+        done, self._ahead = self._in_flight(), None
+        if done is None:
+            return
+        self.metrics.drains[cause] += 1
+        if events is not None:
+            with span("serving.drain", cause=cause) as span_:
+                self._gen.decoded(self, done, span_, self.metrics.clock(),
+                                  events)
+
+    def _make_room(self, events, live, ahead, waits):
+        """The capacity pass before a decode pass over `live` ``[(slot,
+        request)]``, each row ``ahead[slot]`` positions longer than the
+        host's length; returns the pages it allocated.  With `waits` (a
+        pass launched ahead) it evicts nobody for want of pages: None
+        where the pool would have to, or where a chaos plan evicted."""
         cfg = self.config
         grown = 0
         # chaos hook: injected pool exhaustion drives ONE deterministic
@@ -1195,16 +1324,22 @@ class LLMEngine:
                 self._evict(victim, events)
                 note_recovery("serving.pool", "pool_exhaust",
                               victim=victim.request_id)
-        # capacity pass: every live row must fit what this pass writes
-        # (one more token, or its in-flight block); the pool running dry
-        # preempts the latest-arrived running request
-        for slot in range(cfg.max_num_seqs):
-            req = self._slots[slot]
-            if req is None:
-                continue
-            need = self._alloc.pages_needed(
-                int(self._lens[slot]) + self._gen.rows, cfg.page_size)
-            while not self._alloc.can_allocate(slot, need):
+            if waits:
+                return None
+        # every live row must fit what this pass writes (one more token,
+        # or its in-flight block)
+        need = {s: self._alloc.pages_needed(
+            int(self._lens[s] + ahead[s]) + self._gen.rows, cfg.page_size)
+            for s, _r in live}
+        # the pool running dry preempts the latest-arrived running request
+        # (a pass launched ahead leaves that to the next synchronous pass,
+        # which needs the pages granted here too)
+        for slot, req in live:
+            if self._slots[slot] is not req:
+                continue                       # preempted meanwhile
+            while not self._alloc.can_allocate(slot, need[slot]):
+                if waits:
+                    return None
                 victim = self.scheduler.select_victim(
                     [r for r in self._slots if r is not None])
                 if victim is None:
@@ -1214,9 +1349,9 @@ class LLMEngine:
                 self._evict(victim, events)
                 if victim is req:
                     break
-            if self._slots[slot] is None:
+            if self._slots[slot] is not req:
                 continue                       # row preempted itself
-            for pos, page in self._alloc.allocate(slot, need):
+            for pos, page in self._alloc.allocate(slot, need[slot]):
                 self._tables[slot, pos] = page
                 grown += 1
         return grown
@@ -1323,20 +1458,21 @@ class LLMEngine:
                       exc=type(exc).__name__)
 
     # ------------------------------------------------------ sampling
-    def _sample(self, logits, reqs, width, carry=()):
+    def _sample(self, logits, reqs, width, carry=(), **kw):
         """The sampler over `logits`, one Request (or None: a padding
         row, a dead slot) a row or slot; the generation kind says what a
         row's key is and what comes back."""
-        return self._gen.sample(self, logits, reqs, width, carry)
+        return self._gen.sample(self, logits, reqs, width, carry, **kw)
 
     def _run_sampler(self, width, logits, keys, temps, top_ks, top_ps,
-                     carry=()):
+                     carry=(), fetch=True):
         """One call of the ``sample/<width>`` program and its ONE blocking
         fetch.  `keys`: the per-row operands a draw's key is made of, as
         the generation kind orders them (seeds, positions, ...).  `carry`:
         the expert stats of the program that made `logits` (a model with
         expert layers), which ride the fetch into ``_moe_stats``.  Returns
-        the fetched array less the stats."""
+        the fetched array less the stats; without `fetch`, the program's
+        output as it stands on the device (:meth:`_fetch` reads it)."""
         # the searches this call's program will run: it branches on the
         # same three facts of the same operands
         draws, any_k, any_p = sampler_path(temps, top_ks, top_ps)
@@ -1351,11 +1487,19 @@ class LLMEngine:
                             self._place(temps), self._place(top_ks),
                             self._place(top_ps))
                 res = fn(*operands, *carry)
-            with span("serving.fetch"):
-                out = np.asarray(res)
+            if not fetch:
+                return res
+            return self._fetch(res, carry)
+
+    def _fetch(self, res, carry):
+        """The blocking fetch of a sampler's output `res`: the array less
+        the expert stats it carries (with `carry`), which go to
+        ``_moe_stats``."""
+        with span("serving.fetch"):
+            out = np.asarray(res)
         if carry:
-            self._moe_stats = out[-len(carry[0]):]
-            out = out[:-len(carry[0])]
+            self._moe_stats = out[-EXPERT_STATS:]
+            out = out[:-EXPERT_STATS]
         return out
 
     # ------------------------------------------------- finish / evict

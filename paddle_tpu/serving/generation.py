@@ -11,7 +11,10 @@ the object where the kinds differ — it holds no branch on the kind.
 
 - :class:`NextToken` — one token a sequence a step: a prefill samples the
   first token from the prompt's last logits, a decode step feeds ``[slots,
-  1]`` ids and advances every length by one.
+  1]`` ids and advances every length by one.  Its passes RUN AHEAD: a pass
+  takes its rows' ids from the previous pass's sampler output on the device
+  (an id the host leaves at ``-1``), so the engine launches pass n+1 before
+  it fetches pass n's tokens.
 - :class:`BlockDiffusion` — generation by diffusion over blocks of ``B``
   positions (``models/sdar_moe.py``; the family's
   ``block_diffusion_generate``).  A prefill stores the prompt's ``L // B``
@@ -46,10 +49,12 @@ from paddle_tpu.observability import span
 from paddle_tpu.serving.request import RequestState
 from paddle_tpu.serving.sampler import sample_tokens
 
-__all__ = ["BlockDiffusion", "NextToken", "make_generation"]
+__all__ = ["BlockDiffusion", "NextToken", "Pass", "make_generation"]
 
-# part of a block-diffusion engine's AOT fingerprint: bump with any change
-# to what its programs compute round the model
+# part of an engine's AOT fingerprint: bump with any change to what the
+# kind's programs compute round the model
+NEXT_TOKEN_REVISION = 1     # 1: the decode pass takes the previous sampler's
+#                             output (run-ahead)
 BLOCK_DIFFUSION_REVISION = 1
 # the expert stats a program of a model with expert layers hands the
 # sampler to carry (``PagedKVContext.expert_stats``)
@@ -58,17 +63,44 @@ EXPERT_STATS = 4
 _BLOCK_KNOBS = ("denoising_steps", "remasking", "confidence_threshold")
 
 
+class Pass:
+    """A decode pass on the device, launched and not yet fetched.
+
+    - `rows`: ``[(slot, request, its evictions at launch)]`` — a row is
+      delivered only if the slot still serves that request, never evicted
+      since (``LLMEngine._serving``);
+    - `logits`, `stats`: what the decode program returned (its expert
+      stats: ``()`` for a model without expert layers);
+    - `tokens`: the sampler's unfetched output, for a kind that samples at
+      launch (else None);
+    - `pages`: the pages its attention reads (``serving.decode``'s
+      ``pages_live``)."""
+
+    __slots__ = ("rows", "logits", "stats", "tokens", "pages")
+
+    def __init__(self, rows, logits, stats, tokens, pages):
+        self.rows = rows
+        self.logits = logits
+        self.stats = stats
+        self.tokens = tokens
+        self.pages = pages
+
+
 class NextToken:
-    """One token a sequence a step."""
+    """One token a sequence a step; a pass can run ahead of the host."""
 
     kind = "next_token"
     rows = 1            # positions a slot feeds a decode pass
-    path = ""           # what the kind adds to the AOT fingerprint
+    # what the kind adds to the AOT fingerprint
+    path = f"+next_token/{NEXT_TOKEN_REVISION}"
     block_length = 0    # `EngineMetrics.block_length` (0: no blocks)
     prefill_heads = 1   # outputs a prefill program puts before the pools
+    # a pass's ids can be the previous pass's sampler output on the device
+    runs_ahead = True
 
     def __init__(self, cfg):
         self.slots = cfg.max_num_seqs
+        self._no_prev = None    # the placed zeros of a pass with no prev
 
     # ------------------------------------------------------ requests
     def check_params(self, sp):
@@ -152,14 +184,25 @@ class NextToken:
                 jnp.zeros((cfg.max_num_seqs, 1), jnp.float32)), (1, 2), \
                 eng._guarded_out_shardings()
 
-        def decode(params, k_pools, v_pools, tables, lens, tokens):
+        def decode(params, k_pools, v_pools, tables, lens, tokens, prev):
+            # a row the host left at -1 takes the token the previous
+            # pass's sampler drew for its slot (`prev`: that sampler's
+            # output, expert stats and all), on the device
+            tokens = jnp.where(tokens < 0, prev[:tokens.shape[0], None],
+                               tokens)
             ctx = eng._kv_context(k_pools, v_pools, tables, lens, "decode")
             logits = eng._run_model(params, tokens, lens[:, None], ctx)
             return (logits[:, 0].astype(jnp.float32),
                     ctx.k_pools, ctx.v_pools) + eng._expert_stats(ctx)
 
-        return decode, eng._decode_example(self.rows), (1, 2), \
-            eng._step_out_shardings()
+        return decode, (*eng._decode_example(self.rows),
+                        jnp.zeros((self._sampled(eng),), jnp.int32)), \
+            (1, 2), eng._step_out_shardings()
+
+    def _sampled(self, eng):
+        """Length of the decode sampler's output: a token a slot, then the
+        carried expert stats."""
+        return self.slots + (EXPERT_STATS if eng._moe_layers else 0)
 
     def sampler_program(self, eng, width):
         V = int(eng._model.config.vocab_size)
@@ -181,13 +224,17 @@ class NextToken:
             (eng._repl_sharding if eng._mesh is not None else None)
 
     # ------------------------------------------------------- sampling
-    def sample(self, eng, logits, reqs, width, carry=()):
+    def sample(self, eng, logits, reqs, width, carry=(), ahead=None,
+               fetch=True):
         """reqs: per-row Request or None (padding rows).  Position is
         the ABSOLUTE index of the token being sampled = the row's cache
         length AFTER its input token was appended — which is exactly
-        `total_len` host-side.  `carry`: the expert stats of the program
-        that made `logits` (a model with expert layers), which ride this
-        step's one blocking fetch into the engine's ``_moe_stats``."""
+        `total_len` host-side, plus `ahead[i]` (1: the row's input token is
+        still on the device, in the previous pass's output).  `carry`: the
+        expert stats of the program that made `logits` (a model with
+        expert layers), which ride the blocking fetch into the engine's
+        ``_moe_stats``.  Without `fetch`, the sampler's unfetched
+        output."""
         seeds = np.zeros((width,), np.int32)
         pos = np.zeros((width,), np.int32)
         temps = np.zeros((width,), np.float32)
@@ -202,9 +249,11 @@ class NextToken:
             temps[i] = sp.temperature
             top_ks[i] = sp.top_k
             top_ps[i] = sp.top_p
+        if ahead is not None:
+            pos += ahead
         out = eng._run_sampler(width, logits, (seeds, pos), temps, top_ks,
-                               top_ps, carry)
-        return [int(t) for t in out[:width]]
+                               top_ps, carry, fetch=fetch)
+        return [int(t) for t in out[:width]] if fetch else out
 
     # ------------------------------------------------ a step's outcome
     def admitted(self, eng, req, slot, tokens, head, stats, span_, bucket,
@@ -220,25 +269,47 @@ class NextToken:
             if not req.is_finished:
                 req.transition(RequestState.DECODE)
 
-    def decode_operands(self, eng, live):
-        """The decode program's per-pass ids ``[slots, rows]``."""
+    def decode_operands(self, eng, live, ahead, after):
+        """The decode program's per-pass operands after the lengths: the
+        ids ``[slots, rows]`` — ``-1`` where a row's last token is still
+        on the device (``ahead``), in pass `after`'s sampler output — and
+        that output (zeros when no pass is in flight); a guarded engine's
+        program takes the ids alone."""
         tokens = np.zeros((self.slots, 1), np.int32)
         for s, r in live:
-            tokens[s, 0] = r.output_token_ids[-1]
-        return tokens
+            tokens[s, 0] = -1 if ahead[s] else r.output_token_ids[-1]
+        if eng.config.guard:
+            return (eng._place(tokens),)
+        if after is not None:
+            return eng._place(tokens), after.tokens
+        if self._no_prev is None:
+            self._no_prev = eng._place(np.zeros((self._sampled(eng),),
+                                                np.int32))
+        return eng._place(tokens), self._no_prev
 
-    def decoded(self, eng, live, logits, stats, span_, t0, events):
-        """After the decode program ran over `live` ``[(slot, request)]``:
-        one token a sequence, every length advanced by one."""
-        reqs = [eng._slots[s] for s in range(self.slots)]
-        toks = eng._sample(logits, reqs, width=self.slots, carry=stats)
+    def launched(self, eng, live, ahead, logits, stats, pages):
+        """After the decode program was called over `live` ``[(slot,
+        request)]``: the sampler is launched behind it, unfetched."""
+        reqs = [None] * self.slots
+        for s, r in live:
+            reqs[s] = r
+        out = eng._sample(logits, reqs, width=self.slots, carry=stats,
+                          ahead=ahead, fetch=False)
+        return Pass([(s, r, r.num_evictions) for s, r in live], None,
+                    stats, out, pages)
+
+    def decoded(self, eng, done, span_, t0, events):
+        """Pass `done`'s tokens fetched: one token a sequence it still
+        serves, every such length advanced by one."""
+        toks = eng._fetch(done.tokens, done.stats)
+        live = [(s, r) for s, r, ev in done.rows if eng._serving(s, r, ev)]
         with span("serving.deliver", tokens=len(live)):
             eng._note_experts(span_, self.slots)
             for s, r in live:
                 eng._lens[s] += 1
             now = eng._note_decode(t0)
             for s, r in live:
-                eng._deliver(r, [toks[s]], [None], now, events)
+                eng._deliver(r, [int(toks[s])], [None], now, events)
 
 
 class BlockDiffusion(NextToken):
@@ -252,6 +323,10 @@ class BlockDiffusion(NextToken):
 
     kind = "block_diffusion"
     prefill_heads = 0
+    # the next pass's ids come from the host's unmasking rule on the
+    # fetched confidences: a pass cannot be launched before the last one's
+    # fetch
+    runs_ahead = False
 
     def __init__(self, cfg, spec):
         super().__init__(cfg)
@@ -440,17 +515,22 @@ class BlockDiffusion(NextToken):
             self.open_block(slot, tokens[stored:])
             req.transition(RequestState.DECODE)
 
-    def decode_operands(self, eng, live):
-        return self.ids.copy()
+    def decode_operands(self, eng, live, ahead, after):
+        return (eng._place(self.ids.copy()),)
 
-    def decoded(self, eng, live, logits, stats, span_, t0, events):
-        """After a pass over `live`: a slot whose block held no mask has
-        committed it — its length moves by ``B`` and the next block opens;
-        any other slot fixes what its rule says, and delivers the block
-        when no mask is left."""
+    def launched(self, eng, live, ahead, logits, stats, pages):
+        return Pass([(s, r, r.num_evictions) for s, r in live], logits,
+                    stats, None, pages)
+
+    def decoded(self, eng, done, span_, t0, events):
+        """After a pass over its live slots: a slot whose block held no
+        mask has committed it — its length moves by ``B`` and the next
+        block opens; any other slot fixes what its rule says, and delivers
+        the block when no mask is left."""
         B, m = self.rows, eng.metrics
-        toks, conf = eng._sample(logits, list(eng._slots),
-                                 width=self.slots * B, carry=stats)
+        live = [(s, r) for s, r, _ev in done.rows]
+        toks, conf = eng._sample(done.logits, list(eng._slots),
+                                 width=self.slots * B, carry=done.stats)
         with span("serving.deliver") as deliver:
             before = m.generated_tokens
             eng._note_experts(span_, self.slots * B)

@@ -25,6 +25,13 @@ from paddle_tpu.observability.metrics import (Histogram, _label_key,
 
 __all__ = ["Histogram", "EngineMetrics"]
 
+# why a decode pass in flight was fetched with none launched behind it:
+# an admission due at the next step (or one whose prefill waited it out),
+# a decode fault, a pass that would have had to evict, a page hand-off, no
+# row left for the next pass (or shutdown), a kind of generation, the
+# guard or a pool with a state by slot, which read the host between passes
+DRAIN_CAUSES = ("prefill", "fault", "evict", "handoff", "idle", "kind")
+
 # Live-instance count per label set.  Two engines created with the same
 # explicit `metrics_name` SHARE registry instruments (same (name,
 # labels) key — Prometheus semantics), so the instruments may only be
@@ -167,6 +174,11 @@ class EngineMetrics:
         self.decode_forwards_total = 0   # live slots summed over passes
         self.commit_passes_total = 0     # passes that stored a final block
         self.tokens_fixed_total = 0      # positions fixed by denoising
+        # run-ahead: decode passes launched while the previous pass's
+        # tokens were still on the device, and the times a pass in flight
+        # was fetched with none behind it, by cause (DRAIN_CAUSES)
+        self.passes_ahead = 0
+        self.drains = collections.Counter()
 
     def note_experts(self, pairs, tokens_max, mean_load):
         """One program's routing: `pairs` token-expert pairs over all
@@ -282,4 +294,8 @@ class EngineMetrics:
             "prefill_step_ms": self.prefill_step_s.summary(),
             "decode_step_ms": self.decode_step_s.summary(),
             "sampler_paths": dict(self.sampler_paths),
+            "run_ahead": {
+                "passes_ahead": self.passes_ahead,
+                "drains": {c: self.drains[c] for c in DRAIN_CAUSES},
+            },
         }

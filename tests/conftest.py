@@ -30,6 +30,18 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.slow)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _global_mesh_stays_in_its_file():
+    # a file that installs a global mesh (`init_mesh`, or code that calls
+    # `ensure_mesh`) and leaves it keeps it to itself: under xdist the
+    # next file on the same worker would otherwise trace every model
+    # under it — which file that is depends on the placement
+    from paddle_tpu.distributed import mesh
+    found = mesh.get_mesh()
+    yield
+    mesh.set_mesh(found)
+
+
 @pytest.fixture(autouse=True)
 def _seed():
     import paddle_tpu as P

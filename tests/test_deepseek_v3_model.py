@@ -191,18 +191,21 @@ def _engine(model, **kw):
 
 class _LogitTap:
     """Keeps the logits every sampler call saw, by request and position
-    (the engine compares logits nowhere else)."""
+    (the engine compares logits nowhere else; a row whose last token is
+    still on the device, ``ahead``, samples one position further)."""
 
     def __init__(self, engine):
         self.rows = {}
         inner = engine._sample
 
-        def tapped(logits, reqs, width, carry=()):
+        def tapped(logits, reqs, width, carry=(), **kw):
             arr = np.asarray(logits)
+            ahead = kw.get("ahead")
             for i, r in enumerate(reqs):
                 if r is not None:
-                    self.rows[(r.request_id, r.total_len)] = arr[i]
-            return inner(logits, reqs, width, carry)
+                    pos = r.total_len + (0 if ahead is None else ahead[i])
+                    self.rows[(r.request_id, int(pos))] = arr[i]
+            return inner(logits, reqs, width, carry, **kw)
 
         engine._sample = tapped
 
@@ -337,5 +340,5 @@ def test_pool_accounting_follows_the_declaration(model):
     assert engine.kv_bytes_per_token == TINY["num_hidden_layers"] * width * 4
     assert engine.hbm_budget_bytes == (engine.params_bytes
                                        + 2 * engine.kv_pool_bytes + (64 << 20))
-    assert engine.attention_path == "latent/xla"
+    assert engine.attention_path == "latent/xla+next_token/1"
     engine.shutdown()

@@ -185,18 +185,21 @@ def kernel_engines(tiny_model, monkeypatch, tmp_path):
 
 def test_fingerprint_names_the_attention_path(tiny_model, kernel_engines):
     xla, kern = kernel_engines
-    assert xla.attention_path == "xla"
-    assert kern.attention_path == f"paged_decode/{PAGED_DECODE_REVISION}"
+    assert xla.attention_path == "xla+next_token/1"
+    assert kern.attention_path == \
+        f"paged_decode/{PAGED_DECODE_REVISION}+next_token/1"
     assert xla._k_pools[0].ndim == 4 and kern._k_pools[0].ndim == 3
     assert xla.program_fingerprint != kern.program_fingerprint
     args = (tiny_model.config, _cfg(), xla._params, None)
-    # stable within a path, and the default is the XLA composition's
-    assert engine_fingerprint(*args) == xla.program_fingerprint
-    assert engine_fingerprint(*args, attention="xla") \
+    # stable within a path
+    assert engine_fingerprint(*args, attention=xla.attention_path) \
+        == xla.program_fingerprint
+    assert engine_fingerprint(*args, attention="xla+next_token/1") \
         == xla.program_fingerprint
     assert engine_fingerprint(*args, attention=kern.attention_path) \
         == kern.program_fingerprint
-    assert engine_fingerprint(*args, attention="paged_decode/0") \
+    assert engine_fingerprint(
+        *args, attention="paged_decode/0+next_token/1") \
         != kern.program_fingerprint
 
 
@@ -245,8 +248,9 @@ def test_decode_span_carries_pages_live_and_kernel(kernel_engines, which):
     assert spans
     assert all(s.attrs["kernel"] is (which == "kernel") for s in spans)
     # page 8: a 3-token and a 19-token prompt decode at lengths 3 and 19
-    # first — ceil(4/8) + ceil(20/8) pages
+    # first — ceil(4/8) + ceil(20/8) pages — and the pass launched ahead
+    # of it in the same span at 4 and 20: as many again
     assert spans[0].attrs["live"] == 2
-    assert spans[0].attrs["pages_live"] == 1 + 3
+    assert spans[0].attrs["pages_live"] == (1 + 3) + (1 + 3)
     assert engine.metrics.snapshot()["pages"]["live"] \
         == spans[-1].attrs["pages_live"]

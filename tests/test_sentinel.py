@@ -756,7 +756,8 @@ class TestServingGuard:
                                        max_model_len=32,
                                        prefill_buckets=(8,),
                                        guard=guard)
-            fps.add(engine_fingerprint(mcfg, cfg, params))
+            fps.add(engine_fingerprint(mcfg, cfg, params,
+                                       attention="xla"))
         assert len(fps) == 2   # guarded programs are their own family
 
 

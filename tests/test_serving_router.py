@@ -130,7 +130,8 @@ class TestAOTProgramCache:
                                program_cache=cache_dir)
         assert e1.program_fingerprint != e2.program_fingerprint
         fp1 = engine_fingerprint(tiny_model.config, _cfg(),
-                                 e1._params, None)
+                                 e1._params, None,
+                                 attention=e1.attention_path)
         assert fp1 == e1.program_fingerprint
         e1.shutdown()
         e2.shutdown()
@@ -188,7 +189,8 @@ class TestAOTProgramCache:
                 attention=engine.attention_path, experts=experts)
 
         assert fp(gpt, None) == gpt.program_fingerprint == engine_fingerprint(
-            tiny_model.config, cfg, gpt._params, None)
+            tiny_model.config, cfg, gpt._params, None,
+            attention="xla+next_token/1")
         assert fp(sdar, "ragged_dot") == sdar.program_fingerprint
         assert fp(sdar, None) != sdar.program_fingerprint
         monkeypatch.setattr(ops.pallas, "kernel_default", lambda: True)

@@ -252,7 +252,8 @@ def test_pool_accounting_follows_the_declaration(model):
     assert engine.kv_pool_bytes == kv + state
     assert engine.hbm_budget_bytes == (engine.params_bytes
                                        + 2 * (kv + state) + (64 << 20))
-    assert engine.attention_path == "kv:xla/row_pages+state:xla/float32"
+    assert engine.attention_path == \
+        "kv:xla/row_pages+state:xla/float32+next_token/1"
     # row pages: a token's 2 x 16 K values are one row
     assert engine._k_pools[2].shape == (cfg.num_pages, cfg.page_size, 32)
     assert engine._v_pools[0].dtype == jnp.float32

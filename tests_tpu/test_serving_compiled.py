@@ -158,7 +158,7 @@ def test_decode_program_reads_no_whole_pool(monkeypatch):
     engine.shutdown()
 
     xla = _cell_engine(monkeypatch, kernel=False)
-    assert xla.attention_path == "xla"
+    assert xla.attention_path == "xla+next_token/1"
     found = set(_WHOLE_POOL.findall(xla._get_decode().as_text()))
     assert found == {"copy", "convert", "fusion"}
     xla.shutdown()
