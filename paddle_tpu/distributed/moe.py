@@ -401,10 +401,11 @@ def _grouped(rows, w, counts):
     return gm.grouped_matmul(rows, w, counts, tiles)
 
 
-def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
+def dropless_experts(h, weights, idx, w13, w2, first=0, share=False,
+                     act="silu"):
     """Routed experts without a capacity: ``sum_i weights[:, i] *
-    E_idx[:, i](h)`` over the experts HELD, ``E(h) = (silu(h W1) * (h
-    W3)) W2``.
+    E_idx[:, i](h)`` over the experts HELD, ``E(h) = (act(h W1) * (h
+    W3)) W2`` — ``act`` ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).
 
     ``h [n, d]``; ``weights`` / ``idx [n, k]`` from a router over ALL
     experts; ``w13 [E_held, d, 2f]`` (``[W1 | W3]``) and ``w2 [E_held, f,
@@ -431,8 +432,8 @@ def dropless_experts(h, weights, idx, w13, w2, first=0, share=False):
     rows = h[order // k]                                     # [n*k, d]
     a = _grouped(rows, w13, counts)
     gate, up = jnp.split(a, 2, -1)
-    act = (jax.nn.silu(gate) * up).astype(h.dtype)
-    y = _grouped(act, w2, counts)
+    gate = jax.nn.relu(gate) if act == "relu" else jax.nn.silu(gate)
+    y = _grouped((gate * up).astype(h.dtype), w2, counts)
     # rows past the held experts' are not this holder's: weight 0
     wts = jnp.where(mine, weights.reshape(-1), 0.0)[order]
     if share:
@@ -449,7 +450,8 @@ class DroplessMoELayer(nn.Layer):
     Kanana-2, :func:`sigmoid_topk_route`, with the selection bias
     ``gate_bias``; equations in docs/serving.md "Latent pool and dropless
     experts") or ``"softmax"`` (Granite, :func:`softmax_topk_route`: no
-    bias, no scaling).
+    bias, no scaling).  ``act`` is the gate's activation of every routed
+    expert: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU, SmallThinker's).
 
     The layer is told which contiguous range of experts it holds
     (``held = (first, count)``, default all): it routes over all
@@ -458,18 +460,25 @@ class DroplessMoELayer(nn.Layer):
     every holder's alike (``shared=False`` leaves them to one holder).
     Stacked leaves: ``w13 [count, d, 2f]``, ``w2 [count, f, d]``.
 
-    ``forward`` returns the layer's output; the tokens each held expert
-    got in that call are kept in ``last_counts`` (a traced ``[count]``
-    int32 inside a compiled program) for whoever counts the routing.
+    ``forward(x, route_from=None)`` returns the layer's output; the
+    router reads ``route_from`` where it is given (a model whose router
+    sits before another block, as SmallThinker's before attention) and
+    ``x`` otherwise, the experts always read ``x``.  The tokens each held
+    expert got in that call are kept in ``last_counts`` (a traced
+    ``[count]`` int32 inside a compiled program) for whoever counts the
+    routing.
     """
 
     def __init__(self, d_model, d_expert, num_experts, top_k, n_shared=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True, held=None,
                  shared=True, initializer_range=0.02, make_parameter=None,
-                 route="sigmoid"):
+                 route="sigmoid", act="silu"):
         super().__init__()
         if route not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown router {route!r}")
+        if act not in ("silu", "relu"):
+            raise ValueError(f"unknown expert activation {act!r}")
+        self.act = act
         self.d_model, self.num_experts, self.top_k = d_model, num_experts, top_k
         self.scale, self.norm_topk_prob = routed_scaling_factor, norm_topk_prob
         self.route = route
@@ -493,7 +502,7 @@ class DroplessMoELayer(nn.Layer):
             self.shared_w13 = make([d_model, 2 * n_shared * d_expert], std)
             self.shared_w2 = make([n_shared * d_expert, d_model], std)
 
-    def forward(self, x):
+    def forward(self, x, route_from=None):
         import jax
         import jax.numpy as jnp
 
@@ -509,12 +518,18 @@ class DroplessMoELayer(nn.Layer):
             def route(h, gw):
                 return softmax_topk_route(h, gw, self.top_k)
 
+        source = () if route_from is None else (route_from,)
+
         def fn(v, *leaves):
             h = v.reshape(-1, v.shape[-1])
-            weights, idx = route(h, *leaves[:len(router)])
+            r = h
+            if source:
+                r, *leaves = leaves
+                r = r.reshape(-1, r.shape[-1])
+            weights, idx = route(r, *leaves[:len(router)])
             w13, w2, *shared = leaves[len(router):]
             out, counts = dropless_experts(h, weights, idx, w13, w2,
-                                           self.first, self.share)
+                                           self.first, self.share, self.act)
             if shared:
                 a = jnp.matmul(h, shared[0],
                                preferred_element_type=jnp.float32)
@@ -526,6 +541,7 @@ class DroplessMoELayer(nn.Layer):
 
         shared = ((self.shared_w13, self.shared_w2) if self.has_shared
                   else ())
-        out, counts = apply(fn, x, *router, self.w13, self.w2, *shared)
+        out, counts = apply(fn, x, *source, *router, self.w13, self.w2,
+                            *shared)
         self.last_counts = counts
         return out

@@ -291,19 +291,23 @@ def paged_prefill_append(k_new, v_new, k_pages, v_pages, tables, lens,
     return k_pages, v_pages
 
 
-def grouped_causal_attention(q, k, v, scale, block=1):
+def grouped_causal_attention(q, k, v, scale, block=1, window=None):
     """``q [b, s, H, d]`` over ``k`` / ``v [b, s, H_kv, d]``, query head
     ``i`` reading K/V head ``i // (H / H_kv)``; ``scale`` multiplies the
     scores; causal, float32 softmax, both contractions accumulated wide.
     ``block`` > 1 makes the mask causal over BLOCKS of that many positions:
     ``i`` sees ``j`` iff ``j // block <= i // block`` (its whole block and
-    every earlier one)."""
+    every earlier one).  ``window``: ``i`` sees ``j`` iff ``i - window < j
+    <= i`` (a sliding window; with ``block`` 1 only)."""
     b, s, H, d = q.shape
     g = H // k.shape[2]
     qg = q.reshape(b, s, k.shape[2], g, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * scale
-    if block == 1:
+    if window is not None:
+        gap = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        seen = (gap >= 0) & (gap < window)
+    elif block == 1:
         seen = jnp.tril(jnp.ones((s, s), jnp.bool_))
     else:
         at = jnp.arange(s) // block

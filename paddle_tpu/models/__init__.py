@@ -50,3 +50,7 @@ from paddle_tpu.models.sdar_moe import (  # noqa: F401
     SdarMoeConfig,
     SdarMoeForCausalLM,
 )
+from paddle_tpu.models.smallthinker import (  # noqa: F401
+    SmallThinkerConfig,
+    SmallThinkerForCausalLM,
+)

@@ -13,6 +13,14 @@ the diagonal:
 - tiles the diagonal or a ragged key edge crosses take the MASKED body;
 - every other tile takes the unmasked body (no iota, compare or select).
 
+A causal forward may also be given a WINDOW `W` (query `i` sees key `j`
+iff `i - W < j <= i`): key tiles wholly before the band's lower edge are
+not run and their K/V DMA is elided as the diagonal's are, and the tiles
+that edge crosses take the masked body.  The backward refuses a window
+(no caller trains through one).  K/V with fewer heads than Q (grouped
+queries: query head `i` reads K/V head `i // (H / H_kv)`) are read in
+place through the index map, never repeated to `H` heads.
+
 `_pick_blocks` chooses that schedule from what the kernel can see
 (lengths, head size, dtype; causal moves no choice) and is the only place
 the choice is made; its docstring says on which shape each choice was
@@ -65,6 +73,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
+
+# what the serving AOT fingerprint names the kernel by where a serving
+# program runs it (serving/kv_pool.py): bump on a change to what it computes
+# or how it tiles
+FLASH_ATTENTION_REVISION = "fa-w1"
 
 # the tile a caller gets who names only one of its two blocks, and ring
 # attention's; tuned at 16k / d128 causal, where it stays (`_pick_blocks`)
@@ -215,6 +228,12 @@ def _least(a, b):
     return jnp.minimum(a, b)
 
 
+def _most(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
+
+
 def _key_tiles(row0, first, sched, causal, seq_k):
     """For the query tile that starts at `row0`, of the `span` key tiles a
     grid step holds from tile `first` on: local tiles [0, free) need no
@@ -226,6 +245,29 @@ def _key_tiles(row0, first, sched, causal, seq_k):
         run = _least(run, (row0 + bq + bk - 1) // bk)
         free = _least(free, (row0 + 1) // bk)
     return _clip(free - first, span), _clip(run - first, span)
+
+
+def _window_tiles(row0, first, sched, window):
+    """For the query tile that starts at `row0` under a window of `window`
+    keys, of the `span` key tiles from tile `first` on: local tiles before
+    `lo` lie wholly before the band's lower edge (not run), [lo, clear) are
+    crossed by it (masked), from `clear` on it masks nothing."""
+    bq, bk, span, _ = sched
+    lo = (row0 - window + 1) // bk
+    clear = (row0 + bq - window + bk - 1) // bk
+    return _clip(lo - first, span), _clip(clear - first, span)
+
+
+def _ranges(free, run, edge):
+    """The `_walk` ranges of a query tile's key tiles: [0, free) unmasked,
+    [free, run) masked — and with a window's `edge = (lo, clear)`, tiles
+    before `lo` not run and [lo, clear) masked too."""
+    if edge is None:
+        return (0, free, False), (free, run, True)
+    lo, clear = edge
+    low = _least(clear, run)
+    mid = _most(low, free)
+    return (lo, low, True), (low, mid, False), (mid, run, True)
 
 
 def _query_tiles(col0, first, sched, causal, seq_q):
@@ -269,16 +311,19 @@ def _tile_slice(j, size):
     return pl.ds(pl.multiple_of(j * size, size), size)
 
 
-def _keep(shape, key_axis, row0, col0, causal, seq_k):
+def _keep(shape, key_axis, row0, col0, causal, seq_k, window=None):
     """bool `shape`: the scores that a tile whose first query is `row0` and
-    first key `col0` keeps — a key at or before its query (causal), and
-    before the ragged edge `seq_k` (None: the length is block-aligned, the
-    compare is not traced).  None where there is nothing to mask."""
+    first key `col0` keeps — a key at or before its query (causal), less
+    than `window` keys before it (a window), and before the ragged edge
+    `seq_k` (None: the length is block-aligned, the compare is not
+    traced).  None where there is nothing to mask."""
     key = jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
     keep = None
     if causal:
         query = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - key_axis)
         keep = key - query <= row0 - col0
+    if window is not None:
+        keep = jnp.logical_and(keep, key - query > row0 - col0 - window)
     if seq_k is not None:
         edge = key < seq_k - col0
         keep = edge if keep is None else jnp.logical_and(keep, edge)
@@ -307,7 +352,7 @@ def _scores(q, k, scale, keep):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
-                sched, seq_k, grid):
+                sched, seq_k, grid, window=None):
     """Key-major, the tile is `k q^T` `[bk, bq]`: the softmax's max and sum
     run down the sublanes (whole-vreg work), m, l and lse are lane-dense
     rows `[1, bq]`, and the accumulator is `v^T p^T` `[d, bq]`, turned
@@ -318,6 +363,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
     qi, ki = _axis(1, nq), _axis(2, nk)
     row0 = qi * bq
     free, run = _key_tiles(row0, ki * span, sched, causal, seq_k)
+    band = (None if window is None
+            else _window_tiles(row0, ki * span, sched, window))
     edge = seq_k if seq_k % bk else None      # the ragged key edge, if any
     key_axis = 0 if key_major else 1
 
@@ -325,7 +372,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
         keys = _tile_slice(j, bk)
         q, k, v = q_ref[0], k_ref[0, keys, :], v_ref[0, keys, :]
         keep = _keep((bk, bq) if key_major else (bq, bk), key_axis, row0,
-                     (ki * span + j) * bk, causal, edge) if masked else None
+                     (ki * span + j) * bk, causal, edge,
+                     window) if masked else None
         s = (_scores(k, q, scale, keep) if key_major
              else _scores(q, k, scale, keep))                 # f32
         # softmax statistics stay fp32, in the log2 domain
@@ -354,7 +402,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
     if not scratch:
         # one tile is the whole row of tiles and which body it takes is
         # known at trace time: the state never leaves values
-        finalize(*tile(0, run > free, None, None, None))
+        finalize(*tile(0, run > free or band is not None, None, None, None))
         return
     acc_ref, m_ref, l_ref = scratch
 
@@ -376,7 +424,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, causal,
     def done():
         finalize(stat(m_ref), stat(l_ref), acc_ref[...])
 
-    _walk(ki, nk, span, init, step, done, (0, free, False), (free, run, True))
+    _walk(ki, nk, span, init, step, done, *_ranges(free, run, band))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -476,9 +524,10 @@ def _schedule_of(q, k, block_q, block_k):
     return _walks(sched, sq, sk)
 
 
-def _flash_bhsd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_bhsd(q, k, v, causal, scale, block_q, block_k, interpret,
+                window=None):
     return _flash_block(q, k, v, causal, scale, block_q, block_k,
-                        interpret)[0]
+                        interpret, window)[0]
 
 
 # a function that holds `pallas_call` sites is traced and lowered once for
@@ -487,32 +536,46 @@ _once_a_shape = functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret"))
 
 
-@_once_a_shape
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    groups = h // k.shape[1]        # query heads a K/V head serves
     sched, _ = _schedule_of(q, k, block_q, block_k)
     bq, bk, span, key_major = sched
     bh = b * h
     qp = _pad_to(q, bq, 2).reshape(bh, -1, d)
-    kp = _pad_to(k, span * bk, 2).reshape(bh, -1, d)
-    vp = _pad_to(v, span * bk, 2).reshape(bh, -1, d)
+    kp = _pad_to(k, span * bk, 2).reshape(bh // groups, -1, d)
+    vp = _pad_to(v, span * bk, 2).reshape(bh // groups, -1, d)
     sqp, skp = qp.shape[1], kp.shape[1]
 
     grid = (bh, sqp // bq, skp // (span * bk))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, sched=sched, seq_k=sk,
-        grid=grid)
+        grid=grid, window=window)
     # causal: clamp the k index map to the diagonal so the skipped
     # above-diagonal steps re-map to an already-resident block and Pallas
     # elides their K/V DMA entirely (a step that runs no tile skips
-    # compute, not the prefetch)
-    if causal:
+    # compute, not the prefetch); a window clamps it from below to the
+    # band's first block the same way
+    # grouped queries: a (batch, query head) row reads its K/V head's row
+    def kv_row(g):
+        return g if groups == 1 else g // groups
+
+    if window is not None:
         def kv_index(g, qi, ki):
-            return (g, jnp.minimum(ki, (qi * bq + bq - 1) // (span * bk)), 0)
+            lo = jnp.maximum(qi * bq - window + 1, 0) // (span * bk)
+            hi = (qi * bq + bq - 1) // (span * bk)
+            return (kv_row(g), jnp.clip(ki, lo, hi), 0)
+    elif causal:
+        def kv_index(g, qi, ki):
+            return (kv_row(g),
+                    jnp.minimum(ki, (qi * bq + bq - 1) // (span * bk)), 0)
     else:
         def kv_index(g, qi, ki):
-            return (g, ki, 0)
+            return (kv_row(g), ki, 0)
     if key_major:
         # lse as rows, a query tile each
         lse_shape, lse_block = (bh, sqp // bq, 1, bq), (1, 1, 1, bq)
@@ -554,25 +617,34 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return o, lse.reshape(b, h, sqp)[:, :, :sq]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_block(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_block(q, k, v, causal, scale, block_q, block_k, interpret,
+                 window=None):
     """Flash attention returning (o, lse); differentiable in both (ring
-    attention merges blocks by their lse)."""
-    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+    attention merges blocks by their lse) — without a window and with as
+    many K/V heads as query heads."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window=window)
 
 
-def _flash_block_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_block_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                     window=None):
     # through _flash_block, not the raw _flash_fwd: under nested
     # differentiation (recompute's backward takes a vjp of a region whose
     # ops each took their own) the outer trace then meets a custom_vjp
     # call it can linearize, never a raw pallas_call it would have to JVP
     o, lse = _flash_block(q, k, v, causal, scale, block_q, block_k,
-                          interpret)
+                          interpret, window)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_block_bwd(causal, scale, block_q, block_k, interpret, res, cts):
+def _flash_block_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                     cts):
     q, k, v, o, lse = res
+    if window is not None or k.shape[1] != q.shape[1]:
+        raise NotImplementedError(
+            "flash attention backward with a window or grouped K/V heads: "
+            "no caller trains through either (docs/serving.md)")
     do, dlse = cts
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                                    # [b,h,sq]
@@ -698,10 +770,15 @@ def _on_tpu():
 
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None, block_q=None,
-                         block_k=None, interpret=None):
+                         block_k=None, interpret=None, window=None):
     """Flash attention on [batch, seq, heads, head_dim] inputs (paddle
-    layout). Differentiable (custom VJP). Raises on CPU unless
-    `interpret=True` — callers pick the XLA sdpa path there."""
+    layout). Differentiable (custom VJP) without a window and with as many
+    K/V heads as query heads.  `window` (causal only): query `i` sees keys
+    `i - window < j <= i`.  K/V may have fewer heads than Q, a divisor of
+    them (grouped queries).  Raises on CPU unless `interpret=True` —
+    callers pick the XLA sdpa path there."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal: pass causal=True")
     if interpret is None:
         interpret = False
         if not _on_tpu():
@@ -714,7 +791,7 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, block_q=None,
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     o = _flash_bhsd(qt, kt, vt, bool(causal), scale, block_q, block_k,
-                    bool(interpret))
+                    bool(interpret), None if window is None else int(window))
     return jnp.transpose(o, (0, 2, 1, 3))
 
 

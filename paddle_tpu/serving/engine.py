@@ -292,8 +292,7 @@ class PagedKVContext:
         """q/k/v: Tensor [b, s, n_head, head_dim] -> Tensor same shape
         (attention output); writes this layer's K/V into its pools."""
         li = self._next_layer()
-        step = (self.pool.prefill if self.mode == "prefill"
-                else self.pool.decode)
+        step = self.pool.layer_step(li, self.mode, self.slot)
 
         def fn(qv, kv, vv):
             out, self.k_pools[li], self.v_pools[li] = step(
@@ -422,6 +421,8 @@ class LLMEngine:
         self.metrics.compile_bound = cfg.compile_bound
         self.metrics.pages_total = cfg.num_pages - 1   # page 0 reserved
         self.metrics.state_pool_bytes = self._pool.state_nbytes
+        if self._pool.window_layers:
+            self.metrics.window_pool_bytes = self._pool.window_nbytes
         self.metrics.block_length = self._gen.block_length
         # health state machine over live page-pool occupancy; the gauge
         # is EngineMetrics-owned so its registry lifecycle matches
@@ -1182,6 +1183,12 @@ class LLMEngine:
         self.metrics.pages_live = sum(p.pages for p in launched)
         span_.set(pages_live=self.metrics.pages_live,
                   ahead=int(self._ahead is not None))
+        if self._pool.window_layers:
+            # `window_rows_live`: the ring rows of one window layer the
+            # passes launched here read
+            self.metrics.window_rows_live = sum(p.window_rows
+                                                for p in launched)
+            span_.set(window_rows_live=self.metrics.window_rows_live)
         self._gen.decoded(self, done, span_, t0, events)
 
     def _launch(self, events, after=None):
@@ -1260,7 +1267,9 @@ class LLMEngine:
         else:
             logits, self._k_pools, self._v_pools = out
         self._decode_fault_streak = 0
-        return self._gen.launched(self, live, ahead, logits, stats, pages)
+        done = self._gen.launched(self, live, ahead, logits, stats, pages)
+        done.window_rows = self._pool.live_rows(lens[in_pass])
+        return done
 
     def _admission_due(self, ending):
         """Whether the next step admits the queue's head, into a slot free

@@ -74,9 +74,12 @@ class Pass:
     - `tokens`: the sampler's unfetched output, for a kind that samples at
       launch (else None);
     - `pages`: the pages its attention reads (``serving.decode``'s
-      ``pages_live``)."""
+      ``pages_live``);
+    - `window_rows`: the ring rows one window layer of its pool reads
+      (``window_rows_live``; 0 for a pool without window layers)."""
 
-    __slots__ = ("rows", "logits", "stats", "tokens", "pages")
+    __slots__ = ("rows", "logits", "stats", "tokens", "pages",
+                 "window_rows")
 
     def __init__(self, rows, logits, stats, tokens, pages):
         self.rows = rows
@@ -84,6 +87,7 @@ class Pass:
         self.stats = stats
         self.tokens = tokens
         self.pages = pages
+        self.window_rows = 0
 
 
 class NextToken:

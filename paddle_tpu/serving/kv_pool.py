@@ -20,13 +20,17 @@ programs, and asks the pool object for everything about the format.
   elsewhere; wire block ``rows``.  Refuses a mesh and a narrow dtype.
 - :class:`LayeredPool` — a kind PER LAYER: a :class:`GroupedKV` for the
   layers that declare ``kv`` (row pages at their own K/V head count,
-  grouped queries reading through the XLA composition) and a
+  grouped queries reading through the XLA composition), a
   :class:`SlotState` for those that declare ``state`` — a recurrent
   layer's state, indexed by SLOT and not by page (``[max_num_seqs,
   ...]``: the convolution's window at the engine's dtype, the
   state-space matrix in float32), overwritten by the prefill that
   admits a request and advanced in place by decode; wire blocks
-  ``conv`` / ``ssm``.  Refuses a mesh and a narrow dtype.
+  ``conv`` / ``ssm`` — and a :class:`WindowKV` for those that declare
+  ``window``: a sliding-window layer's K and V of a slot's last ``W``
+  positions, in a ring indexed by SLOT (``[max_num_seqs, W, H_kv,
+  d]``, position ``p`` at row ``p % W``), no pages and no hand-off.
+  Refuses a mesh and a narrow dtype.
 
 docs/serving.md "The page pool" has the table.  The step functions (the
 mathematics) stay in :mod:`paddle_tpu.incubate.nn.paged_attention` and
@@ -51,6 +55,8 @@ from paddle_tpu.incubate.nn.paged_attention import (grouped_causal_attention,
                                                     paged_decode_step,
                                                     paged_prefill_append,
                                                     row_pages_default)
+from paddle_tpu.ops.pallas.flash_attention import (FLASH_ATTENTION_REVISION,
+                                                   flash_attention_bshd)
 from paddle_tpu.ops.pallas.mla_paged_attention import \
     MLA_PAGED_DECODE_REVISION
 from paddle_tpu.ops.pallas.paged_attention import (PAGED_DECODE_REVISION,
@@ -61,7 +67,8 @@ from paddle_tpu.quantization.kv_cache import (quantized_decode_step,
                                               resolve_kv_cache_dtype)
 
 __all__ = ["GroupedKV", "LatentPool", "LayeredPool", "PagePool",
-           "PlainKV", "QuantizedKV", "SlotState", "make_page_pool"]
+           "PlainKV", "QuantizedKV", "SlotState", "WindowKV",
+           "make_page_pool"]
 
 
 def _dense_causal_attention(q, k, v):
@@ -103,6 +110,7 @@ class PagePool:
     decode_kernel = False
     state_layers = 0          # layers cached by slot (SlotState)
     state_nbytes = 0
+    window_layers = 0         # layers cached in a ring by slot (WindowKV)
 
     def __init__(self, cfg, num_layers, mesh=None, spec=None):
         self.page_size = cfg.page_size
@@ -150,6 +158,18 @@ class PagePool:
         """Traced guard column ``[B]`` bool: a page scale gone bad on a
         page a slot uses.  Only a quantized kind has scales."""
         return jnp.zeros(tables.shape[0], jnp.bool_)
+
+    def live_rows(self, lens):
+        """Ring rows of one window layer a decode pass over slots at
+        `lens` reads (a kind without window layers: 0)."""
+        return 0
+
+    def layer_step(self, li, mode, slot=None):
+        """The traced write-and-read ``(q, k, v, kp, vp, tables, lens) ->
+        (out, kp, vp)`` of layer `li` in a program of `mode` ("prefill" |
+        "decode"); `slot` is a prefill's admitted slot, for a kind that
+        caches by slot."""
+        return self.prefill if mode == "prefill" else self.decode
 
     # ------------------------------------------- what a slot adds
     def slot_operands(self, slot):
@@ -311,10 +331,20 @@ class GroupedKV(PlainKV):
         self.attention_path = "xla/row_pages"
         if self.causal_block > 1:
             self.geometry["causal_block"] = self.causal_block
+        # a causal prefill reads through the Pallas flash kernel where it
+        # runs (on a TPU, in a program no mesh partitions): no [s, s]
+        # score table; a block-causal one keeps the XLA composition
+        self.flash = self.causal_block == 1 and _flash_prefill()
+        if self.flash:
+            self.attention_path += f"+prefill:{FLASH_ATTENTION_REVISION}"
 
     def prefill(self, q, k, v, kp, vp, tables, lens):
-        out = grouped_causal_attention(q, k, v, self.scale,
-                                       block=self.causal_block)
+        if self.flash:
+            out = flash_attention_bshd(q, k, v, causal=True,
+                                       scale=self.scale)
+        else:
+            out = grouped_causal_attention(q, k, v, self.scale,
+                                           block=self.causal_block)
         kp, vp = self._append(jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
                               kp, vp, tables, lens, self.page_size)
         return out, kp, vp
@@ -468,25 +498,124 @@ class SlotState:
                     ssm, ssm_new.astype(ssm.dtype), at, axis=0))
 
 
+class WindowKV:
+    """A sliding-window layer's cache: K and V of a slot's last ``window``
+    positions in a RING indexed by slot — ``[max_num_seqs, window, H_kv,
+    d]`` at the engine's dtype, position ``p`` at row ``p % window`` — read
+    by ``query_heads`` query heads at the model's ``scale``.  No pages: a
+    slot's ring is its own whatever its length.
+
+    A prefill attends over the prompt under the window (the Pallas flash
+    kernel on a TPU, :func:`grouped_causal_attention` elsewhere) and writes
+    the prompt's last ``min(len, window)`` rows.  Decode writes row ``len %
+    window``, then reads the slot's ring masked to the positions ``(len -
+    window, len]``: row ``r`` is read only once ``len >= r`` or ``len >=
+    window``, so a row a slot's earlier request left is never read, and a
+    pass that is launched and discarded overwrites only the row of the
+    position that falls out of every later query's window."""
+
+    def __init__(self, cfg, spec):
+        heads, dim = int(spec["num_heads"]), int(spec["head_dim"])
+        self.window = int(spec["window"])
+        self.num_heads = heads
+        self.groups = int(spec.get("query_heads", heads)) // heads
+        self.scale = float(spec.get("scale", dim ** -0.5))
+        self.struct = jax.ShapeDtypeStruct(
+            (cfg.max_num_seqs, self.window, heads, dim), cfg.dtype)
+        self.flash = _flash_prefill()
+        self.attention_path = f"window/{self.window}:xla/ring" + (
+            f"+prefill:{FLASH_ATTENTION_REVISION}" if self.flash else "")
+        self.geometry = {"window": self.window, "window_heads": heads,
+                         "window_head_dim": dim}
+
+    def prefill(self, q, k, v, kr, vr, tables, lens, slot):
+        """``q [1, s, H, d]`` / ``k``, ``v [1, s, H_kv, d]`` of a padded
+        prompt of ``lens[0]`` tokens admitted at ``slot [1]``: windowed
+        causal attention, and the slot's ring rewritten — row ``r`` gets
+        the latest position ``p < len`` with ``p % window == r`` (the
+        first row's position where there is none: such a row is not read
+        before decode writes it)."""
+        if self.flash:
+            out = flash_attention_bshd(q, k, v, causal=True,
+                                       scale=self.scale, window=self.window)
+        else:
+            out = grouped_causal_attention(q, k, v, self.scale,
+                                           window=self.window)
+        n = lens.astype(jnp.int32)[0]
+        r = jnp.arange(self.window, dtype=jnp.int32)
+        held = n - 1 - jnp.remainder(n - 1 - r, self.window)
+        take = jnp.clip(held, 0, k.shape[1] - 1)
+        at = slot.astype(jnp.int32)[0]
+        kr = jax.lax.dynamic_update_slice_in_dim(
+            kr, k[:, take].astype(kr.dtype), at, axis=0)
+        vr = jax.lax.dynamic_update_slice_in_dim(
+            vr, v[:, take].astype(vr.dtype), at, axis=0)
+        return out, kr, vr
+
+    def decode(self, q, k, v, kr, vr, tables, lens):
+        """``q [slots, 1, H, d]``: each slot's new K/V row written at row
+        ``len % window`` (in place), then its query heads attend over its
+        ring, masked to the positions ``(len - window, len]``; float32
+        softmax, both contractions accumulated wide."""
+        if q.shape[1] > 1:
+            raise NotImplementedError("a window layer decodes one position "
+                                      "a slot (no kind of generation by "
+                                      "blocks)")
+        b, _, H, d = q.shape
+        hk, W = self.num_heads, self.window
+        lens = lens.astype(jnp.int32)
+        rows = jnp.arange(b)
+        kr = kr.at[rows, lens % W].set(k[:, 0].astype(kr.dtype))
+        vr = vr.at[rows, lens % W].set(v[:, 0].astype(vr.dtype))
+        scores = jnp.einsum(
+            "bhgd,bkhd->bhgk", q.reshape(b, hk, self.groups, d), kr,
+            preferred_element_type=jnp.float32) * self.scale
+        row = jnp.arange(W)[None, :]
+        live = (row <= lens[:, None]) | (lens[:, None] >= W)
+        scores = jnp.where(live[:, None, None, :], scores,
+                           jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bhgk,bkhd->bhgd", probs, vr,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, 1, H, d).astype(q.dtype), kr, vr
+
+    def live_rows(self, lens):
+        """Rows a decode pass over slots at ``lens`` (host ints, the
+        positions stored before the pass) reads in ONE window layer."""
+        return int(np.sum(np.minimum(np.asarray(lens) + 1, self.window)))
+
+
+def _flash_prefill():
+    """Whether a causal prefill runs the flash kernel here: on a TPU, in a
+    program no mesh partitions (``kernel_default``)."""
+    from paddle_tpu.ops.pallas import kernel_default
+    return kernel_default()
+
+
 class LayeredPool(PagePool):
     """A cache kind PER LAYER (``{"kind": "layers", "layers": [...]}``):
     one :class:`GroupedKV` for the layers declaring ``kv`` (one geometry:
-    ``num_heads x head_dim``, optionally ``query_heads`` and ``scale``)
-    and one :class:`SlotState` for those declaring ``state``.  A layer's
-    entry is a pair in the engine's two lists either way: (K pages, V
-    pages) or (conv, ssm).  The pool is plain and on one device."""
+    ``num_heads x head_dim``, optionally ``query_heads`` and ``scale``),
+    one :class:`SlotState` for those declaring
+    ``state`` and one :class:`WindowKV` for those declaring ``window``
+    (``num_heads``, ``head_dim``, ``window``, optionally ``query_heads``
+    and ``scale``) — each kind with its own geometry.  A layer's entry is
+    a pair in the engine's two lists whatever its kind: (K pages, V
+    pages), (conv, ssm) or (K ring, V ring).  The pool is plain and on one
+    device."""
 
     kind = "layers"
 
     def __init__(self, cfg, layers, mesh=None):
         self.kinds = [layer["kind"] for layer in layers]
-        unknown = set(self.kinds) - {"kv", "state"}
+        unknown = set(self.kinds) - {"kv", "state", "window"}
         if unknown:
             raise ValueError(f"unknown kv cache kind {sorted(unknown)[0]!r} "
                              f"in a per-layer declaration")
         kv = [layer for layer in layers if layer["kind"] == "kv"]
         state = [layer for layer in layers if layer["kind"] == "state"]
-        if any(layer != group[0] for group in (kv, state)
+        window = [layer for layer in layers if layer["kind"] == "window"]
+        if any(layer != group[0] for group in (kv, state, window)
                for layer in group):
             raise ValueError("the layers of one cache kind must share "
                              "one geometry")
@@ -500,7 +629,7 @@ class LayeredPool(PagePool):
                 "mesh: a per-layer pool is not sharded (a 'state' layer's "
                 "per-slot state lives on one device)")
         super().__init__(cfg, len(layers))
-        self.kv = self.state = None
+        self.kv = self.state = self.window = None
         if kv:
             self.kv = _grouped_kv(cfg, len(kv), kv[0])
             self.geometry.update(self.kv.geometry, num_layers=len(layers))
@@ -508,14 +637,26 @@ class LayeredPool(PagePool):
             self.state = SlotState(cfg, state[0])
             self.state_layers = len(state)
             self.geometry.update(self.state.geometry)
+        if window:
+            self.window = WindowKV(cfg, window[0])
+            self.window_layers = len(window)
+            self.geometry.update(self.window.geometry)
         self.geometry["kinds"] = list(self.kinds)
         self.attention_path = "+".join(
-            [f"kv:{self.kv.attention_path}"] * bool(kv)
-            + ["state:xla/float32"] * bool(state))
+            ([f"kv:{self.kv.attention_path}"] if kv else [])
+            + ["state:xla/float32"] * bool(state)
+            # with rings beside pages, which layer is which enters the
+            # AOT fingerprint too (a letter a layer)
+            + ([self.window.attention_path, "kinds:" + "".join(
+                k[0] for k in self.kinds)] if window else []))
 
     def _struct_of(self, li):
-        return ((self.kv._struct,) * 2 if self.kinds[li] == "kv"
-                else self.state.struct)
+        kind = self.kinds[li]
+        if kind == "kv":
+            return (self.kv._struct,) * 2
+        if kind == "window":
+            return (self.window.struct,) * 2
+        return self.state.struct
 
     def _per_layer(self, make):
         """``(firsts, seconds)``: ``make(struct leaf)`` over each layer's
@@ -539,8 +680,18 @@ class LayeredPool(PagePool):
             for s in self.state.struct)
 
     @property
+    def window_nbytes(self):
+        """Bytes of every window layer's two rings."""
+        if self.window is None:
+            return 0
+        s = self.window.struct
+        return (2 * self.window_layers * math.prod(s.shape)
+                * np.dtype(s.dtype).itemsize)
+
+    @property
     def nbytes(self):
-        return (self.kv.nbytes if self.kv else 0) + self.state_nbytes
+        return ((self.kv.nbytes if self.kv else 0) + self.state_nbytes
+                + self.window_nbytes)
 
     # a page layer's read and write are its PlainKV's
     def prefill(self, *args):
@@ -549,20 +700,38 @@ class LayeredPool(PagePool):
     def decode(self, *args):
         return self.kv.decode(*args)
 
+    def layer_step(self, li, mode, slot=None):
+        if self.kinds[li] != "window":
+            return super().layer_step(li, mode, slot)
+        if mode == "prefill":
+            return functools.partial(self.window.prefill, slot=slot)
+        return self.window.decode
+
     def recur(self, fn, conv, ssm, lens, slot, values):
         return self.state.recur(fn, conv, ssm, lens, slot, values)
 
     def slot_operands(self, slot):
-        return (np.array([slot], np.int32),) if self.state else ()
+        return ((np.array([slot], np.int32),)
+                if self.state or self.window else ())
 
     def prefill_attrs(self, tokens, bucket):
-        if self.state is None:
-            return {}
-        return {"scan_tokens": tokens}
+        attrs = {}
+        if self.state is not None:
+            attrs["scan_tokens"] = tokens
+        if self.window is not None:
+            # `window_rows`: the rows this prefill writes into rings
+            attrs.update(window=self.window.window,
+                         window_layers=self.window_layers,
+                         window_rows=self.window_layers * min(
+                             tokens, self.window.window))
+        return attrs
 
     def decode_attrs(self, live):
         return ({"state_rows": live * self.state_layers} if self.state
                 else {})
+
+    def live_rows(self, lens):
+        return self.window.live_rows(lens) if self.window else 0
 
     # ------------------------------------------------------- hand-off
     def exported_pages(self, layers):
@@ -570,7 +739,15 @@ class LayeredPool(PagePool):
             return 0
         return self.kv.exported_pages([layers[self.kinds.index("kv")]])
 
+    def _no_window_handoff(self):
+        if self.window is not None:
+            raise ValueError(
+                "a 'window' layer's ring is not handed off: export / "
+                "import of a per-layer pool with window layers is not "
+                "built")
+
     def export(self, pools, pages, slot=None):
+        self._no_window_handoff()
         layers = []
         for li, kind in enumerate(self.kinds):
             first, second = pools[0][li], pools[1][li]
@@ -582,6 +759,7 @@ class LayeredPool(PagePool):
         return layers
 
     def import_(self, pools, idx, layers, slot=None):
+        self._no_window_handoff()
         halves = ([], [])
         for li, kind in enumerate(self.kinds):
             first, second = pools[0][li], pools[1][li]

@@ -167,6 +167,10 @@ class EngineMetrics:
         # per-slot recurrent state (pools with state layers only)
         self.state_pool_bytes = 0
         self.state_admits_total = 0  # slots whose state a prefill overwrote
+        # per-slot rings of window layers (pools with window layers only)
+        self.window_pool_bytes = 0
+        self.window_rows_live = 0    # rows of one window layer the last
+        #                              decode step's passes read
         self.moe_imbalance = None    # histogram of heaviest / mean load
         # generation by diffusion over blocks (0 = a next-token engine,
         # which snapshots as before)
@@ -239,6 +243,9 @@ class EngineMetrics:
         state = ({} if not self.state_pool_bytes else {"state": {
             "pool_bytes": self.state_pool_bytes,
             "admits_total": self.state_admits_total}})
+        window = ({} if not self.window_pool_bytes else {"window": {
+            "pool_bytes": self.window_pool_bytes,
+            "rows_live": self.window_rows_live}})
         blocks = ({} if not self.block_length else {"blocks": {
             "block_length": self.block_length,
             "decode_forwards_total": self.decode_forwards_total,
@@ -250,6 +257,7 @@ class EngineMetrics:
         return {
             **moe,
             **state,
+            **window,
             **blocks,
             "uptime_s": round(elapsed, 3),
             "requests": {

@@ -182,3 +182,33 @@ def test_grouped_matmul_compiles_at_the_block_pass_size(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
+
+
+def test_windowed_grouped_flash_compiles_at_the_cell_size(one_chip):
+    """The flash forward with a window over grouped K/V, through Mosaic at
+    ``smallthinker21b_serve_longdoc``'s longest prefill (28 query heads
+    over 4 K/V heads of 128, 16,384 positions, a window of 4,096): it keeps
+    its name and the layout the prefill roofline finds it by, and K/V are
+    read in place (no 28-head copy of them)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda q, k, v: fa._flash_bhsd(
+            q, k, v, True, 128 ** -0.5, None, None, False, 4096)).lower(
+                S((1, 28, 16384, 128)), S((1, 4, 16384, 128)),
+                S((1, 4, 16384, 128))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = _kernel_calls(text)
+    assert [name.split(".")[0] for name in calls] == ["flash_fwd"]
+    (line,) = calls.values()
+    assert "[28,16384,128]" in line.split(" custom-call(")[0]
+    assert "bf16[4,16384,128]" in line.split(" custom-call(")[1]

@@ -198,3 +198,125 @@ def test_pool_kind_contract(kind):
     there_out = decode_step(there, moved, nxt, check=False)
     for a, b in zip(here_out, there_out):
         np.testing.assert_array_equal(a[live], b[live])
+
+
+# ------------------------------------------------------------ window rings
+WIN, W_HEADS, W_QUERY = 8, 2, 4          # a ring of 8 rows, 2 query heads
+#                                          a K/V head
+
+
+def _window_pool():
+    from paddle_tpu.serving.kv_pool import LayeredPool
+    cfg = serving.EngineConfig(max_num_seqs=SLOTS, page_size=PAGE,
+                               max_model_len=64)
+    return LayeredPool(cfg, [{"kind": "window", "num_heads": W_HEADS,
+                              "head_dim": DIM, "query_heads": W_QUERY,
+                              "window": WIN}])
+
+
+def _windowed(q, k, v):
+    """Dense attention of a query at the last of ``k``/``v``'s positions
+    [t, H_kv, d] over the window's last WIN of them."""
+    k, v = k[-WIN:], v[-WIN:]
+    g = W_QUERY // W_HEADS
+    qg = q.reshape(W_HEADS, g, DIM)
+    p = _softmax(np.einsum("hgd,thd->hgt", qg, k) / np.sqrt(DIM))
+    return np.einsum("hgt,thd->hgd", p, v).reshape(W_QUERY, DIM)
+
+
+class _Ring:
+    """Drives one window layer slot by slot as the engine does; each slot's
+    history is what its CURRENT request wrote."""
+
+    def __init__(self):
+        self.pool = _window_pool()
+        self.rings = list(self.pool.allocate())
+        self.rng = np.random.default_rng(7)
+        self.hist = {}
+        self.lens = np.zeros((SLOTS,), np.int32)
+
+    def draw(self, b, s, heads):
+        return self.rng.standard_normal((b, s, heads, DIM)).astype(
+            np.float32)
+
+    def prefill(self, slot, n, bucket=32):
+        q, k, v = (self.draw(1, bucket, h)
+                   for h in (W_QUERY, W_HEADS, W_HEADS))
+        step = self.pool.layer_step(0, "prefill",
+                                    jnp.asarray([slot], jnp.int32))
+        out, self.rings[0][0], self.rings[1][0] = step(
+            *(jnp.asarray(x) for x in (q, k, v)), self.rings[0][0],
+            self.rings[1][0], None, jnp.asarray([n], jnp.int32))
+        self.hist[slot] = (k[0, :n], v[0, :n])
+        self.lens[slot] = n
+        for i in range(n):                 # the prompt's own window
+            want = _windowed(q[0, i], k[0, :i + 1], v[0, :i + 1])
+            np.testing.assert_allclose(np.asarray(out)[0, i], want,
+                                       rtol=1e-5, atol=1e-5)
+
+    def decode(self, check=True):
+        """One pass over every slot at the stored lengths; unchecked, the
+        lengths do not advance (a pass launched and discarded)."""
+        q, k, v = (self.draw(SLOTS, 1, h)
+                   for h in (W_QUERY, W_HEADS, W_HEADS))
+        out, self.rings[0][0], self.rings[1][0] = self.pool.layer_step(
+            0, "decode")(*(jnp.asarray(x) for x in (q, k, v)),
+                         self.rings[0][0], self.rings[1][0], None,
+                         jnp.asarray(self.lens))
+        if check:
+            for b, (hk, hv) in self.hist.items():
+                hk, hv = (np.concatenate([h, x[b]]) for h, x in
+                          ((hk, k), (hv, v)))
+                self.hist[b] = (hk, hv)
+                np.testing.assert_allclose(
+                    np.asarray(out)[b, 0], _windowed(q[b, 0], hk, hv),
+                    rtol=1e-5, atol=1e-5)
+                self.lens[b] += 1
+
+
+@pytest.mark.parametrize("case", ["longer_than_window", "slot_reused",
+                                  "discarded_pass"])
+def test_window_ring_reads_the_window(case):
+    """A window layer's ring against dense attention over the last WIN
+    positions a request wrote: a prompt longer than the window, decode
+    across the ring's wrap, a slot reused by a shorter request (the rows
+    its earlier request left are never read), and a run-ahead pass that
+    is launched and discarded (it overwrites only the row of a position
+    that no later query's window holds)."""
+    ring = _Ring()
+    assert ring.pool.window_layers == 1 and ring.pool.state_layers == 0
+    ring.prefill(0, 19)                    # 19 > WIN: the last 8 kept
+    ring.prefill(1, 3)
+    for _ in range(12):                    # slot 1 wraps, slot 0 wraps on
+        ring.decode()
+    if case == "slot_reused":
+        ring.prefill(0, 2)                 # slot 0's new, shorter request
+        for _ in range(10):
+            ring.decode()
+    if case == "discarded_pass":
+        # the pass run ahead of the last one (whose queries have read
+        # their windows) is discarded; the next pass computes its
+        # positions again
+        ring.decode(check=False)
+        for _ in range(10):
+            ring.decode()
+
+
+def test_window_ring_geometry_and_refusals():
+    """Bytes, the fingerprint's term, the prefill's attributes — and a
+    hand-off of a ring, which is not built, refused by name."""
+    pool = _window_pool()
+    ring = pool.window.struct
+    assert ring.shape == (SLOTS, WIN, W_HEADS, DIM)
+    assert pool.nbytes == pool.window_nbytes == 2 * SLOTS * WIN * W_HEADS \
+        * DIM * 4
+    assert pool.attention_path == f"window/{WIN}:xla/ring+kinds:w"
+    assert pool.geometry["window"] == WIN
+    assert pool.prefill_attrs(19, 32) == {"window": WIN, "window_layers": 1,
+                                          "window_rows": WIN}
+    assert pool.live_rows([3, 19]) == 4 + WIN
+    pools = pool.allocate()
+    with pytest.raises(ValueError, match="'window' layer"):
+        pool.export(pools, [1], slot=0)
+    with pytest.raises(ValueError, match="'window' layer"):
+        pool.import_(pools, np.asarray([1]), [{}], slot=0)

@@ -355,3 +355,84 @@ class TestKernelsUnderRecompute:
 
         for got, want in zip(grads(True), grads(False)):
             np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def _windowed_dense(q, k, v, window):
+    """Dense causal attention under a window, K/V heads repeated to the
+    query heads' (query head i reads K/V head i // groups)."""
+    groups = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, groups, axis=2) for x in (k, v))
+    s = q.shape[1]
+    gap = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    seen = (gap >= 0) & (gap < window)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+class TestFlashWindow:
+    """The forward with a window (key tiles before the band's lower edge
+    not run, those it crosses masked) and grouped K/V read through the
+    index map, against the dense windowed composition (interpret mode,
+    float32: the kernel's online softmax differs from one softmax by
+    rounding alone, 1e-5)."""
+
+    @pytest.mark.parametrize("s,heads,kv_heads,d,window,block", [
+        (256, 4, 2, 64, 16, 64),      # window < block: one tile, masked
+        (256, 2, 1, 128, 100, 64),    # the band spans tiles: some skipped
+        (200, 4, 4, 64, 70, 64),      # a ragged tail under the window
+        (130, 4, 2, 64, 40, None),    # the kernel's own schedule
+        (300, 4, 4, 64, 1000, 64),    # a window past the sequence: causal
+    ])
+    def test_matches_dense_windowed(self, s, heads, kv_heads, d, window,
+                                    block):
+        rng = np.random.default_rng(s + window)
+        q, k, v = (jnp.asarray(rng.standard_normal((1, s, h, d)),
+                               jnp.float32)
+                   for h in (heads, kv_heads, kv_heads))
+        out = flash_attention_bshd(q, k, v, causal=True, window=window,
+                                   block_q=block, block_k=block,
+                                   interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(
+            _windowed_dense(q, k, v, window)), rtol=1e-5, atol=1e-5)
+
+    def test_skipped_tiles_cost_nothing_in_the_answer(self):
+        """Keys wholly before every query's window do not move the output:
+        scrambling them changes nothing."""
+        rng = np.random.default_rng(0)
+        q, k, v = (jnp.asarray(rng.standard_normal((1, 256, 2, 64)),
+                               jnp.float32) for _ in range(3))
+        kw = dict(causal=True, window=32, block_q=64, block_k=64,
+                  interpret=True)
+        out = flash_attention_bshd(q, k, v, **kw)
+        k2 = k.at[:, :160].set(1e3)
+        out2 = flash_attention_bshd(q, k2, v, **kw)
+        np.testing.assert_array_equal(np.asarray(out[:, 192:]),
+                                      np.asarray(out2[:, 192:]))
+
+    def test_no_window_leaves_the_jaxpr_unchanged(self):
+        """``window=None`` traces exactly what a call without it traces
+        (forward and backward), and a window traces something else."""
+        q, k, v = _qkv(1, 256, 2, 64)
+
+        def forward(**kw):
+            return lambda q, k, v: flash_attention_bshd(
+                q, k, v, causal=True, interpret=True, **kw)
+
+        def jaxprs(**kw):
+            f = forward(**kw)
+            g = jax.grad(lambda q, k, v: jnp.sum(f(q, k, v)))
+            return str(jax.make_jaxpr(f)(q, k, v)), str(
+                jax.make_jaxpr(g)(q, k, v))
+
+        assert jaxprs(window=None) == jaxprs()
+        assert str(jax.make_jaxpr(forward(window=64))(q, k, v)) != \
+            jaxprs()[0]
+
+    def test_backward_and_non_causal_window_refuse(self):
+        q, k, v = _qkv(1, 128, 2, 64)
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention_bshd(q, k, v, window=16, interpret=True)
+        with pytest.raises(NotImplementedError, match="window"):
+            jax.grad(lambda q: jnp.sum(flash_attention_bshd(
+                q, k, v, causal=True, window=16, interpret=True)))(q)
