@@ -65,6 +65,7 @@ import jax.numpy as jnp
 __all__ = [
     "PageAllocator",
     "grouped_causal_attention",
+    "grouped_paged_attend",
     "latent_attend",
     "latent_decode_path",
     "latent_decode_step",
@@ -317,6 +318,32 @@ def grouped_causal_attention(q, k, v, scale, block=1, window=None):
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
                      preferred_element_type=jnp.float32)
     return out.reshape(b, s, H, d).astype(q.dtype)
+
+
+def grouped_paged_attend(q, k_pages, v_pages, tables, lens, scale):
+    """``q [b, rows, H, d]`` over each slot's first ``lens[b]`` positions
+    of ROW pages ``[N, page, H_kv*d]`` (the whole table gathered as rows
+    ``[b, positions, H_kv, d]``, masked at ``lens``), query head ``i``
+    reading K/V head ``i // (H / H_kv)``; ``scale`` multiplies the scores;
+    float32 softmax, both contractions accumulated wide -> ``[b, rows, H,
+    d]``.  A block's rows see every position under ``lens`` (the caller
+    counts the block in).  The XLA composition of a grouped-query cache's
+    decode read, and the definition the kernel ``grouped_paged_decode`` is
+    held to."""
+    b, rows, H, d = q.shape
+    hk = k_pages.shape[-1] // d
+    keys = k_pages[tables].reshape(b, -1, hk, d)
+    vals = v_pages[tables].reshape(b, -1, hk, d)
+    scores = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", q.reshape(b, rows, hk, H // hk, d),
+        keys, preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(keys.shape[1])[None, :] < lens[:, None]
+    scores = jnp.where(live[:, None, None, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vals,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, rows, H, d).astype(q.dtype)
 
 
 # --------------------------------------------------------- latent pools

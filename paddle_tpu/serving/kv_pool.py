@@ -20,7 +20,8 @@ programs, and asks the pool object for everything about the format.
   elsewhere; wire block ``rows``.  Refuses a mesh and a narrow dtype.
 - :class:`LayeredPool` — a kind PER LAYER: a :class:`GroupedKV` for the
   layers that declare ``kv`` (row pages at their own K/V head count,
-  grouped queries reading through the XLA composition), a
+  grouped queries reading the live pages through the Pallas kernel
+  ``grouped_paged_decode`` on a TPU, the XLA composition elsewhere), a
   :class:`SlotState` for those that declare ``state`` — a recurrent
   layer's state, indexed by SLOT and not by page (``[max_num_seqs,
   ...]``: the convolution's window at the engine's dtype, the
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from paddle_tpu.incubate.nn.paged_attention import (grouped_causal_attention,
+                                                    grouped_paged_attend,
                                                     latent_decode_path,
                                                     latent_decode_step,
                                                     latent_pool_width,
@@ -55,13 +57,14 @@ from paddle_tpu.incubate.nn.paged_attention import (grouped_causal_attention,
                                                     paged_decode_step,
                                                     paged_prefill_append,
                                                     row_pages_default)
+from paddle_tpu.ops.pallas import on_tpu
 from paddle_tpu.ops.pallas.flash_attention import (FLASH_ATTENTION_REVISION,
                                                    flash_attention_bshd)
 from paddle_tpu.ops.pallas.mla_paged_attention import \
     MLA_PAGED_DECODE_REVISION
-from paddle_tpu.ops.pallas.paged_attention import (PAGED_DECODE_REVISION,
-                                                   from_row_pages,
-                                                   to_row_pages)
+from paddle_tpu.ops.pallas.paged_attention import (
+    GROUPED_PAGED_DECODE_REVISION, PAGED_DECODE_REVISION, from_row_pages,
+    grouped_paged_decode, paged_decode_supported, to_row_pages)
 from paddle_tpu.quantization.kv_cache import (quantized_decode_step,
                                               quantized_prefill_append,
                                               resolve_kv_cache_dtype)
@@ -317,9 +320,13 @@ class GroupedKV(PlainKV):
     """K and V of ``num_heads x head_dim`` read by ``query_heads`` query
     heads (head ``i`` reads K/V head ``i // groups``) at the model's own
     score ``scale``: ROW pages on every platform (a head-major pool is
-    re-laid whole by every program that appends to it), read through the
-    XLA composition — ``paged_decode`` reads one K/V head a query head
-    at ``1/sqrt(d)``.  On one device."""
+    re-laid whole by every program that appends to it).  Decode of one
+    position a slot reads the live pages through the Pallas kernel
+    ``grouped_paged_decode`` where it runs (on a TPU, in a program no mesh
+    partitions, for the geometries it takes); a block-causal cache
+    (``causal_block`` > 1) is decoded by blocks of that many rows, and
+    that read, like every read elsewhere, is the XLA composition over the
+    table.  On one device."""
 
     def __init__(self, cfg, num_layers, num_heads, head_dim, query_heads,
                  scale, causal_block=1):
@@ -327,21 +334,27 @@ class GroupedKV(PlainKV):
         self.groups = query_heads // num_heads
         self.scale = float(scale)
         self.causal_block = int(causal_block)
-        self.decode_kernel = False
-        self.attention_path = "xla/row_pages"
+        # where the kernels run (on a TPU, in a program no mesh
+        # partitions), a causal prefill reads through the Pallas flash
+        # kernel — no [s, s] score table — and the decode of one position
+        # a slot through grouped_paged_decode; a block-causal cache keeps
+        # the XLA composition for both
+        self.flash = self.causal_block == 1 and _flash_prefill()
+        self.decode_kernel = self.flash and paged_decode_supported(
+            cfg.dtype, num_heads, head_dim, cfg.page_size)
+        self.attention_path = (
+            f"grouped_paged_decode/{GROUPED_PAGED_DECODE_REVISION}"
+            if self.decode_kernel else "xla/row_pages")
         if self.causal_block > 1:
             self.geometry["causal_block"] = self.causal_block
-        # a causal prefill reads through the Pallas flash kernel where it
-        # runs (on a TPU, in a program no mesh partitions): no [s, s]
-        # score table; a block-causal one keeps the XLA composition
-        self.flash = self.causal_block == 1 and _flash_prefill()
         if self.flash:
             self.attention_path += f"+prefill:{FLASH_ATTENTION_REVISION}"
 
     def prefill(self, q, k, v, kp, vp, tables, lens):
         if self.flash:
             out = flash_attention_bshd(q, k, v, causal=True,
-                                       scale=self.scale)
+                                       scale=self.scale,
+                                       interpret=not on_tpu())
         else:
             out = grouped_causal_attention(q, k, v, self.scale,
                                            block=self.causal_block)
@@ -350,44 +363,18 @@ class GroupedKV(PlainKV):
         return out, kp, vp
 
     def decode(self, q, k, v, kp, vp, tables, lens):
-        """Each slot's new K/V row scattered at its length (in place),
-        the slot's table gathered as rows ``[b, positions, H_kv, d]``, a
-        K/V head's group of queries attending together; float32 softmax,
-        both contractions accumulated wide.  ``q`` / ``k`` / ``v`` of
-        ``rows`` > 1 positions a slot are one BLOCK (:meth:`decode_block`)."""
-        if q.shape[1] > 1:
-            return self.decode_block(q, k, v, kp, vp, tables, lens)
-        b, _, H, d = q.shape
-        page, hk = self.page_size, self.num_heads
-        lens = lens.astype(jnp.int32)
-        page_ids = jnp.take_along_axis(tables, (lens // page)[:, None],
-                                       axis=1)[:, 0]
-        kp = kp.at[page_ids, lens % page].set(
-            k.reshape(b, hk * d).astype(kp.dtype))
-        vp = vp.at[page_ids, lens % page].set(
-            v.reshape(b, hk * d).astype(vp.dtype))
-        keys = kp[tables].reshape(b, -1, hk, d)
-        vals = vp[tables].reshape(b, -1, hk, d)
-        scores = jnp.einsum(
-            "bhgd,bkhd->bhgk", q.reshape(b, hk, self.groups, d), keys,
-            preferred_element_type=jnp.float32) * self.scale
-        live = jnp.arange(keys.shape[1])[None, :] < (lens + 1)[:, None]
-        scores = jnp.where(live[:, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhgk,bkhd->bhgd", probs, vals,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(b, 1, H, d).astype(q.dtype), kp, vp
-
-    def decode_block(self, q, k, v, kp, vp, tables, lens):
-        """A pass over each slot's in-flight block of ``rows`` positions
-        (``q [b, rows, H, d]``): the block's K/V rows are written FIRST, at
-        ``len .. len + rows - 1`` (in place, in pages the slot owns;
-        overwritten by the block's next pass), then each of its ``rows x
-        H`` query rows attends over the slot's ``len + rows`` positions —
-        the stored prefix and the whole block, so no mask is needed inside
-        it.  ``len`` is not advanced here: the caller advances it when the
-        pass was the block's last."""
+        """A pass over each slot's ``rows`` positions (``q [b, rows, H,
+        d]``; one, or a BLOCK of a block-causal cache): their K/V rows
+        written FIRST, at ``len .. len + rows - 1`` (in place, in pages the
+        slot owns; a block's are overwritten by its next pass), then each
+        of the ``rows x H`` query rows attends over the slot's ``len +
+        rows`` positions — the stored prefix and the whole block, so no
+        mask is needed inside it.  One position a slot reads the live
+        pages alone through ``grouped_paged_decode`` where
+        :attr:`decode_kernel` says so; every other pass gathers the whole
+        table (:func:`grouped_paged_attend`).  ``len`` is not advanced
+        here: the caller advances it (a block's, when the pass was its
+        last)."""
         b, rows, H, d = q.shape
         page, hk = self.page_size, self.num_heads
         lens = lens.astype(jnp.int32)
@@ -397,18 +384,13 @@ class GroupedKV(PlainKV):
             k.reshape(b, rows, hk * d).astype(kp.dtype))
         vp = vp.at[page_ids, at % page].set(
             v.reshape(b, rows, hk * d).astype(vp.dtype))
-        keys = kp[tables].reshape(b, -1, hk, d)
-        vals = vp[tables].reshape(b, -1, hk, d)
-        scores = jnp.einsum(
-            "bqhgd,bkhd->bhgqk", q.reshape(b, rows, hk, self.groups, d),
-            keys, preferred_element_type=jnp.float32) * self.scale
-        live = jnp.arange(keys.shape[1])[None, :] < (lens + rows)[:, None]
-        scores = jnp.where(live[:, None, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vals,
-                         preferred_element_type=jnp.float32)
-        return out.reshape(b, rows, H, d).astype(q.dtype), kp, vp
+        if rows == 1 and self.decode_kernel:
+            out = grouped_paged_decode(q[:, 0], kp, vp, tables, lens + 1,
+                                       scale=self.scale,
+                                       interpret=not on_tpu())
+            return out[:, None], kp, vp
+        return (grouped_paged_attend(q, kp, vp, tables, lens + rows,
+                                     self.scale), kp, vp)
 
 
 class LatentPool(PagePool):
@@ -537,7 +519,8 @@ class WindowKV:
         before decode writes it)."""
         if self.flash:
             out = flash_attention_bshd(q, k, v, causal=True,
-                                       scale=self.scale, window=self.window)
+                                       scale=self.scale, window=self.window,
+                                       interpret=not on_tpu())
         else:
             out = grouped_causal_attention(q, k, v, self.scale,
                                            window=self.window)
@@ -632,6 +615,7 @@ class LayeredPool(PagePool):
         self.kv = self.state = self.window = None
         if kv:
             self.kv = _grouped_kv(cfg, len(kv), kv[0])
+            self.decode_kernel = self.kv.decode_kernel
             self.geometry.update(self.kv.geometry, num_layers=len(layers))
         if state:
             self.state = SlotState(cfg, state[0])

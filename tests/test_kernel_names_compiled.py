@@ -212,3 +212,41 @@ def test_windowed_grouped_flash_compiles_at_the_cell_size(one_chip):
     (line,) = calls.values()
     assert "[28,16384,128]" in line.split(" custom-call(")[0]
     assert "bf16[4,16384,128]" in line.split(" custom-call(")[1]
+
+
+@pytest.mark.parametrize("cell,slots,q_heads,kv_heads,pages,width,scale", [
+    ("smallthinker21b_serve_longdoc", 32, 28, 4, 32769, 1024, 128 ** -0.5),
+    ("granite4h_serve_chat", 64, 32, 8, 8192, 128, 1 / 128)])
+def test_grouped_paged_decode_compiles_at_the_cell_sizes(
+        one_chip, cell, slots, q_heads, kv_heads, pages, width, scale):
+    """The grouped-query decode kernel through Mosaic at the two cells'
+    full layers (bf16 row pages of 16 rows, head_dim 128): it keeps its
+    name, so ``breakdown.device_ops`` and ``grouped_decode_roofline.serve``
+    find it, and the pool is read where it lies (no copy of a pool-shaped
+    operand)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas.paged_attention import grouped_paged_decode
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (pages, 16, kv_heads * 128)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda q, k, v, t, n: grouped_paged_decode(
+            q, k, v, t, n, scale=scale)).lower(
+                S((slots, q_heads, 128)), S(pool), S(pool),
+                S((slots, width), jnp.int32),
+                S((slots,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = _kernel_calls(text)
+    assert [n.split("%")[-1].split(".")[0] for n in calls] == [
+        "grouped_paged_decode"], cell
+    shape = "[%d,16,%d]" % (pages, kv_heads * 128)
+    assert not [ln for ln in text.splitlines()
+                if shape in ln.split(" = ")[-1].split("(")[0]
+                and (" copy(" in ln or " convert(" in ln)], cell
