@@ -452,6 +452,10 @@ class DroplessMoELayer(nn.Layer):
     experts") or ``"softmax"`` (Granite, :func:`softmax_topk_route`: no
     bias, no scaling).  ``act`` is the gate's activation of every routed
     expert: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU, SmallThinker's).
+    ``shared_gate=True`` scales the shared experts' output by a token's
+    own ``sigmoid(x W_sg)`` (``shared_gate_weight [d, 1]``, Qwen3-Next's
+    ``shared_expert_gate``); off by default, and then the layer's program
+    is what it was without the option.
 
     The layer is told which contiguous range of experts it holds
     (``held = (first, count)``, default all): it routes over all
@@ -472,7 +476,7 @@ class DroplessMoELayer(nn.Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k, n_shared=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True, held=None,
                  shared=True, initializer_range=0.02, make_parameter=None,
-                 route="sigmoid", act="silu"):
+                 route="sigmoid", act="silu", shared_gate=False):
         super().__init__()
         if route not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown router {route!r}")
@@ -501,6 +505,9 @@ class DroplessMoELayer(nn.Layer):
         if self.has_shared:
             self.shared_w13 = make([d_model, 2 * n_shared * d_expert], std)
             self.shared_w2 = make([n_shared * d_expert, d_model], std)
+        self.shared_gate = self.has_shared and shared_gate
+        if self.shared_gate:
+            self.shared_gate_weight = make([d_model, 1], std)
 
     def forward(self, x, route_from=None):
         import jax
@@ -534,13 +541,18 @@ class DroplessMoELayer(nn.Layer):
                 a = jnp.matmul(h, shared[0],
                                preferred_element_type=jnp.float32)
                 gate, up = jnp.split(a, 2, -1)
-                out = out + jnp.matmul(
+                y = jnp.matmul(
                     (jax.nn.silu(gate) * up).astype(h.dtype), shared[1],
-                    preferred_element_type=jnp.float32).astype(h.dtype)
+                    preferred_element_type=jnp.float32)
+                if self.shared_gate:
+                    y = y * jax.nn.sigmoid(jnp.matmul(
+                        h, shared[2], preferred_element_type=jnp.float32))
+                out = out + y.astype(h.dtype)
             return out.reshape(v.shape), counts
 
         shared = ((self.shared_w13, self.shared_w2) if self.has_shared
-                  else ())
+                  else ()) + ((self.shared_gate_weight,) if self.shared_gate
+                              else ())
         out, counts = apply(fn, x, *source, *router, self.w13, self.w2,
                             *shared)
         self.last_counts = counts

@@ -46,6 +46,10 @@ from paddle_tpu.models.granitemoehybrid import (  # noqa: F401
     GraniteMoeHybridConfig,
     GraniteMoeHybridForCausalLM,
 )
+from paddle_tpu.models.qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    Qwen3NextForCausalLM,
+)
 from paddle_tpu.models.sdar_moe import (  # noqa: F401
     SdarMoeConfig,
     SdarMoeForCausalLM,

@@ -408,7 +408,8 @@ class GraniteMoeHybridForCausalLM(_Leaves):
               "query_heads": c.num_heads, "scale": c.attention_multiplier}
         state = {"kind": "state",
                  "conv": (c.mamba_d_conv - 1, c.conv_width),
-                 "ssm": (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state)}
+                 "ssm": (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
+                 "recurrence": "mamba2", "chunk": c.mamba_chunk_size}
         return {"kind": "layers",
                 "layers": [dict(state if t == "mamba" else kv)
                            for t in c.layer_types]}

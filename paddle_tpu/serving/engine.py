@@ -329,8 +329,10 @@ class LLMEngine:
       (``models/granitemoehybrid.py``): a declaration PER LAYER — ``kv``
       with its own ``num_heads`` / ``head_dim`` (and ``query_heads``,
       ``scale`` for grouped queries), read through ``kv_ctx.attend``, or
-      ``state`` (``conv``, ``ssm`` shapes), a per-SLOT recurrent state
-      reached through ``kv_ctx.recur``; a prefill overwrites its slot's
+      ``state`` (``conv``, ``ssm`` shapes, optionally the ``recurrence``
+      it holds and its prompts' ``chunk``; Granite's Mamba-2,
+      ``models/qwen3_next.py``'s gated delta rule), a per-SLOT recurrent
+      state reached through ``kv_ctx.recur``; a prefill overwrites its slot's
       state, decode advances it in place, the hand-off carries it.
 
     The declaration and ``EngineConfig(kv_cache_dtype=, dtype=, mesh=)``
@@ -421,6 +423,9 @@ class LLMEngine:
         self.metrics.compile_bound = cfg.compile_bound
         self.metrics.pages_total = cfg.num_pages - 1   # page 0 reserved
         self.metrics.state_pool_bytes = self._pool.state_nbytes
+        if self._pool.state_layers:
+            self.metrics.state_slot_bytes = self._pool.state.slot_nbytes
+            self.metrics.state_recurrence = self._pool.state.recurrence
         if self._pool.window_layers:
             self.metrics.window_pool_bytes = self._pool.window_nbytes
         self.metrics.block_length = self._gen.block_length

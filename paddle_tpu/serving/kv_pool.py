@@ -25,7 +25,8 @@ programs, and asks the pool object for everything about the format.
   :class:`SlotState` for those that declare ``state`` — a recurrent
   layer's state, indexed by SLOT and not by page (``[max_num_seqs,
   ...]``: the convolution's window at the engine's dtype, the
-  state-space matrix in float32), overwritten by the prefill that
+  recurrence's matrix — Mamba-2's state-space matrix, a delta rule's
+  key-value memory — in float32), overwritten by the prefill that
   admits a request and advanced in place by decode; wire blocks
   ``conv`` / ``ssm`` — and a :class:`WindowKV` for those that declare
   ``window``: a sliding-window layer's K and V of a slot's last ``W``
@@ -448,9 +449,12 @@ class SlotState:
     """A recurrent layer's cache: indexed by SLOT, not by page.  An entry
     is the pair (``conv [slots, *conv]`` at the engine's dtype — the
     convolution's last inputs —, ``ssm [slots, *ssm]`` float32 — the
-    state-space matrix).  A prefill starts from nothing and overwrites
-    its slot's entry, so a finished request's state never reaches the
-    next; decode advances every slot's entry in place."""
+    recurrence's matrix, whatever recurrence the model runs through
+    :meth:`recur`).  A prefill starts from nothing and overwrites its
+    slot's entry, so a finished request's state never reaches the next;
+    decode advances every slot's entry in place.  The declaration may name
+    its ``recurrence`` (default ``"mamba2"``; the spans say it) and the
+    ``chunk`` its prompts run in (``scan_chunks`` counts them)."""
 
     wire = ("conv", "ssm")
 
@@ -459,9 +463,24 @@ class SlotState:
         self.struct = (
             jax.ShapeDtypeStruct((slots, *spec["conv"]), cfg.dtype),
             jax.ShapeDtypeStruct((slots, *spec["ssm"]), jnp.float32))
+        self.recurrence = str(spec.get("recurrence", "mamba2"))
+        self.chunk = spec.get("chunk")
         # lists: what a hand-off's JSON header brings back
         self.geometry = {"conv": list(spec["conv"]),
                          "ssm": list(spec["ssm"])}
+
+    @property
+    def slot_nbytes(self):
+        """Bytes of ONE slot's entry in ONE layer."""
+        return sum(math.prod(s.shape[1:]) * np.dtype(s.dtype).itemsize
+                   for s in self.struct)
+
+    def chunks(self, bucket):
+        """Chunks one layer's prompt of ``bucket`` positions runs, padding
+        included (one where no chunk is declared)."""
+        if not self.chunk:
+            return 1
+        return -(-bucket // min(int(self.chunk), bucket))
 
     def recur(self, fn, conv, ssm, lens, slot, values):
         """``fn(conv, ssm, lens, *values) -> (out, conv', ssm')`` on the
@@ -701,7 +720,10 @@ class LayeredPool(PagePool):
     def prefill_attrs(self, tokens, bucket):
         attrs = {}
         if self.state is not None:
-            attrs["scan_tokens"] = tokens
+            attrs.update(scan_tokens=tokens,
+                         recurrence=self.state.recurrence,
+                         scan_chunks=self.state_layers
+                         * self.state.chunks(bucket))
         if self.window is not None:
             # `window_rows`: the rows this prefill writes into rings
             attrs.update(window=self.window.window,
@@ -711,7 +733,8 @@ class LayeredPool(PagePool):
         return attrs
 
     def decode_attrs(self, live):
-        return ({"state_rows": live * self.state_layers} if self.state
+        return ({"state_rows": live * self.state_layers,
+                 "recurrence": self.state.recurrence} if self.state
                 else {})
 
     def live_rows(self, lens):

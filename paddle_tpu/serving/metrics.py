@@ -166,6 +166,8 @@ class EngineMetrics:
         self.moe_tokens_routed = 0   # token-expert pairs, all layers
         # per-slot recurrent state (pools with state layers only)
         self.state_pool_bytes = 0
+        self.state_slot_bytes = 0    # one slot's state in one layer
+        self.state_recurrence = None
         self.state_admits_total = 0  # slots whose state a prefill overwrote
         # per-slot rings of window layers (pools with window layers only)
         self.window_pool_bytes = 0
@@ -242,6 +244,8 @@ class EngineMetrics:
             "expert_imbalance": self.moe_imbalance.summary()}})
         state = ({} if not self.state_pool_bytes else {"state": {
             "pool_bytes": self.state_pool_bytes,
+            "slot_layer_bytes": self.state_slot_bytes,
+            "recurrence": self.state_recurrence,
             "admits_total": self.state_admits_total}})
         window = ({} if not self.window_pool_bytes else {"window": {
             "pool_bytes": self.window_pool_bytes,

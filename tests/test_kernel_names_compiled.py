@@ -250,3 +250,67 @@ def test_grouped_paged_decode_compiles_at_the_cell_sizes(
     assert not [ln for ln in text.splitlines()
                 if shape in ln.split(" = ")[-1].split("(")[0]
                 and (" copy(" in ln or " convert(" in ln)], cell
+
+
+def test_grouped_flash_compiles_at_head_256(one_chip):
+    """The flash forward over grouped K/V through Mosaic at
+    ``qwen3next80b_serve_longctx``'s longest prefill (16 query heads over 2
+    K/V heads of 256, 16,384 positions, causal, no window): it keeps its
+    name and the layout ``[16, s, 256]``, and K/V are read in place."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda q, k, v: fa._flash_bhsd(
+            q, k, v, True, 256 ** -0.5, None, None, False)).lower(
+                S((1, 16, 16384, 256)), S((1, 2, 16384, 256)),
+                S((1, 2, 16384, 256))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = _kernel_calls(text)
+    assert [name.split(".")[0] for name in calls] == ["flash_fwd"]
+    (line,) = calls.values()
+    assert "[16,16384,256]" in line.split(" custom-call(")[0]
+    assert "bf16[2,16384,256]" in line.split(" custom-call(")[1]
+
+
+def test_grouped_paged_decode_compiles_at_head_256(one_chip):
+    """The grouped-query decode kernel through Mosaic at
+    ``qwen3next80b_serve_longctx``'s full layer: 64 slots, 16 query heads
+    reading 2 K/V heads of 256 (a group of 8, scale 1/16), bf16 row pages
+    of 16 rows for 16,384 positions a slot.  It keeps its name and reads
+    the pool where it lies."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas.paged_attention import (
+        grouped_paged_decode, paged_decode_supported)
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert paged_decode_supported(jnp.bfloat16, 2, 256, 16)
+    pool = (65537, 16, 512)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda q, k, v, t, n: grouped_paged_decode(
+            q, k, v, t, n, scale=1 / 16)).lower(
+                S((64, 16, 256)), S(pool), S(pool),
+                S((64, 1024), jnp.int32),
+                S((64,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = _kernel_calls(text)
+    assert [n.split("%")[-1].split(".")[0] for n in calls] == [
+        "grouped_paged_decode"]
+    assert not [ln for ln in text.splitlines()
+                if "[65537,16,512]" in ln.split(" = ")[-1].split("(")[0]
+                and (" copy(" in ln or " convert(" in ln)]

@@ -230,12 +230,22 @@ def test_spans_and_counters_carry_the_state(model):
     assert got["serving.prefill"]["scan_tokens"] == 11
     assert "scan_tokens" not in got["serving.decode"]
     assert got["serving.decode"]["state_rows"] == STATE_LAYERS
+    # the recurrence by name, and the chunks its prompt ran (padding in)
+    assert got["serving.prefill"]["recurrence"] == "mamba2"
+    assert got["serving.decode"]["recurrence"] == "mamba2"
+    chunk = model.config.mamba_chunk_size
+    bucket = got["serving.prefill"]["bucket"]
+    assert got["serving.prefill"]["scan_chunks"] == STATE_LAYERS * -(
+        -bucket // min(chunk, bucket))
     assert got["serving.decode"]["kernel"] is False
     # the marker counts the experts HELD (all 8 of TINY's, 4 layers)
     assert 1 <= got["serving.experts"]["experts_hit"] <= 4 * 8
     snap = engine.metrics.snapshot()["state"]
     assert snap == {"pool_bytes": engine._pool.state_nbytes,
-                    "admits_total": 1}
+                    "slot_layer_bytes": engine._pool.state.slot_nbytes,
+                    "recurrence": "mamba2", "admits_total": 1}
+    assert snap["pool_bytes"] == (STATE_LAYERS * engine.config.max_num_seqs
+                                  * snap["slot_layer_bytes"])
     engine.shutdown()
 
 
