@@ -378,13 +378,13 @@ class LLMEngine:
         self._gen = make_generation(model, cfg)
         # a pass launched and not fetched (`generation.Pass`): the kind's
         # passes run ahead of the host unless the guard reads the host
-        # between them, or the pool keeps a state by slot — a pass writes
-        # it in place, so a hand-off could not take back a row of the pass
-        # in flight; tests reach the synchronous loop by clearing
+        # between them; tests reach the synchronous loop by clearing
         # `_run_ahead`
-        self._run_ahead = (self._gen.runs_ahead and not cfg.guard
-                           and not self._pool.state_layers)
+        self._run_ahead = self._gen.runs_ahead and not cfg.guard
         self._ahead = None
+        # the events of a pass a hand-off fetched between steps: the next
+        # step returns them first
+        self._late_events = []
         self._moe_layers = int(getattr(model, "num_expert_layers", 0))
         self._moe_experts = int(getattr(mc, "n_routed_experts", 0))
         self._moe_top_k = int(getattr(mc, "num_experts_per_tok", 0))
@@ -763,10 +763,24 @@ class LLMEngine:
         the pool geometry the importer validates against.  With
         `release` (default) the request leaves this engine entirely —
         slot, pages and live-table entry — so prefill workers stay
-        empty-handed between handoffs.  A pass in flight is discarded
-        first: what is exported is the state the last step left."""
+        empty-handed between handoffs.
+
+        A pass in flight is ended first.  Over pages alone it is discarded:
+        what is exported is the state the last step left, and the importer
+        computes the pass's token again.  A pass writes a slot's state in
+        place, so a pool with state layers fetches it instead: its tokens
+        are delivered as a step would deliver them (the next :meth:`step`
+        returns their events) and ride the payload, and the state exported
+        is the one they left.  Returns None where such a fetch finished
+        the request: the next step reports its finish."""
         self._gen.check_handoff()
-        self._drain("handoff")
+        if self._pool.state_layers:
+            self._drain("handoff", self._late_events)
+            if any(fin and rid == request_id
+                   for rid, _tok, fin in self._late_events):
+                return None
+        else:
+            self._drain("handoff")
         req = self._requests.get(request_id)
         if req is None or req.slot is None:
             raise ValueError(
@@ -924,7 +938,7 @@ class LLMEngine:
         ``[(request_id, token_id, finished), ...]`` for tokens produced
         this step; a preemption surfaces as ``(request_id, None, False)``
         (the request re-enters the queue and will be replayed)."""
-        events = []
+        events, self._late_events = self._late_events, []
         with span("serving.step", running=self.num_running,
                   waiting=self.queue_depth) as step_span:
             admitted = self._step_inner(events)
@@ -1302,12 +1316,15 @@ class LLMEngine:
 
     def _drain(self, cause, events=None):
         """End the pass in flight, so that the next is launched afresh:
-        fetch it and deliver its tokens to `events` (an admission), or,
-        outside a step (a hand-off, shutdown), discard it unfetched — the
-        engine's state is then the one the last step left, and the next
+        fetch it and deliver its tokens to `events` (an admission, a
+        hand-off from a pool with state layers), or, without `events` (a
+        hand-off over pages alone, shutdown), discard it unfetched — the
+        engine's pages are then the ones the last step left, and the next
         pass computes those tokens again (draws keyed by (seed, position),
-        over the same K/V; a pool with a state by slot does not run
-        ahead)."""
+        over the same K/V).  A state by slot is advanced in place by every
+        pass: discarded, the pass would leave it a token ahead of the host,
+        and the next pass would apply that token twice — so only shutdown
+        discards over such a pool."""
         done, self._ahead = self._in_flight(), None
         if done is None:
             return

@@ -67,19 +67,26 @@ class DisaggregatedEngine:
     # ------------------------------------------------------- transfer
     def export(self, request_id):
         """Pull `request_id` off the prefill side; returns an opaque
-        retryable handle for :meth:`import_`.  Remote exports park the
-        blob in the coordination KV under a fresh ``hid``; local ones
-        carry the state dict (optionally bounced through the KV when a
-        client is given, to exercise the real wire format)."""
+        retryable handle for :meth:`import_`, or None where the request
+        finished on the prefill side as it was exported (the pass in
+        flight a state pool fetches held its last token).  Remote
+        exports park the blob in the coordination KV under a fresh
+        ``hid``; local ones carry the state dict (optionally bounced
+        through the KV when a client is given, to exercise the real wire
+        format)."""
         hid = f"h{self._next_hid}"
         self._next_hid += 1
         if _is_remote(self.prefill):
             r = self.prefill.call("export_handoff",
                                   {"request_id": request_id,
                                    "hid": hid})
+            if r.get("finished"):
+                return None
             self.handoff_bytes += int(r.get("bytes", 0))
             return ("kv", hid)
         state = self.prefill.export_page_state(request_id)
+        if state is None:
+            return None
         if self._client is not None:
             blob = wire.pack_state(state)
             self.handoff_bytes += len(blob)
@@ -148,9 +155,13 @@ class DisaggregatedEngine:
         while pending or ready or live_decode:
             progressed = False
             if pending:
+                # a request's events of the step, taken together: it
+                # finished here, or it runs with its first token in
+                landed = {}
                 for rid, tok, fin in self.prefill.step():
-                    if rid not in pending:
-                        continue
+                    if rid in pending and (fin or tok is not None):
+                        landed[rid] = landed.get(rid, False) or fin
+                for rid, fin in landed.items():
                     progressed = True
                     if fin:
                         req = self.prefill.finished_requests.pop(
@@ -160,12 +171,15 @@ class DisaggregatedEngine:
                             getattr(req, "finish_reason", None),
                             "prefill")
                         pending.discard(rid)
-                    elif tok is not None:
-                        # first token landed: the request is DECODE-
-                        # state on the prefill engine — export now
-                        # (frees its prefill pages) and queue the
-                        # import
-                        ready.append((rid, self.export(rid)))
+                        continue
+                    # first token landed: the request is DECODE-state on
+                    # the prefill engine — export now (frees its prefill
+                    # pages) and queue the import; None: the hand-off's
+                    # fetch of the pass in flight finished it, and the
+                    # next step reports that
+                    handle = self.export(rid)
+                    if handle is not None:
+                        ready.append((rid, handle))
                         pending.discard(rid)
             if ready:
                 still = []

@@ -178,6 +178,10 @@ class ReplicaServer:
         if method == "export_handoff":
             state = engine.export_page_state(
                 p["request_id"], release=p.get("release", True))
+            if state is None:
+                # finished by the pass in flight the hand-off fetched:
+                # the next step's events report it
+                return {"hid": p["hid"], "finished": True}
             blob = wire.pack_state(state)
             key = wire.handoff_key(self._ns(), p["hid"])
             _fleet.kv_set_bytes(self._client, key, blob)

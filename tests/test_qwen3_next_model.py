@@ -266,7 +266,7 @@ def test_engine_prefill_then_decode_matches_reference(model, weights):
     rng = np.random.default_rng(3)
     engine = _engine(model, num_pages=9)     # 8 pages of 8 for 3 slots
     assert engine._pool.kinds == ["state", "state", "state", "kv"]
-    assert not engine._run_ahead             # a state by slot: synchronous
+    assert engine._run_ahead                 # a state by slot runs ahead
     tap = _LogitTap(engine)
     prompts = [rng.integers(1, TINY["vocab_size"], n).tolist()
                for n in (21, 17, 30, 3, 12)]
@@ -283,6 +283,7 @@ def test_engine_prefill_then_decode_matches_reference(model, weights):
                                        atol=ATOL)
             checked += 1
     assert checked == sum(sp.max_new_tokens for sp in sps)
+    assert engine.metrics.snapshot()["run_ahead"]["passes_ahead"] > 0
     engine.shutdown()
 
 

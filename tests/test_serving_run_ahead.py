@@ -33,7 +33,25 @@ def _deepseek_v3():
                                        dtype=jnp.float32)
 
 
-KINDS = {"gpt": _gpt, "deepseek_v3": _deepseek_v3}
+def _granitemoehybrid():
+    """A pool with a state by slot (Mamba-2 layers beside one layer of
+    pages): every pass advances a slot's state in place."""
+    from tests.test_granitemoehybrid_model import build, tiny_weights
+    return build(tiny_weights()), dict(page_size=8, max_model_len=64,
+                                       dtype=jnp.float32)
+
+
+def _qwen3_next():
+    """A state by slot of another recurrence (Gated DeltaNet layers beside
+    one layer of pages)."""
+    from tests.test_qwen3_next_model import build, tiny_weights
+    return build(tiny_weights()), dict(page_size=8, max_model_len=64,
+                                       dtype=jnp.float32)
+
+
+STATE_KINDS = {"granitemoehybrid": _granitemoehybrid,
+               "qwen3_next": _qwen3_next}
+KINDS = {"gpt": _gpt, "deepseek_v3": _deepseek_v3, **STATE_KINDS}
 
 
 @pytest.fixture(scope="module", params=sorted(KINDS))
@@ -181,7 +199,8 @@ def test_drains_are_counted_by_cause(kind):
 def _split(kind, ahead, sps):
     """A prefill engine and a decode engine, each request handed off after
     the prefill engine's first step — with the pass launched ahead of that
-    step in flight, holding its third token."""
+    step in flight, holding its third token.  Also returns the hand-off
+    drains and whether the pool keeps a state by slot."""
     pre, dec = _engine(kind), _engine(kind)
     pre._run_ahead = dec._run_ahead = ahead
     out = DisaggregatedEngine(pre, dec).generate(PROMPTS[:4], sps)
@@ -189,38 +208,93 @@ def _split(kind, ahead, sps):
     pre.shutdown()
     dec.shutdown()
     return [(r.tokens, r.finish_reason, r.finished_on) for r in out], \
-        handoffs
+        handoffs, bool(pre._pool.state_layers)
 
 
 @pytest.mark.parametrize("stop", ["length", "eos"])
 def test_a_hand_off_serves_the_synchronous_tokens(kind, stop):
-    """A hand-off discards the pass in flight: a request whose third token
-    — its last, by `max_new_tokens` or at EOS — was in it is exported as
-    the step left it, and the decode engine serves that token."""
+    """Over pages alone a hand-off discards the pass in flight: a request
+    whose third token — its last, by `max_new_tokens` or at EOS — was in
+    it is exported as the step left it, and the decode engine serves that
+    token.  A pool with a state by slot fetches the pass instead: such a
+    request finishes on the prefill engine, with the same tokens."""
     new = 3 if stop == "length" else 8
     sps = [serving.SamplingParams(max_new_tokens=new, temperature=0.0)] + [
         serving.SamplingParams(max_new_tokens=new, temperature=0.9,
                                top_p=0.9, seed=seed) for seed in (5, 6, 7)]
     if stop == "eos":
-        plain, _ = _split(kind, False, sps)
+        plain, _, _ = _split(kind, False, sps)
         sps = [serving.SamplingParams(
             max_new_tokens=8, temperature=sp.temperature, top_p=sp.top_p,
             seed=sp.seed, eos_token_id=tokens[2])
             for sp, (tokens, _r, _on) in zip(sps, plain)]
-    want, _ = _split(kind, False, sps)
-    got, handoffs = _split(kind, True, sps)
-    assert got == want
-    assert sum(len(t) == 3 and on == "decode" for t, _r, on in got) >= 2
+    want, _, _ = _split(kind, False, sps)
+    got, handoffs, state = _split(kind, True, sps)
     assert handoffs > 0
+    if not state:
+        assert got == want
+        assert sum(len(t) == 3 and on == "decode" for t, _r, on in got) >= 2
+    else:
+        assert [(t, r) for t, r, _on in got] == [(t, r) for t, r, _on in want]
+        assert sum(len(t) == 3 and on == "decode" for t, _r, on in want) >= 2
+        assert sum(len(t) == 3 and on == "prefill" for t, _r, on in got) >= 2
 
 
-@pytest.mark.parametrize("which", ["guard", "block_diffusion", "state_pool"])
+@pytest.mark.parametrize("name", sorted(STATE_KINDS))
+def test_a_state_hand_off_fetches_the_pass_in_flight(name):
+    """A hand-off from a pool with a state by slot, with a pass in flight,
+    fetches it: the payload carries its token, its state rows (and pages)
+    are the synchronous engine's after the same tokens, the next step
+    returns the token's event, and an engine importing the payload serves
+    the synchronous tokens."""
+    kind = KINDS[name]()
+    prompt = PROMPTS[5]
+    sp = serving.SamplingParams(max_new_tokens=10, temperature=0.8,
+                                top_p=0.9, seed=13)
+    whole = _engine(kind)
+    whole._run_ahead = False
+    want = whole.generate([prompt], sp)[0].output_token_ids
+    ahead = _engine(kind)
+    rid = ahead.add_request(prompt, sp)
+    for _ in range(3):
+        ahead.step()
+    held = len(ahead._requests[rid].output_token_ids)
+    assert ahead._ahead is not None
+    got = ahead.export_page_state(rid)
+    assert ahead.metrics.drains["handoff"] == 1
+    n = len(got["output_token_ids"])
+    assert n == held + 1 and got["output_token_ids"] == want[:n]
+    assert ahead.step() == [(rid, want[n - 1], False)]
+    # a fresh engine: a page's rows past the length are another request's
+    sync = _engine(kind)
+    sync._run_ahead = False
+    srid = sync.add_request(prompt, sp)
+    while len(sync._requests[srid].output_token_ids) < n:
+        sync.step()
+    there = sync.export_page_state(srid)
+    assert got["len"] == there["len"]
+    assert [sorted(b) for b in got["layers"]] == [
+        sorted(b) for b in there["layers"]]
+    assert sum("ssm" in b for b in got["layers"]) == \
+        ahead._pool.state_layers
+    for mine, theirs in zip(got["layers"], there["layers"]):
+        for block in theirs:
+            np.testing.assert_array_equal(mine[block], theirs[block])
+    dec = _engine(kind)
+    moved = dec.import_page_state(got)
+    while dec.has_unfinished():
+        dec.step()
+    assert dec.finished_requests[moved].output_token_ids == want
+    assert dec.metrics.snapshot()["run_ahead"]["passes_ahead"] > 0
+    for engine in (whole, sync, ahead, dec):
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("which", ["guard", "block_diffusion"])
 def test_guard_and_block_engines_do_not_run_ahead(which):
-    """A guarded engine, a block-diffusion engine and an engine whose pool
-    keeps a state by slot (written in place by every pass, so a pass in
-    flight could not be taken back) fetch every pass before the next is
-    launched; the guarded one serves the unguarded synchronous loop's
-    tokens."""
+    """A guarded engine and a block-diffusion engine fetch every pass
+    before the next is launched; the guarded one serves the unguarded
+    synchronous loop's tokens."""
     if which == "guard":
         kind = _gpt()
         prompts, new = PROMPTS[:4], 6
@@ -230,15 +304,6 @@ def test_guard_and_block_engines_do_not_run_ahead(which):
         want = [r.output_token_ids for r in sync.generate(prompts, sp)]
         sync.shutdown()
         engine = _engine(kind, guard=True)
-    elif which == "state_pool":
-        from tests.test_granitemoehybrid_model import build, tiny_weights
-        prompts, new, want = [[3, 4, 5], [7, 1]], 5, None
-        engine = serving.LLMEngine(build(tiny_weights()),
-                                   serving.EngineConfig(
-                                       max_num_seqs=3, page_size=8,
-                                       max_model_len=64, dtype=jnp.float32))
-        assert engine._pool.state_layers
-        sp = serving.SamplingParams(max_new_tokens=new, temperature=0.0)
     else:
         from tests.test_sdar_moe_model import M, build, tiny_weights
         rng = np.random.default_rng(3)
@@ -258,3 +323,25 @@ def test_guard_and_block_engines_do_not_run_ahead(which):
         assert got == want
     engine.shutdown()
 
+
+def test_a_replica_reports_a_request_its_hand_off_finished():
+    """Over the fleet's verbs: a hand-off from a state pool whose pass in
+    flight holds the request's last token answers ``finished`` and parks
+    no blob, and the replica's next step reports the finish."""
+    from paddle_tpu.serving.fleet.server import ReplicaServer
+    engine = _engine(_granitemoehybrid())
+    server = ReplicaServer(None, 0, lambda p: engine)
+    server._dispatch("boot", {})
+    rid = server._dispatch("add", {"prompt": [3, 4, 5], "sp": {
+        "max_new_tokens": 3, "temperature": 0.0}})
+    first = server._dispatch("step", {})["events"]
+    assert [tok is not None for r, tok, _fin in first if r == rid] == [
+        True, True]
+    assert engine._ahead is not None
+    assert server._dispatch("export_handoff", {
+        "request_id": rid, "hid": "h0"}) == {"hid": "h0", "finished": True}
+    after = server._dispatch("step", {})
+    assert [fin for r, _tok, fin in after["events"] if r == rid] == [True]
+    assert [f["rid"] for f in after["finished"]] == [rid]
+    assert len(after["finished"][0]["tokens"]) == 3
+    server._dispatch("shutdown", {})
